@@ -33,6 +33,7 @@ import sys
 import time
 
 from repro.bench import BenchSizes
+from repro.launch.compile_cache import enable_compile_cache
 
 from benchmarks import (decode_bench, fig9_cache, fig11_lifetime,
                         fig12_14_hashing, kernels_bench, roofline_summary,
@@ -52,6 +53,7 @@ def main(argv=None) -> None:
                          "(src/repro/kernels/autotune_cache.json) instead "
                          "of running the benches")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.autotune:
         from repro.kernels import autotune
         payload = autotune.autotune(quick=args.quick)

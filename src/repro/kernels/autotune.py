@@ -174,12 +174,10 @@ def _time_multiset(n_q: int, block_q: int, plane_format: str,
     live = (np.arange(len(block_sets)) < n_blocks).astype(np.int32)
     args = tuple(jax.numpy.asarray(a)
                  for a in (keys_p, masks_p, block_sets, live))
-    interpret = jax.default_backend() != "tpu"
-
     def run():
         return xam_search_multiset_pallas(
             args[0], args[1], planes, valid, args[2], args[3],
-            block_q=block_q, interpret=interpret).block_until_ready()
+            block_q=block_q).block_until_ready()
 
     return time_callable(run, reps=reps).median_us
 
@@ -204,12 +202,10 @@ def _time_search(block_q: int, block_c: int, plane_format: str,
         data = jax.numpy.asarray(pack_bits_np(data_np, axis=0))
     else:
         data = jax.numpy.asarray(data_np)
-    interpret = jax.default_backend() != "tpu"
-
     def run():
         return xam_search_pallas(
-            keys, data, masks, block_q=block_q, block_c=block_c,
-            interpret=interpret).block_until_ready()
+            keys, data, masks, block_q=block_q,
+            block_c=block_c).block_until_ready()
 
     return time_callable(run, reps=reps).median_us
 

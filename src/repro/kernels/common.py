@@ -17,6 +17,27 @@ PLANE_FORMATS = ("int8", "packed8")
 PLANE_FORMAT_ENV = "REPRO_PLANE_FORMAT"
 
 
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Pallas interpret mode, decided when the kernel is traced.
+
+    ``None`` means: compiled on a TPU, interpreted on the CPU (the only
+    way a Pallas TPU kernel runs there, and what the CPU test suite
+    uses).  Any other platform raises instead of silently interpreting.
+    An explicit ``True``/``False`` is honoured as given."""
+    if interpret is not None:
+        return bool(interpret)
+    import jax
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels here target the TPU; backend {platform!r} can "
+        "neither compile them nor interpret them by default (pass "
+        "interpret=True explicitly to debug)")
+
+
 def bucket_pow2(n: int, lo: int) -> int:
     """Next power of two >= max(n, lo) — the recompile-killing bucket
     policy for ragged query counts (one compiled shape per bucket)."""

@@ -33,11 +33,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import resolve_interpret
+
 BLOCK_Q = 8   # queries per grid step (acceptance floor: >= 8)
 
 
 def _lookup_kernel(scalars_ref,   # (3, Q) int32 prefetch (index maps only)
-                   qvec_ref,      # (3, bq) int32: homes, q_lo, q_hi
+                   qvec_ref,      # (bq, 3) int32: homes, q_lo, q_hi
                    *refs,         # 2*bq packed tiles (1, 2, H) ... + out_ref
                    block_q: int):
     del scalars_ref               # consumed by the index maps
@@ -48,9 +50,9 @@ def _lookup_kernel(scalars_ref,   # (3, Q) int32 prefetch (index maps only)
     big = jnp.int32(2 * window)
 
     qv = qvec_ref[...]
-    homes = qv[0:1, :].T          # (bq, 1)
-    q_lo = qv[1:2, :].T
-    q_hi = qv[2:3, :].T
+    homes = qv[:, 0:1]            # (bq, 1)
+    q_lo = qv[:, 1:2]
+    q_hi = qv[:, 2:3]
     off = homes % window
 
     # Lay each query's two window tiles side by side as one (bq, 2H) lane
@@ -75,7 +77,7 @@ def _lookup_kernel(scalars_ref,   # (3, Q) int32 prefetch (index maps only)
     jax.jit, static_argnames=("window", "block_q", "interpret"))
 def hopscotch_lookup_pallas(table_lo, table_hi, homes, q_lo, q_hi,
                             *, window: int, block_q: int = BLOCK_Q,
-                            interpret: bool = True):
+                            interpret: bool | None = None):
     """table_lo/hi: (n_slots,) uint32 (n_slots % window == 0, with >= window
     pad slots so home+2H never overruns); homes: (Q,) int32; q_lo/hi: (Q,)
     uint32.  Returns (Q,) int32 first-match offsets (-1 = miss)."""
@@ -112,7 +114,7 @@ def hopscotch_lookup_pallas(table_lo, table_hi, homes, q_lo, q_hi,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(qp // block_q,),
-        in_specs=[pl.BlockSpec((3, block_q), lambda i, s: (0, i))]
+        in_specs=[pl.BlockSpec((block_q, 3), lambda i, s: (i, 0))]
         + tile_specs,
         out_specs=pl.BlockSpec((block_q, 1), lambda i, s: (i, 0)),
     )
@@ -120,6 +122,6 @@ def hopscotch_lookup_pallas(table_lo, table_hi, homes, q_lo, q_hi,
         functools.partial(_lookup_kernel, block_q=block_q),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((qp, 1), jnp.int32),
-        interpret=interpret,
-    )(scalars, scalars, *([packed] * (2 * block_q)))
+        interpret=resolve_interpret(interpret),
+    )(scalars, scalars.T, *([packed] * (2 * block_q)))
     return out[:, 0]

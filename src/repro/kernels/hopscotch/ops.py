@@ -15,8 +15,6 @@ from repro.kernels.common import bucket_pow2
 from repro.kernels.hopscotch.kernel import BLOCK_Q, hopscotch_lookup_pallas
 from repro.kernels.hopscotch.ref import hopscotch_lookup_ref
 
-_ON_TPU = jax.default_backend() == "tpu"
-
 
 def hopscotch_lookup(table_lo, table_hi, homes, q_lo, q_hi, *, window: int,
                      block_q: int | None = None,
@@ -38,8 +36,8 @@ def hopscotch_lookup(table_lo, table_hi, homes, q_lo, q_hi, *, window: int,
         Queries per kernel grid step (default 8); each step gather-DMAs
         all its queries' window tiles together.
     use_kernel, interpret
-        Reference-path switch and Pallas interpret-mode flag (defaults
-        to True off-TPU).
+        Reference-path switch and Pallas interpret-mode flag (None:
+        compiled on a TPU, interpreted on the CPU).
 
     Returns
     -------
@@ -59,8 +57,6 @@ def hopscotch_lookup(table_lo, table_hi, homes, q_lo, q_hi, *, window: int,
     q_hi = jnp.asarray(q_hi, jnp.uint32)
     if not use_kernel:
         return hopscotch_lookup_ref(table_lo, table_hi, homes, q_lo, q_hi, window)
-    if interpret is None:
-        interpret = not _ON_TPU
     if block_q is None:
         block_q = BLOCK_Q
     q = homes.shape[0]

@@ -14,30 +14,32 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.common import resolve_interpret
 
 TILE = 4096  # bytes per command (paper: "each search covering upto 4KB")
 
 
 def _make_kernel(pattern_len: int, tile: int):
     def kernel(text_ref, halo_ref, pattern_ref, out_ref):
-        # (1, tile) current tile, (1, tile) next tile, (1, P_pad) pattern.
-        # Compare in int8 (uint8 -> int8 is a bijection, so equality is
-        # preserved): 8-bit lanes pack 4x denser on the VPU than the old
-        # int32 upcast.
+        # (1, tile) current tile, (1, tile) next tile (the halo), (P,)
+        # int32 pattern in SMEM.  Bytes widen to int32 before the compare:
+        # the TPU compares 32-bit lanes, and each pattern byte is read as
+        # a 32-bit SMEM scalar.
         window = jnp.concatenate([text_ref[...], halo_ref[...]], axis=1)
-        window = window.astype(jnp.int8)
-        pat = pattern_ref[...].astype(jnp.int8)
-        acc = jnp.ones((1, tile), bool)
+        window = window.astype(jnp.int32)
+        acc = jnp.ones((1, tile), jnp.int32)
         for k in range(pattern_len):  # static unroll: P vector compares
-            acc = acc & (window[:, k:k + tile] == pat[0, k])
-        out_ref[...] = acc.astype(jnp.int8)
+            acc = jnp.where(window[:, k:k + tile] == pattern_ref[k], acc, 0)
+        out_ref[...] = acc.astype(out_ref.dtype)
     return kernel
 
 
 @functools.partial(jax.jit, static_argnames=("pattern_len", "tile", "interpret"))
 def string_match_pallas(text: jnp.ndarray, pattern: jnp.ndarray, *,
                         pattern_len: int, tile: int = TILE,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: bool | None = None) -> jnp.ndarray:
     """text: (N,) uint8, pattern: (P,) uint8 (P == pattern_len <= tile).
     Returns (N,) int8 match-start flags."""
     n = text.shape[0]
@@ -45,8 +47,6 @@ def string_match_pallas(text: jnp.ndarray, pattern: jnp.ndarray, *,
     n_tiles = (n + tile - 1) // tile
     padded = (n_tiles + 1) * tile  # one extra tile: halo for the last tile
     text_p = jnp.zeros((1, padded), jnp.uint8).at[0, :n].set(text)
-    p_pad = max(_round_up(pattern_len, 128), 128)
-    pat_p = jnp.zeros((1, p_pad), jnp.uint8).at[0, :pattern_len].set(pattern)
 
     out = pl.pallas_call(
         _make_kernel(pattern_len, tile),
@@ -54,16 +54,12 @@ def string_match_pallas(text: jnp.ndarray, pattern: jnp.ndarray, *,
         in_specs=[
             pl.BlockSpec((1, tile), lambda i: (0, i)),
             pl.BlockSpec((1, tile), lambda i: (0, i + 1)),
-            pl.BlockSpec((1, p_pad), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n_tiles * tile), jnp.int8),
-        interpret=interpret,
-    )(text_p, text_p, pat_p)
+        interpret=resolve_interpret(interpret),
+    )(text_p, text_p, pattern.astype(jnp.int32))
     res = out[0, :n]
     valid = jnp.arange(n) <= (n - pattern_len)
     return (res.astype(bool) & valid).astype(jnp.int8)
-
-
-def _round_up(x: int, m: int) -> int:
-    return ((x + m - 1) // m) * m

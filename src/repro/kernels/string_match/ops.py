@@ -1,13 +1,10 @@
 """Jit'd wrappers for the string-match kernel."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.string_match.kernel import string_match_pallas
 from repro.kernels.string_match.ref import string_match_ref
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 def string_match(text, pattern, *, use_kernel: bool = True,
@@ -23,9 +20,10 @@ def string_match(text, pattern, *, use_kernel: bool = True,
     use_kernel : bool
         False = numpy-style reference path.
     tile : int
-        Text bytes per kernel grid step (int8 compares, no upcast).
+        Text bytes per kernel grid step (compared in int32 lanes).
     interpret : bool, optional
-        Pallas interpret-mode flag (defaults to True off-TPU).
+        Pallas interpret-mode flag (None: compiled on a TPU,
+        interpreted on the CPU).
 
     Returns
     -------
@@ -36,8 +34,6 @@ def string_match(text, pattern, *, use_kernel: bool = True,
     pattern = jnp.asarray(pattern, jnp.uint8)
     if not use_kernel:
         return string_match_ref(text, pattern)
-    if interpret is None:
-        interpret = not _ON_TPU
     return string_match_pallas(
         text, pattern, pattern_len=int(pattern.shape[0]), tile=tile,
         interpret=interpret)

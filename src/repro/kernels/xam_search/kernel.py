@@ -45,6 +45,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import resolve_interpret
+
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_C = 512
@@ -75,32 +77,35 @@ def _check_scoring(scoring: str):
 
 
 def _match_bitmap(keys, masks, data, scoring: str):
-    """±1-encoded XNOR-current matmul -> (bq, bc) int8 match bitmap."""
+    """±1-encoded XNOR-current matmul.
+
+    Returns ``(match, n_sel)``: the (bq, bc) int32 {0, 1} match bitmap
+    and the (bq, 1) int32 count of selected key bits (the integer
+    Ref_S).  The ±1 encoding is built in int32 and only the finished
+    {-1, 0, 1} operands narrow to the MXU dtype: Mosaic has no int8
+    vector multiply, and bool vectors are kept to select conditions
+    (never reduced), which is what the TPU compiler lays out."""
+    keys = keys.astype(jnp.int32)
+    sel = masks.astype(jnp.int32) != 0
+    k_enc = jnp.where(sel, 2 * keys - 1, 0)                   # {-1, 0, 1}
+    d_enc = 2 * data.astype(jnp.int32) - 1                    # {-1, 1}
+    n_sel = jnp.sum(jnp.where(sel, 1, 0), axis=1, keepdims=True)
     if scoring == "int8":
-        k_enc = ((2 * keys - 1) * masks).astype(jnp.int8)      # {-1, 0, 1}
-        d_enc = (2 * data - 1).astype(jnp.int8)                # {-1, 1}
-        n_sel = jnp.sum(masks.astype(jnp.int32), axis=1, keepdims=True)
         score = jax.lax.dot_general(
-            k_enc, d_enc,
+            k_enc.astype(jnp.int8), d_enc.astype(jnp.int8),
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32,
         )                                        # int8 x int8 -> int32 MXU
         # Integer sense amp: all-match <=> score == n_sel exactly.
-        return (score >= n_sel).astype(jnp.int8)
-    keys = keys.astype(jnp.float32)
-    masks = masks.astype(jnp.float32)
-    data = data.astype(jnp.float32)
-    k_enc = (2.0 * keys - 1.0) * masks
-    d_enc = 2.0 * data - 1.0
-    n_sel = jnp.sum(masks, axis=1, keepdims=True)
+        return jnp.where(score >= n_sel, 1, 0), n_sel
     score = jax.lax.dot_general(
-        k_enc, d_enc,
+        k_enc.astype(jnp.float32), d_enc.astype(jnp.float32),
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     # All-match  <=>  score == n_sel  (sense amp threshold).  0.5 guard band
     # = half the two-unit gap to a single-mismatch column (analog margin).
-    return (score >= n_sel - 0.5).astype(jnp.int8)
+    return jnp.where(score >= n_sel.astype(jnp.float32) - 0.5, 1, 0), n_sel
 
 
 def _xam_search_kernel(keys_ref, data_ref, masks_ref, out_ref, *,
@@ -110,7 +115,8 @@ def _xam_search_kernel(keys_ref, data_ref, masks_ref, out_ref, *,
     data = data_ref[...]
     if data.dtype == jnp.uint8:
         data = _unpack_rows(data)
-    out_ref[...] = _match_bitmap(keys_ref[...], masks_ref[...], data, scoring)
+    match, _ = _match_bitmap(keys_ref[...], masks_ref[...], data, scoring)
+    out_ref[...] = match.astype(out_ref.dtype)
 
 
 @functools.partial(
@@ -123,7 +129,7 @@ def xam_search_pallas(
     block_q: int = DEFAULT_BLOCK_Q,
     block_c: int = DEFAULT_BLOCK_C,
     scoring: str = "int8",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Batched masked CAM search.  keys/masks (Q, R), data (R, C) int8 —
     or (ceil(R/8), C) uint8 packed words (``plane_format="packed8"``; the
@@ -169,7 +175,7 @@ def xam_search_pallas(
         ],
         out_specs=pl.BlockSpec((bq, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qp, cp), jnp.int8),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(keys_p, data_p, masks_p)
     return out[:q, :c]
 
@@ -182,7 +188,7 @@ def _xam_multiset_kernel(block_sets_ref,       # (n_qb,) int32 in SMEM
                          live_blocks_ref,      # (n_qb,) int32 in SMEM
                          keys_ref, masks_ref,  # (bq, R) int8
                          plane_ref,            # (1, R, C) int8 — this block's set
-                         valid_ref,            # (1, C) int8
+                         valid_ref,            # (1, 1, C) int8
                          out_ref,              # (bq, 1) int32
                          *, scoring: str):
     del block_sets_ref  # consumed by the index maps
@@ -204,19 +210,18 @@ def _xam_multiset_kernel(block_sets_ref,       # (n_qb,) int32 in SMEM
         plane = plane_ref[0]
         if plane.dtype == jnp.uint8:          # packed8: unpack in VMEM
             plane = _unpack_rows(plane)
-        match = _match_bitmap(
+        match, n_sel = _match_bitmap(
             keys_ref[...], masks_ref[...], plane, scoring)      # (bq, C)
-        live = match * valid_ref[...]                       # fused validity
+        live = jnp.where(valid_ref[0].astype(jnp.int32) == 1, match, 0)  # validity
         bq, c = live.shape
         pos = jax.lax.broadcasted_iota(jnp.int32, (bq, c), 1)
         big = jnp.int32(c)
-        first = jnp.min(jnp.where(live == 1, pos, big), axis=1,
+        first = jnp.min(jnp.where(live != 0, pos, big), axis=1,
                         keepdims=True)
         # Ragged block tails (all-zero mask rows) also report -1, so the
         # (Q,) result is deterministic end-to-end, not
         # garbage-where-discarded.
-        row_live = jnp.any(masks_ref[...] != 0, axis=1)[:, None]
-        first = jnp.where(row_live, first, big)
+        first = jnp.where(n_sel > 0, first, big)
         out_ref[...] = jnp.where(first < big, first, -1).astype(jnp.int32)
 
 
@@ -232,7 +237,7 @@ def xam_search_multiset_pallas(
     *,
     block_q: int = MULTISET_BLOCK_Q,
     scoring: str = "int8",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """One fused launch over a set-grouped query batch.  Returns (Q,) int32
     first matching *valid* way per query, -1 = miss.  Q must be a multiple
@@ -276,7 +281,9 @@ def xam_search_multiset_pallas(
             pl.BlockSpec((block_q, r), lambda i, s, nb: (i, 0)),
             pl.BlockSpec((block_q, r), lambda i, s, nb: (i, 0)),
             pl.BlockSpec((1, rp, c), lambda i, s, nb: (s[i], 0, 0)),
-            pl.BlockSpec((1, c), lambda i, s, nb: (s[i], 0)),
+            # Validity rides as (n_sets, 1, C) so the gathered block's
+            # last two dims equal the array's (the TPU tiling rule).
+            pl.BlockSpec((1, 1, c), lambda i, s, nb: (s[i], 0, 0)),
         ],
         out_specs=pl.BlockSpec((block_q, 1), lambda i, s, nb: (i, 0)),
     )
@@ -285,10 +292,10 @@ def xam_search_multiset_pallas(
         functools.partial(_xam_multiset_kernel, scoring=scoring),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((q, 1), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_sets.astype(jnp.int32), live_blocks.astype(jnp.int32),
       keys.astype(jnp.int8), masks.astype(jnp.int8),
-      planes.astype(pdt), valid.astype(jnp.int8))
+      planes.astype(pdt), valid.astype(jnp.int8).reshape(n_sets, 1, c))
     return out[:, 0]
 
 

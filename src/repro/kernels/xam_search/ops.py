@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the XAM search kernels.
 
-``interpret`` defaults to True on CPU (this rig) and should be False on real
-TPUs; the flag is threaded, never hard-coded in callers.  ``scoring``
-selects the MXU arithmetic: ``"int8"`` (default — int8 x int8 -> int32
+``interpret=None`` (the default) is resolved when the kernel is traced:
+compiled on a TPU, interpreted on the CPU
+(``kernels.common.resolve_interpret``); the flag is threaded, never
+hard-coded in callers.  ``scoring`` selects the MXU arithmetic: ``"int8"`` (default — int8 x int8 -> int32
 accumulate) or ``"f32"`` (the original float32 path); the default can be
 flipped rig-wide via ``REPRO_XAM_SCORING=f32``.
 """
@@ -15,7 +16,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.kernels import autotune
@@ -24,8 +24,6 @@ from repro.kernels.common import (
 from repro.kernels.xam_search.kernel import (
     MULTISET_BLOCK_Q, xam_search_multiset_pallas, xam_search_pallas)
 from repro.kernels.xam_search.ref import xam_search_ref
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 #: Host-side fused-search launches since import (every device dispatch of a
 #: multi-set search bumps it exactly once — the unsharded single call, each
@@ -97,8 +95,6 @@ def xam_search(keys, data, masks=None, *, use_kernel: bool = True,
             bits = np.concatenate(
                 [bits, np.zeros((rp8 - r, c), np.int8)], axis=0)
         data = jnp.asarray(pack_bits_np(bits, axis=0))
-    if interpret is None:
-        interpret = not _ON_TPU
     block_q, block_c = autotune.search_blocks(plane_format)
     return xam_search_pallas(keys, data, masks,
                              block_q=block_q, block_c=block_c,
@@ -352,15 +348,14 @@ def xam_search_multiset(key_bits: np.ndarray, set_ids: np.ndarray,
         batch's shape bucket and plane format, falling back to the
         16/64 two-point heuristic — the answer is width-independent),
         MXU arithmetic ("int8" default / "f32"), and Pallas
-        interpret-mode flag (defaults to True off-TPU).
+        interpret-mode flag (None: compiled on a TPU, interpreted on the
+        CPU).
 
     Returns
     -------
     np.ndarray, shape (Q,), int32
         First matching *valid* way per query; ``-1`` = miss.
     """
-    if interpret is None:
-        interpret = not _ON_TPU
     out, slot = _multiset_dispatch(
         key_bits, set_ids, planes, valid,
         block_q=_pick_block_q(len(set_ids), block_q,
@@ -386,8 +381,8 @@ def _stacked_shardmap_fn(mesh: Mesh, block_q: int, scoring: str,
         return out[None]
 
     spec = (P("sets"),) * 6
-    return jax.jit(shard_map(per_shard, mesh=mesh, in_specs=spec,
-                             out_specs=P("sets"), check_rep=False))
+    return jax.jit(jax.shard_map(per_shard, mesh=mesh, in_specs=spec,
+                                 out_specs=P("sets"), check_vma=False))
 
 
 def xam_search_multiset_stacked(key_bits: np.ndarray, set_ids: np.ndarray,
@@ -451,8 +446,6 @@ def xam_search_multiset_stacked(key_bits: np.ndarray, set_ids: np.ndarray,
         return xam_search_multiset(key_bits, set_ids, planes, valid,
                                    block_q=block_q, scoring=scoring,
                                    interpret=interpret)
-    if interpret is None:
-        interpret = not _ON_TPU
     scoring = _resolve_scoring(scoring)
     block_q = _pick_block_q(len(set_ids), block_q, plane_format_of(planes))
     key_bits = np.asarray(key_bits, np.int8)
@@ -548,8 +541,6 @@ def xam_search_multiset_sharded(key_bits: np.ndarray, set_ids: np.ndarray,
         return xam_search_multiset(
             key_bits, set_ids, planes_by_shard[0], valid_by_shard[0],
             block_q=block_q, scoring=scoring, interpret=interpret)
-    if interpret is None:
-        interpret = not _ON_TPU
     scoring = _resolve_scoring(scoring)
     key_bits = np.asarray(key_bits, np.int8)
     set_ids = np.asarray(set_ids, np.int64)

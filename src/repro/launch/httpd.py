@@ -2,6 +2,7 @@
 
     PYTHONPATH=src python -m repro.launch.httpd --arch yi-9b --reduced \
         --port 8077 --n-workers 2 --decode-tokens 8
+    PYTHONPATH=src python -m repro.launch.httpd --arch yi-9b --n-layers 24
 
 Boots the full serving stack — mesh-placed model, `MonarchKVIndex`
 prefix cache (+ KV slab store on resume-capable archs), async
@@ -36,10 +37,10 @@ import numpy as np
 
 import jax
 
-from repro import configs
-from repro.dist import sharding
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
-from repro.launch.serve import build_model_fns
+from repro.launch.serve import (add_model_args, build_model_fns,
+                                init_placed_params, model_config)
 from repro.models import transformer
 from repro.serve.admit_queue import AdmitQueue
 from repro.serve.http_frontend import HttpFrontend, ServeRouter
@@ -49,8 +50,7 @@ from repro.serve.kv_index import (KVIndexConfig, KVSlabStore,
 
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--arch", default="yi-9b", choices=sorted(configs.ARCHS))
-    ap.add_argument("--reduced", action="store_true")
+    add_model_args(ap)
     ap.add_argument("--mesh", default="host",
                     choices=["host", "single", "multi"])
     ap.add_argument("--prompt-len", type=int, default=96,
@@ -102,11 +102,7 @@ def build_frontend(args) -> tuple[HttpFrontend, AdmitQueue]:
 
     Separated from :func:`main` so tests can boot the real stack on an
     ephemeral port and drive it in-process."""
-    cfg = configs.get_arch(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-    if not cfg.supports_decode:
-        raise SystemExit(f"{cfg.name} is encoder-only; no decode service")
+    cfg = model_config(args)
     mesh = (make_host_mesh() if args.mesh == "host"
             else make_production_mesh(multi_pod=(args.mesh == "multi")))
     max_seq = args.prompt_len + args.decode_tokens
@@ -129,11 +125,7 @@ def build_frontend(args) -> tuple[HttpFrontend, AdmitQueue]:
                          policy=args.admit_policy)
 
     with mesh:
-        params = transformer.init_params(jax.random.PRNGKey(0), cfg)
-        p_named = sharding.to_named(
-            sharding.param_specs(jax.eval_shape(lambda: params), mesh),
-            mesh)
-        params = jax.tree.map(jax.device_put, params, p_named)
+        params = init_placed_params(cfg, mesh)
         prefill_fn, decode_fn, _ = build_model_fns(
             params, cfg, max_seq=max_seq,
             decode_tokens=args.decode_tokens, index=idx, resume=resume)
@@ -160,6 +152,7 @@ def build_frontend(args) -> tuple[HttpFrontend, AdmitQueue]:
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     frontend, admit_q = build_frontend(args)
     frontend.start()
     host, port = frontend.address

@@ -13,7 +13,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import geometry
@@ -164,8 +163,8 @@ def make_sharded_roll(mesh: Mesh, n_rows: int, shift: int):
         if n not in jitted:
             spec = tuple(P("sets") for _ in range(n))
             jitted[n] = jax.jit(
-                shard_map(_roll, mesh=mesh, in_specs=spec, out_specs=spec,
-                          check_rep=False),
+                jax.shard_map(_roll, mesh=mesh, in_specs=spec,
+                              out_specs=spec, check_vma=False),
                 donate_argnums=tuple(range(n)))
         return jitted[n](*arrays)
 

@@ -3,6 +3,10 @@ MonarchKVIndex prefix cache.
 
     PYTHONPATH=src python -m repro.launch.serve --arch yi-9b --reduced \
         --requests 8 --decode-tokens 8 [--mesh host|single|multi]
+    PYTHONPATH=src python -m repro.launch.serve --arch yi-9b --n-layers 24
+
+(``--reduced`` shrinks every width for a CPU run; ``--n-layers`` keeps
+the published widths and cuts depth to one chip's share.)
 
 The request loop is the same flow examples/serve_prefix_cache.py
 demonstrates; this launcher adds mesh placement (params TP/FSDP-sharded,
@@ -32,6 +36,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import time
 
 import numpy as np
@@ -41,6 +46,7 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.dist import sharding
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import transformer
 from repro.serve import step as serve_step
@@ -184,6 +190,47 @@ def run_request_loop(admit_q: AdmitQueue, requests, *, prefill_fn,
     return records
 
 
+def add_model_args(ap: argparse.ArgumentParser) -> None:
+    """``--arch`` / ``--reduced`` / ``--n-layers``: the model selection
+    shared by this launcher and the HTTP edge (``launch/httpd.py``)."""
+    ap.add_argument("--arch", default="yi-9b", choices=sorted(configs.ARCHS))
+    ap.add_argument("--reduced", action="store_true",
+                    help="CPU smoke-test variant: every width shrunk "
+                         "(ArchConfig.reduced)")
+    ap.add_argument("--n-layers", type=int, default=None,
+                    help="depth cut that keeps every published width: "
+                         "serve the first N layers (24 of yi-9b's 48 is "
+                         "one chip's share of a two-stage pipeline)")
+
+
+def model_config(args):
+    """The served ``ArchConfig`` for :func:`add_model_args` flags."""
+    cfg = configs.get_arch(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    if args.n_layers is not None:
+        if not 1 <= args.n_layers <= cfg.n_layers:
+            raise SystemExit(f"--n-layers {args.n_layers}: {cfg.name} has "
+                             f"{cfg.n_layers} layers")
+        cfg = dataclasses.replace(cfg, n_layers=args.n_layers)
+    if not cfg.supports_decode:
+        raise SystemExit(f"{cfg.name} is encoder-only; no decode service")
+    return cfg
+
+
+def init_placed_params(cfg, mesh):
+    """Random weights (seed 0) made directly in their serving layout:
+    ONE jitted init whose ``out_shardings`` are the mesh placement, so
+    neither a host copy, a second device copy nor a whole-stack float32
+    draw is ever resident (yi-9b at published widths fills most of a
+    chip)."""
+    init = functools.partial(transformer.init_params, cfg=cfg)
+    key = jax.random.PRNGKey(0)
+    p_named = sharding.to_named(
+        sharding.param_specs(jax.eval_shape(init, key), mesh), mesh)
+    return jax.jit(init, out_shardings=p_named)(key)
+
+
 def build_model_fns(params, cfg, *, max_seq, decode_tokens, index=None,
                     resume=False):
     """(prefill_fn, decode_fn, engine) for :func:`run_request_loop`.
@@ -225,8 +272,7 @@ def build_model_fns(params, cfg, *, max_seq, decode_tokens, index=None,
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="yi-9b", choices=sorted(configs.ARCHS))
-    ap.add_argument("--reduced", action="store_true")
+    add_model_args(ap)
     ap.add_argument("--mesh", default="host",
                     choices=["host", "single", "multi"])
     ap.add_argument("--requests", type=int, default=8)
@@ -276,11 +322,8 @@ def main(argv=None):
                          "defer (reject; the loop retries after decode)")
     args = ap.parse_args(argv)
 
-    cfg = configs.get_arch(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-    if not cfg.supports_decode:
-        raise SystemExit(f"{cfg.name} is encoder-only; no decode service")
+    enable_compile_cache()
+    cfg = model_config(args)
     mesh = (make_host_mesh() if args.mesh == "host"
             else make_production_mesh(multi_pod=(args.mesh == "multi")))
 
@@ -322,10 +365,7 @@ def main(argv=None):
                          policy=args.admit_policy)
 
     with mesh:
-        params = transformer.init_params(jax.random.PRNGKey(0), cfg)
-        p_named = sharding.to_named(
-            sharding.param_specs(jax.eval_shape(lambda: params), mesh), mesh)
-        params = jax.tree.map(jax.device_put, params, p_named)
+        params = init_placed_params(cfg, mesh)
         model_prefill, model_decode, engine = build_model_fns(
             params, cfg, max_seq=max_seq, decode_tokens=args.decode_tokens,
             index=idx, resume=resume)

@@ -93,7 +93,9 @@ def init_params(key, cfg: ArchConfig):
                     for i, kind in enumerate(group)
                     if kind != SHARED_ATTN}
         gkeys = jnp.stack(layers.split_keys(keys[1], n_groups))
-        params["groups"] = jax.vmap(init_one_group)(gkeys)
+        # lax.map, not vmap: one group's draw at a time, so a jitted init
+        # holds one layer's float32 transient, never a whole stack's.
+        params["groups"] = jax.lax.map(init_one_group, gkeys)
     if any(k == SHARED_ATTN for k in group + rem):
         params["shared"] = init_block(keys[2], SHARED_ATTN, cfg)
     for i, kind in enumerate(rem):

@@ -17,12 +17,16 @@ import dataclasses
 import os
 import re
 
-# v5e hardware constants (per chip), per the assignment.
+# TPU v5e per-chip peaks.  Source: Google Cloud documentation, "TPU
+# v5e": 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, and
+# 1,600 Gbit/s of chip-to-chip interconnect over four links (50 GB/s
+# each).
 PEAK_FLOPS = 197e12          # bf16
+PEAK_INT8_OPS = 393e12       # int8 x int8 -> int32 (XAM scoring)
 HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s/link (~45GB/s eff; assignment: ~50)
+ICI_BW = 50e9                # bytes/s/link
 
-#: Env knob selecting the machine profile by name (``MACHINES`` keys).
+#: Env knob selecting the machine profile by name (``Machine.name``).
 MACHINE_ENV = "REPRO_MACHINE"
 
 
@@ -30,39 +34,59 @@ MACHINE_ENV = "REPRO_MACHINE"
 class Machine:
     """Per-chip peak rates a roofline is drawn against.
 
-    The v5e numbers are the assignment's constants above; ``cpu-interpret``
-    is a deliberately coarse host profile (one modern server core's DRAM
-    stream + vector peak, order-of-magnitude only) so interpret-mode bench
-    runs report an achieved-bandwidth *fraction* against a ceiling that is
-    at least the right power of ten — CI uses it to sanity-bound the
-    packed-kernel traffic numbers, never to compare against TPU rooflines.
+    ``cpu-interpret`` is a deliberately coarse host profile (one modern
+    server core's DRAM stream + vector peak, order-of-magnitude only) so
+    interpret-mode bench runs report an achieved-bandwidth *fraction*
+    against a ceiling that is at least the right power of ten — it is
+    chosen only on the ``cpu`` platform and never compared against a TPU
+    roofline.
     """
 
     name: str
-    peak_flops: float        # FLOP/s
+    peak_flops: float        # FLOP/s (bf16)
+    peak_int8_ops: float     # OP/s (int8)
     hbm_bw: float            # bytes/s (main-memory stream bandwidth)
     ici_bw: float            # bytes/s/link (interconnect; 0 = none)
 
 
+#: Accelerator profiles keyed by ``jax.Device.device_kind``.  A TPU kind
+#: missing here is an error, never a default.
 MACHINES: dict[str, Machine] = {
-    "v5e": Machine("v5e", PEAK_FLOPS, HBM_BW, ICI_BW),
-    "cpu-interpret": Machine("cpu-interpret", 5e10, 2e10, 1e10),
+    "TPU v5 lite": Machine("v5e", PEAK_FLOPS, PEAK_INT8_OPS, HBM_BW, ICI_BW),
 }
+
+#: The interpret-mode host profile (``platform == "cpu"`` only).
+CPU_INTERPRET = Machine("cpu-interpret", 5e10, 5e10, 2e10, 1e10)
+
+_BY_NAME = {m.name: m for m in (*MACHINES.values(), CPU_INTERPRET)}
+
+
+def machine_for(device) -> Machine:
+    """Peak profile of one ``jax.Device``: ``cpu-interpret`` on the CPU
+    platform, else the table entry for its ``device_kind``."""
+    if device.platform == "cpu":
+        return CPU_INTERPRET
+    try:
+        return MACHINES[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak rates for device kind {device.device_kind!r} "
+            f"(platform {device.platform!r}); known kinds: "
+            f"{sorted(MACHINES)}") from None
 
 
 def current_machine() -> Machine:
     """Active machine profile: ``REPRO_MACHINE`` if set (ValueError on an
-    unknown name), else ``v5e`` on TPU and ``cpu-interpret`` elsewhere."""
+    unknown name), else :func:`machine_for` the first device."""
     name = os.environ.get(MACHINE_ENV)
     if name is not None:
-        if name not in MACHINES:
+        if name not in _BY_NAME:
             raise ValueError(
                 f"{MACHINE_ENV}={name!r} is not a known machine profile; "
-                f"valid values: {sorted(MACHINES)}")
-        return MACHINES[name]
+                f"valid values: {sorted(_BY_NAME)}")
+        return _BY_NAME[name]
     import jax
-    return MACHINES["v5e" if jax.default_backend() == "tpu"
-                    else "cpu-interpret"]
+    return machine_for(jax.devices()[0])
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1,
@@ -223,7 +247,7 @@ def analyze(cost: dict, coll: dict, *, model_flops_per_device: float,
     (the dry-run artifacts target that part); pass ``current_machine()``
     to roofline against the active backend instead."""
     if machine is None:
-        machine = MACHINES["v5e"]
+        machine = MACHINES["TPU v5 lite"]
     raw_flops = float(cost.get("flops", 0.0))
     raw_bytes = float(cost.get("bytes accessed", 0.0))
     if jaxpr_flops_per_device and raw_flops > 0:

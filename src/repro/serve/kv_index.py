@@ -97,7 +97,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import geometry
@@ -491,8 +490,8 @@ def _admit_shardmap_fn(mesh):
 
     spec = (P("sets"),) * 14 + (P(), P()) + (P("sets"),) * 6
     return jax.jit(
-        shard_map(per_shard, mesh=mesh, in_specs=spec,
-                  out_specs=P("sets"), check_rep=False),
+        jax.shard_map(per_shard, mesh=mesh, in_specs=spec,
+                      out_specs=P("sets"), check_vma=False),
         donate_argnums=tuple(range(14)))
 
 

@@ -218,14 +218,14 @@ def test_compressed_psum_error_feedback(rng):
     mesh = _mesh1()
     if mesh.devices.size != 1:
         pytest.skip("single-device formulation")
-    from jax.experimental.shard_map import shard_map
     g = jnp.asarray(rng.normal(size=(512,)), jnp.float32)
     r = jnp.zeros_like(g)
 
     def f(g, r):
         return compression.compressed_psum_leaf(g, r, "data")
 
-    fm = shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
+    fm = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                       out_specs=(P(), P()))
     total_err = []
     acc = jnp.zeros_like(g)
     for _ in range(8):

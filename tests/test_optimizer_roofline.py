@@ -129,6 +129,29 @@ def test_roofline_analyze_terms():
     assert r.useful_ratio == pytest.approx(100e12 / 197e12)
 
 
+class _Device:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("tpu", "TPU v5 lite", "v5e"),
+    ("cpu", "cpu", "cpu-interpret"),
+    ("tpu", "TPU v4", None),            # not in the table: an error
+    ("gpu", "NVIDIA H100", None),       # cpu-interpret is for the CPU only
+])
+def test_machine_table_keyed_by_device_kind(platform, kind, want):
+    if want is None:
+        with pytest.raises(ValueError, match="device kind"):
+            analysis.machine_for(_Device(platform, kind))
+        return
+    m = analysis.machine_for(_Device(platform, kind))
+    assert m.name == want
+    if want == "v5e":   # Google Cloud "TPU v5e" published peaks
+        assert (m.peak_flops, m.peak_int8_ops, m.hbm_bw) == (
+            197e12, 393e12, 819e9)
+
+
 def test_model_flops_dense_vs_moe():
     from repro import configs
     dense = configs.get_arch("yi-9b")

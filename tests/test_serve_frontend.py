@@ -310,10 +310,20 @@ def test_request_loop_defer_retry_waits_for_drain():
 # launcher report (the empty-slice NaN regression)
 
 
-def test_serve_main_tiny_prompt_reports_na(capsys):
+@pytest.fixture
+def serve_mod(monkeypatch):
+    """``repro.launch.serve`` with its compile-cache set-up stubbed out:
+    ``main`` would otherwise switch this test process's JAX onto the
+    checkout's persistent cache for every later test."""
+    from repro.launch import serve
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    return serve
+
+
+def test_serve_main_tiny_prompt_reports_na(capsys, serve_mod):
     """Prefix shorter than one chunk: the per-batch report used to take
     an empty-slice mean (NaN + RuntimeWarning); it must print 'n/a'."""
-    from repro.launch import serve
+    serve = serve_mod
     with warnings.catch_warnings():
         warnings.simplefilter("error", RuntimeWarning)
         records = serve.main(
@@ -327,12 +337,11 @@ def test_serve_main_tiny_prompt_reports_na(capsys):
     assert records[0].decoded.shape == (1, 2)
 
 
-def test_serve_main_non_resume_decode_returns_tokens():
+def test_serve_main_non_resume_decode_returns_tokens(serve_mod):
     """The non-resume ``model_decode`` used to accumulate greedy tokens
     in ``outs`` and throw them away — the launcher must surface the
     ``(B, decode_tokens)`` array on every record."""
-    from repro.launch import serve
-    records = serve.main(
+    records = serve_mod.main(
         argv=["--arch", "yi-9b", "--reduced", "--no-resume",
               "--requests", "2", "--batch", "1", "--prompt-len", "32",
               "--decode-tokens", "3"])
