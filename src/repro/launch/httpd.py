@@ -22,7 +22,9 @@ prefix cache (+ KV slab store on resume-capable archs), async
 
 Index/durability knobs mirror ``launch/serve.py`` (the flag table in
 docs/SERVING.md applies); the edge-specific knobs are ``--port`` /
-``--host``, ``--n-workers``, ``--max-queue``, ``--batch-window-ms``.
+``--host``, ``--n-workers``, ``--max-queue``, ``--batch-window-ms``,
+and ``--profiler-port`` (opens the JAX profiler's server for a live
+capture of the serving path's spans).
 ``--port 0`` binds an ephemeral port and prints it — tests and the CI
 smoke read the "listening on" line.
 """
@@ -74,6 +76,10 @@ def build_parser() -> argparse.ArgumentParser:
                          "(0 disables)")
     ap.add_argument("--verbose", action="store_true",
                     help="per-request access log")
+    ap.add_argument("--profiler-port", type=int, default=None,
+                    help="open the JAX profiler's server on this port so "
+                         "a live capture records the monarch.* spans "
+                         "(docs/SERVING.md, Tracing); off by default")
     # index scaling / durability (same semantics as launch/serve.py)
     ap.add_argument("--n-shards", type=int, default=1)
     ap.add_argument("--sync-admit", action="store_true")
@@ -143,6 +149,9 @@ def build_frontend(args) -> tuple[HttpFrontend, AdmitQueue]:
         batch_window_s=args.batch_window_ms / 1e3)
     frontend = HttpFrontend(router, host=args.host, port=args.port,
                             verbose=args.verbose)
+    if args.profiler_port is not None:
+        jax.profiler.start_server(args.profiler_port)
+        print(f"[httpd] profiler server on port {args.profiler_port}")
     print(f"[httpd] {cfg.name}: resume "
           f"{'ON' if resume else 'off'}, index n_shards={args.n_shards}, "
           f"admit policy={args.admit_policy} "

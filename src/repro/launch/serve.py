@@ -76,6 +76,9 @@ class RequestRecord:
     dropped: bool               # retry rejected too — admission forgone
     resumed_chunks: int = 0     # chunks restored from KV slabs (resume path)
     decoded: np.ndarray | None = None   # decode_fn's (B, T) greedy tokens
+    # time.monotonic() when the first decoded token reached the host
+    # (resume engine's decode; None on other paths)
+    first_token_at: float | None = None
 
 
 def run_request_loop(admit_q: AdmitQueue, requests, *, prefill_fn,
@@ -183,7 +186,9 @@ def run_request_loop(admit_q: AdmitQueue, requests, *, prefill_fn,
             latency_s=done - arrival,
             chunks=int(hits.size), hit_chunks=int(hits.sum()),
             admitted=bool(accepted), retried=retried, dropped=dropped,
-            resumed_chunks=resumed, decoded=decoded)
+            resumed_chunks=resumed, decoded=decoded,
+            first_token_at=(state.state.get("first_token_at")
+                            if isinstance(state, PrefillResult) else None))
         records.append(rec)
         if on_batch is not None:
             on_batch(i, toks, hits, rec)

@@ -55,12 +55,15 @@ callers.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import threading
+import time
 
 import numpy as np
 
 from repro.serve.kv_index import CHUNK_TOKENS, MonarchKVIndex
+from repro.serve.spans import span
 
 #: Coalesced-unit size cap: bounds the single device dispatch a drained
 #: unit turns into (and the work lost if a merged unit fails).
@@ -77,6 +80,9 @@ class AdmitQueueStats:
     shed: int = 0             # pending batches dropped (policy="shed")
     shed_fps: int = 0         # fingerprints in those shed batches
     deferred: int = 0         # submits rejected (policy="defer")
+    lookups: int = 0          # lookups served
+    lookup_wait_s: float = 0.0   # seconds lookups spent in read-your-
+    #                              writes flushes and waiting for the index
 
 
 class AdmitQueue:
@@ -260,16 +266,35 @@ class AdmitQueue:
         :meth:`close` — go to the index directly once the queue is gone."""
         with self._cv:
             self._check_open()
-        if self.read_your_writes:
-            fps = self.index.fingerprints(tokens).reshape(-1)
-            with self._cv:
-                waiting = bool(self._pending) and any(
-                    int(fp) in self._pending for fp in fps)
-            if waiting:
-                self.stats.rww_flushes += 1
-                self.flush()
-        with self._idx_lock:
-            return self.index.lookup(tokens)
+        b, s = np.shape(tokens)
+        with span("lookup", queries=b * (s // CHUNK_TOKENS)):
+            waited = 0.0
+            if self.read_your_writes:
+                fps = self.index.fingerprints(tokens).reshape(-1)
+                with self._cv:
+                    waiting = bool(self._pending) and any(
+                        int(fp) in self._pending for fp in fps)
+                if waiting:
+                    self.stats.rww_flushes += 1
+                    t = time.perf_counter()
+                    with span("lookup.flush"):
+                        self.flush()
+                    waited = time.perf_counter() - t
+            t = time.perf_counter()
+            with self._index_held("lookup.wait"):
+                self.stats.lookups += 1
+                self.stats.lookup_wait_s += waited + time.perf_counter() - t
+                return self.index.lookup(tokens)
+
+    @contextlib.contextmanager
+    def _index_held(self, wait_span: str):
+        """Hold the index lock; the wait for it is the span ``wait_span``."""
+        with span(wait_span):
+            self._idx_lock.acquire()
+        try:
+            yield
+        finally:
+            self._idx_lock.release()
 
     def flush(self) -> None:
         """Drain barrier: block until every submitted batch has been
@@ -363,7 +388,8 @@ class AdmitQueue:
     def _admit_one_batch(self, fps: np.ndarray, n_batches: int = 1) -> None:
         err = None
         try:
-            with self._idx_lock:
+            with span("admit", fps=fps.size, batches=n_batches), \
+                    self._index_held("admit.wait"):
                 self.index.admit_fps(fps)
             self.stats.batches += n_batches
             self.stats.coalesced += n_batches - 1

@@ -79,6 +79,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 import numpy as np
 
 from repro.serve.admit_queue import AdmitQueue
+from repro.serve.spans import span
 
 #: Hard cap on tokens per request batch (rows x cols): a request larger
 #: than this answers 400 instead of occupying a worker for seconds.
@@ -280,25 +281,28 @@ class ServeRouter:
             self._cv.wait_for(lambda: self._queue or self._stop)
             if not self._queue:
                 return None              # stopping and fully drained
-            head = self._queue.popleft()
-            self._inflight += 1
-            batch = [head]
-            rows = head.tokens.shape[0]
-            deadline = self._now() + self.batch_window_s
-            while self.batch_window_s > 0 and rows < self.max_batch_rows:
-                if self._queue:
-                    nxt = self._queue[0]
-                    if (nxt.tokens.shape[1:] != head.tokens.shape[1:]
-                            or rows + nxt.tokens.shape[0]
-                            > self.max_batch_rows):
-                        break            # shape mismatch / row cap
-                    batch.append(self._queue.popleft())
-                    rows += nxt.tokens.shape[0]
-                    continue
-                remaining = deadline - self._now()
-                if remaining <= 0 or self._stop:
-                    break
-                self._cv.wait(timeout=remaining)
+            with span("router.batch") as sp:
+                head = self._queue.popleft()
+                self._inflight += 1
+                batch = [head]
+                rows = head.tokens.shape[0]
+                deadline = self._now() + self.batch_window_s
+                while (self.batch_window_s > 0
+                       and rows < self.max_batch_rows):
+                    if self._queue:
+                        nxt = self._queue[0]
+                        if (nxt.tokens.shape[1:] != head.tokens.shape[1:]
+                                or rows + nxt.tokens.shape[0]
+                                > self.max_batch_rows):
+                            break        # shape mismatch / row cap
+                        batch.append(self._queue.popleft())
+                        rows += nxt.tokens.shape[0]
+                        continue
+                    remaining = deadline - self._now()
+                    if remaining <= 0 or self._stop:
+                        break
+                    self._cv.wait(timeout=remaining)
+                sp.set_metadata(rows=rows)
             return batch
 
     def _serve_batch(self, batch: list[_Pending]) -> None:
@@ -343,6 +347,11 @@ class ServeRouter:
                     "batched_rows": n_rows,
                     "queued_ms": round((t_start - p.t_enqueue) * 1e3, 3),
                     "service_ms": round((t_done - t_start) * 1e3, 3),
+                    # the engine stamps it on time.monotonic, the
+                    # router's default clock
+                    "first_token_ms": (
+                        None if rec.first_token_at is None else round(
+                            (rec.first_token_at - p.t_enqueue) * 1e3, 3)),
                 }
                 row += b
         except BaseException as e:       # noqa: BLE001 — a worker must
@@ -370,7 +379,9 @@ class ServeRouter:
             batch = self._next_batch()
             if batch is None:
                 return
-            self._serve_batch(batch)
+            with span("router.serve", requests=len(batch),
+                      rows=sum(p.tokens.shape[0] for p in batch)):
+                self._serve_batch(batch)
 
 
 # ---------------------------------------------------------------------------
@@ -451,6 +462,12 @@ class _Handler(BaseHTTPRequestHandler):
                                   "GET /healthz, GET /stats"})
 
     def do_POST(self):                   # noqa: N802 — stdlib hook name
+        with span("edge") as sp:
+            self._generate(sp)
+
+    def _generate(self, sp) -> None:
+        """``POST /v1/generate``, from parsing the body to the answer
+        sent, inside the ``monarch.edge`` span ``sp``."""
         if self.path != "/v1/generate":
             self._send_json(404, {"error": f"unknown path {self.path}; "
                                   "POST goes to /v1/generate"})
@@ -460,6 +477,8 @@ class _Handler(BaseHTTPRequestHandler):
             length = int(self.headers.get("Content-Length", 0))
             doc = json.loads(self.rfile.read(length))
             tokens = np.asarray(doc["tokens"], dtype=np.int32)
+            sp.set_metadata(rows=tokens.shape[0] if tokens.ndim else 0,
+                            tokens=tokens.size)
         except (ValueError, TypeError, KeyError, json.JSONDecodeError):
             self._send_json(400, {"error": "body must be JSON "
                                   '{"tokens": [[...int...], ...]} — a '

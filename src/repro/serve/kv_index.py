@@ -109,6 +109,7 @@ from repro.kernels.common import (
     bucket_pow2, pack_bits_np, resolve_plane_format)
 from repro.kernels.xam_search import ops as xam_ops
 from repro.launch import mesh as mesh_mod
+from repro.serve.spans import span
 
 CHUNK_TOKENS = 16
 ROTATE_STRIDE = 7          # prime set stride per rotation (§8)
@@ -503,6 +504,11 @@ def _rotate_planes(bits, valid, fp_of, read_after, shift: int):
     so they stay searchable under the rotated mapping.  No host rebuild."""
     roll = lambda x: jnp.roll(x, shift, axis=0)
     return roll(bits), roll(valid), roll(fp_of), roll(read_after)
+
+
+def _set_bytes(plane) -> int:
+    """Bytes one set of an ``(n_sets, ...)`` device plane holds."""
+    return int(np.prod(plane.shape[1:])) * np.dtype(plane.dtype).itemsize
 
 
 def _shard_property(name: str, doc: str, settable: bool = True):
@@ -978,8 +984,13 @@ class MonarchKVIndex:
                 self._assemble(self._valid), mesh=self.set_mesh)
             self.stats.searches += 1
         elif self.n_parts == 1:
-            ways = xam_ops.xam_search_multiset(
-                key_bits, sets, self._bits[0], self._valid[0])
+            planes, valid = self._bits[0], self._valid[0]
+            with span("lookup.search", queries=key_bits.shape[0],
+                      key_bits=key_bits.shape[1], ways=planes.shape[-1],
+                      sets=np.unique(sets).size,
+                      set_bytes=_set_bytes(planes) + _set_bytes(valid)):
+                ways = xam_ops.xam_search_multiset(
+                    key_bits, sets, planes, valid)
             self.stats.searches += 1
         else:
             ways = xam_ops.xam_search_multiset_sharded(

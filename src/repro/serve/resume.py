@@ -40,6 +40,7 @@ Correctness ground rules (all pinned by ``tests/test_decode_resume.py``):
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any
 
 import numpy as np
@@ -50,6 +51,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.models import transformer
 from repro.serve.kv_index import CHUNK_TOKENS, MonarchKVIndex
+from repro.serve.spans import span
 from repro.serve.step import make_decode_step, make_resume_prefill_step
 
 
@@ -58,10 +60,11 @@ class PrefillResult:
     """What a resume-aware ``prefill_fn`` returns to the request loop.
 
     ``state`` is the opaque decode state (logits/cache/position) for
-    ``decode_fn``; ``slabs`` maps chunk fingerprints to freshly computed
-    KV slabs for the loop to stage at submit time; the chunk counters
-    feed the per-request records and the bench's resumed-fraction
-    metric."""
+    ``decode_fn``, which adds ``first_token_at``: the ``time.monotonic()``
+    stamp of the first decoded token's host read; ``slabs`` maps chunk
+    fingerprints to freshly computed KV slabs for the loop to stage at
+    submit time; the chunk counters feed the per-request records and the
+    bench's resumed-fraction metric."""
     state: Any
     slabs: dict | None = None
     resumed_chunks: int = 0
@@ -93,6 +96,10 @@ def _concat_rows(rows: list):
     """Concatenate per-row prefixes along the batch axis."""
     return jax.tree.map(
         lambda *xs: np.concatenate(xs, axis=xs[0].ndim - 4), *rows)
+
+
+def _nbytes(tree) -> int:
+    return sum(int(a.nbytes) for a in jax.tree.leaves(tree))
 
 
 class PrefixResumeEngine:
@@ -176,31 +183,42 @@ class PrefixResumeEngine:
         toks = np.asarray(toks, np.int32)
         b, s = toks.shape
         n_chunks = s // CHUNK_TOKENS
-        fps = self.index.fingerprints(toks)
-        if hits is None:
-            hits = np.zeros((b, n_chunks), bool)
-        run = self._resume_run(fps, np.asarray(hits, bool), s)
-        p_len = run * CHUNK_TOKENS
-        if run > 0:
-            prefix_kv = _concat_rows([
-                _concat_seq([self.store.get(int(fps[r, k]))
-                             for k in range(run)])
-                for r in range(b)])
-            logits, cache, kv_suffix = self._prefill(
-                self.params, {"tokens": toks[:, p_len:]},
-                jax.tree.map(jnp.asarray, prefix_kv))
-        else:
-            logits, cache, kv_suffix = self._prefill(
-                self.params, {"tokens": toks})
-        # Slice the freshly computed whole chunks into slabs to stage.
-        kv_np = jax.tree.map(np.asarray, kv_suffix)
-        slabs: dict[int, Any] = {}
-        for r in range(b):
-            for c in range(run, n_chunks):
-                fp = int(fps[r, c])
-                if fp not in slabs:
-                    lo = c * CHUNK_TOKENS - p_len
-                    slabs[fp] = _slice_chunk(kv_np, r, lo, lo + CHUNK_TOKENS)
+        with span("resume.prefill", rows=b) as outer:
+            with span("resume.match"):
+                fps = self.index.fingerprints(toks)
+                if hits is None:
+                    hits = np.zeros((b, n_chunks), bool)
+                run = self._resume_run(fps, np.asarray(hits, bool), s)
+            p_len = run * CHUNK_TOKENS
+            outer.set_metadata(prefix=p_len, suffix=s - p_len)
+            if run > 0:
+                with span("resume.restore", rows=b) as restore:
+                    prefix_kv = _concat_rows([
+                        _concat_seq([self.store.get(int(fps[r, k]))
+                                     for k in range(run)])
+                        for r in range(b)])
+                    restore.set_metadata(nbytes=_nbytes(prefix_kv))
+                    prefix_kv = jax.tree.map(jnp.asarray, prefix_kv)
+                with span("resume.step"):
+                    logits, cache, kv_suffix = self._prefill(
+                        self.params, {"tokens": toks[:, p_len:]}, prefix_kv)
+            else:
+                with span("resume.step"):
+                    logits, cache, kv_suffix = self._prefill(
+                        self.params, {"tokens": toks})
+            # Slice the freshly computed whole chunks into slabs to stage.
+            with span("resume.slice") as sliced:
+                kv_np = jax.tree.map(np.asarray, kv_suffix)
+                slabs: dict[int, Any] = {}
+                for r in range(b):
+                    for c in range(run, n_chunks):
+                        fp = int(fps[r, c])
+                        if fp not in slabs:
+                            lo = c * CHUNK_TOKENS - p_len
+                            slabs[fp] = _slice_chunk(kv_np, r, lo,
+                                                     lo + CHUNK_TOKENS)
+                sliced.set_metadata(nbytes=sum(map(_nbytes,
+                                                   slabs.values())))
         self.resumed_chunks += run * b
         self.computed_chunks += (n_chunks - run) * b
         state = {"logits": logits, "cache": cache, "pos": s}
@@ -212,7 +230,8 @@ class PrefixResumeEngine:
         """Greedy decode from a :meth:`prefill` result (or its bare
         ``state``).  Returns the (B, n_tokens) decoded ids; positions
         continue at the full prompt length regardless of how much
-        prefill was skipped."""
+        prefill was skipped.  Stamps ``state["first_token_at"]`` when the
+        first token reaches the host."""
         state = result.state if isinstance(result, PrefillResult) else result
         n = self.decode_tokens if n_tokens is None else n_tokens
         logits, cache, pos = state["logits"], state["cache"], state["pos"]
@@ -220,12 +239,19 @@ class PrefixResumeEngine:
             raise ValueError(
                 f"decode of {n} tokens from position {pos} overflows "
                 f"max_seq={self.max_seq}")
-        nxt = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-        outs = []
-        for t in range(n):
-            outs.append(np.asarray(nxt))
-            nxt, _, cache = self._decode(
-                self.params, cache, nxt, jnp.int32(pos + t))
+        with span("decode", rows=logits.shape[0], pos=pos, steps=n):
+            nxt = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
+            outs = []
+            for t in range(n):
+                # Each token is read on the host before the next step is
+                # dispatched: the device idles between steps meanwhile.
+                with span("decode.sync"):
+                    outs.append(np.asarray(nxt))
+                if t == 0:
+                    state["first_token_at"] = time.monotonic()
+                with span("decode.dispatch"):
+                    nxt, _, cache = self._decode(
+                        self.params, cache, nxt, jnp.int32(pos + t))
         return np.concatenate(outs, axis=1)
 
     def request_fns(self, n_tokens: int | None = None):
