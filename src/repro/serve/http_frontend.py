@@ -403,6 +403,7 @@ def stats_snapshot(router: ServeRouter) -> dict:
         wear = idx.wear_report()
         istats = dataclasses.asdict(idx.stats)
         hit_rate = round(float(idx.hit_rate), 6)
+    store = idx.slab_store
     with router._cv:
         depth = len(router._queue) + router._inflight
         rstats = dataclasses.asdict(router.stats)
@@ -413,6 +414,7 @@ def stats_snapshot(router: ServeRouter) -> dict:
         "wear": wear,
         "lifetime": dataclasses.asdict(lt),
         "router": rstats | {"depth": depth, "workers": router.n_workers},
+        "slab_store": None if store is None else store.stats(),
     }
 
 

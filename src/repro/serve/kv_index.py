@@ -534,7 +534,7 @@ def _shard_property(name: str, doc: str, settable: bool = True):
 
 
 class KVSlabStore:
-    """Host-side KV slab store kept in LOCKSTEP with the index.
+    """KV slab store kept in LOCKSTEP with the index.
 
     Slabs are keyed by the same ``uint32`` fingerprints the index stores
     in its ``fp_of`` columns, and their lifetime is slaved to the
@@ -548,22 +548,91 @@ class KVSlabStore:
     fingerprints, not (set, way) slots — so resident slabs survive any
     number of rotations by construction.
 
+    Residency: a slab whose leaves are device arrays (what the resume
+    engine stages) stays on the device while the resident device bytes
+    fit ``device_budget``; a commit beyond it copies that slab to host
+    memory, outside the store lock, and swaps the copy in (``spilled``
+    counts these).  The slab is servable throughout.  By default the
+    budget is derived from the device's own ``memory_stats()``: its
+    ``bytes_limit``, less a sixteenth of it for fragmentation, less the
+    largest device footprint the process has reached apart from resident
+    slabs (``peak_bytes_in_use`` read at every commit, minus the
+    resident device bytes when it was read), so that serving's own
+    transients, as large as any seen so far, still fit beside the slabs.
+    A backend without memory stats (the CPU) leaves the tier unbounded;
+    ``device_budget`` (bytes) fixes it instead, for tests.
+
     Thread safety: all methods take the store lock; staging (serving
     thread, right after prefill) may race commits (AdmitQueue worker).
+    The lock is reentrant, so :meth:`get_many` reads through :meth:`get`
+    under one hold of it.
 
     A slab is an arbitrary pytree (per-layer k/v arrays for one chunk);
-    the store never inspects it beyond byte accounting.
+    the store never inspects it beyond byte accounting and residency.
     """
 
-    def __init__(self):
-        self._lock = threading.Lock()
+    def __init__(self, device_budget: int | None = None):
+        self._lock = threading.RLock()
         self._staged: dict[int, object] = {}
-        self._resident: dict[int, object] = {}
+        # fp -> (slab, bytes it takes, whether they are device bytes)
+        self._resident: dict[int, tuple[object, int, bool]] = {}
+        self._fixed_budget = device_budget
+        self._peak_seen = 0          # peak_bytes_in_use at the last read
+        self._other_peak = 0         # largest device footprint apart from slabs
+        self.device_bytes = 0        # device memory of resident slabs
+        self.host_bytes = 0          # host memory of resident slabs
+        self.spilled = 0             # commits moved to host memory
 
     @staticmethod
     def _nbytes(slab) -> int:
         return sum(int(getattr(leaf, "nbytes", 0))
                    for leaf in jax.tree.leaves(slab))
+
+    @staticmethod
+    def _device_of(slab):
+        """The device holding ``slab``'s first device leaf, or None for a
+        host slab."""
+        for leaf in jax.tree.leaves(slab):
+            if isinstance(leaf, jax.Array):
+                return min(leaf.devices(), key=lambda d: d.id)
+        return None
+
+    def _budget(self, device) -> float:
+        """Resident device bytes ``device`` may hold (store lock held)."""
+        if self._fixed_budget is not None:
+            return self._fixed_budget
+        stats = device.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            return float("inf")
+        peak = int(stats.get("peak_bytes_in_use", 0))
+        if peak > self._peak_seen:
+            # The new peak was reached while the resident device bytes
+            # were at most what they are now (each commit reads it first).
+            self._peak_seen = peak
+            self._other_peak = max(self._other_peak,
+                                   peak - self.device_bytes)
+        limit = int(stats["bytes_limit"])
+        return limit - limit // 16 - self._other_peak
+
+    def _hold(self, fp: int, slab, on_device: bool) -> None:
+        self._release(fp)
+        # A device slab is counted as laid out on the device (its tiles
+        # padded), which is what the budget and memory_stats() see.
+        n = (sum(leaf.on_device_size_in_bytes()
+                 for leaf in jax.tree.leaves(slab)) if on_device
+             else self._nbytes(slab))
+        self._resident[fp] = (slab, n, on_device)
+        if on_device:
+            self.device_bytes += n
+        else:
+            self.host_bytes += n
+
+    def _release(self, fp: int) -> None:
+        _, n, on_device = self._resident.pop(fp, (None, 0, False))
+        if on_device:
+            self.device_bytes -= n
+        else:
+            self.host_bytes -= n
 
     def stage(self, fp: int, slab) -> None:
         """Hold a freshly computed slab until its admission decides."""
@@ -572,12 +641,25 @@ class KVSlabStore:
 
     def commit(self, fp: int) -> None:
         """Fingerprint installed (or re-offered while resident): promote
-        its staged slab.  No-op when nothing is staged (e.g. a resident
-        refresh admitted via the slab-less ``admit()`` path)."""
+        its staged slab, to host memory if the device tier is full.
+        No-op when nothing is staged (e.g. a resident refresh admitted
+        via the slab-less ``admit()`` path)."""
+        fp = int(fp)
         with self._lock:
-            slab = self._staged.pop(int(fp), None)
-            if slab is not None:
-                self._resident[int(fp)] = slab
+            slab = self._staged.pop(fp, None)
+            if slab is None:
+                return
+            device = self._device_of(slab)
+            budget = float("inf") if device is None else self._budget(device)
+            self._hold(fp, slab, device is not None)
+            spill = self.device_bytes > budget
+        if spill:
+            host = jax.tree.map(np.asarray, slab)
+            with self._lock:
+                # unless dropped or replaced meanwhile
+                if self._resident.get(fp, (None,))[0] is slab:
+                    self._hold(fp, host, False)
+                    self.spilled += 1
 
     def discard(self, fp: int) -> None:
         """Offer skipped/throttled/shed: the staged slab is garbage."""
@@ -588,13 +670,19 @@ class KVSlabStore:
         """Fingerprint evicted from its way: the resident slab dies with
         it (the lockstep half of the index's eviction)."""
         with self._lock:
-            self._resident.pop(int(fp), None)
+            self._release(int(fp))
 
     def get(self, fp: int):
         """Resident slab for ``fp``, or None (staged slabs are NOT
         servable — their admission has not happened yet)."""
         with self._lock:
-            return self._resident.get(int(fp))
+            return self._resident.get(int(fp), (None,))[0]
+
+    def get_many(self, fps) -> list:
+        """Resident slabs for ``fps`` (None where absent), read under one
+        hold of the store lock, so no commit or drop lands between them."""
+        with self._lock:
+            return [self.get(fp) for fp in fps]
 
     def resident_fps(self) -> set[int]:
         with self._lock:
@@ -607,7 +695,15 @@ class KVSlabStore:
     @property
     def resident_bytes(self) -> int:
         with self._lock:
-            return sum(self._nbytes(s) for s in self._resident.values())
+            return self.device_bytes + self.host_bytes
+
+    def stats(self) -> dict:
+        """Residency counters, for ``GET /stats``."""
+        with self._lock:
+            return {"resident": len(self._resident),
+                    "device_bytes": self.device_bytes,
+                    "host_bytes": self.host_bytes,
+                    "spilled": self.spilled}
 
 
 class MonarchKVIndex:

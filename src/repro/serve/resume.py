@@ -8,15 +8,19 @@ batch (driven by ``launch/serve.py::run_request_loop``):
 1. ``lookup`` (through the AdmitQueue) answers which leading chunks of
    the prompt are cached — ONE fused XAM search for the whole batch.
 2. :meth:`PrefixResumeEngine.prefill` fetches the hit chunks' KV slabs
-   from the index's :class:`~repro.serve.kv_index.KVSlabStore`, assembles
-   them into a ``prefix_kv`` pytree, and runs
+   from the index's :class:`~repro.serve.kv_index.KVSlabStore` in one
+   pass, joins them into a ``prefix_kv`` pytree with jitted
+   concatenations on the device (each row's run, then the rows), and
+   runs
    ``transformer.prefill(prefix_kv=...)`` over ONLY the suffix tokens —
    suffix positions start at the prefix length (the RoPE offset
    contract: resumed tokens attend at their original absolute
    positions), so the resulting cache and logits are bit-identical to a
    full prefill of the whole prompt.
-3. The chunks it DID compute are sliced into per-chunk slabs and handed
-   back (:class:`PrefillResult`), which the request loop stages via
+3. The chunks it DID compute are cut into per-chunk slabs by one jitted
+   split (device arrays: slab bytes stay on the chip from the moment
+   they are cut) and handed back (:class:`PrefillResult`), which the
+   request loop stages via
    ``AdmitQueue.submit_tokens(toks, slabs=...)`` — submit-after-prefill,
    so the async admission worker commits slabs while decode runs.
 4. :meth:`PrefixResumeEngine.decode` greedily decodes from the restored
@@ -74,32 +78,43 @@ class PrefillResult:
 # Slab/kv pytree axis conventions: every leaf is (..., B, S, KV, dh) —
 # the sequence axis is third-from-last, the batch axis fourth-from-last
 # (scanned group leaves carry a leading (G,) axis, remainder leaves do
-# not, so axes are addressed from the right).
+# not, so axes are addressed from the right).  The functions below run
+# jitted on the device: slab bytes never pass through the host on the
+# serving path.  A restore joins each row's run, then the rows: programs
+# keyed by the run length and by the row count apart, not by both, since
+# a program's compile time grows with its number of slab arguments.
 
-def _slice_chunk(tree, row: int, lo: int, hi: int):
-    """One row's [lo, hi) token span of a kv pytree, as host arrays."""
-    def f(a):
-        sl = [slice(None)] * a.ndim
-        sl[a.ndim - 4] = slice(row, row + 1)
-        sl[a.ndim - 3] = slice(lo, hi)
-        return np.ascontiguousarray(a[tuple(sl)])
-    return jax.tree.map(f, tree)
+def split_slabs(kv):
+    """A batch's kv pytree -> per row, a tuple of its whole
+    ``CHUNK_TOKENS`` chunks, each a slab with leaves (..., 1,
+    CHUNK_TOKENS, KV, dh); a trailing partial chunk is left out."""
+    leaf = jax.tree.leaves(kv)[0]
+    rows, n = leaf.shape[-4], leaf.shape[-3] // CHUNK_TOKENS
+
+    def chunk(a, r, c):
+        return a[..., r:r + 1, c * CHUNK_TOKENS:(c + 1) * CHUNK_TOKENS,
+                 :, :]
+    return tuple(tuple(jax.tree.map(lambda a: chunk(a, r, c), kv)
+                       for c in range(n)) for r in range(rows))
 
 
-def _concat_seq(slabs: list):
-    """Concatenate per-chunk slabs along the sequence axis."""
+def join_run(slabs):
+    """One row's run of chunk slabs -> its prefix kv pytree,
+    concatenated along the sequence axis."""
     return jax.tree.map(
-        lambda *xs: np.concatenate(xs, axis=xs[0].ndim - 3), *slabs)
+        lambda *xs: jnp.concatenate(xs, axis=xs[0].ndim - 3), *slabs)
 
 
-def _concat_rows(rows: list):
-    """Concatenate per-row prefixes along the batch axis."""
+def join_rows(rows):
+    """Per-row prefix kv pytrees -> the batch's, concatenated along the
+    batch axis."""
     return jax.tree.map(
-        lambda *xs: np.concatenate(xs, axis=xs[0].ndim - 4), *rows)
+        lambda *xs: jnp.concatenate(xs, axis=xs[0].ndim - 4), *rows)
 
 
-def _nbytes(tree) -> int:
-    return sum(int(a.nbytes) for a in jax.tree.leaves(tree))
+def _nbytes(tree, device_only: bool = False) -> int:
+    return sum(int(a.nbytes) for a in jax.tree.leaves(tree)
+               if not device_only or isinstance(a, jax.Array))
 
 
 class PrefixResumeEngine:
@@ -121,7 +136,8 @@ class PrefixResumeEngine:
     decode_tokens : int
         Default greedy-decode length for :meth:`decode`.
     jit : bool
-        jit the prefill/decode steps (on by default; off for debugging).
+        jit the prefill/decode steps and the slab split/join (on by
+        default; off for debugging).
     """
 
     def __init__(self, params, cfg: ArchConfig, *, max_seq: int,
@@ -151,28 +167,32 @@ class PrefixResumeEngine:
         self._prefill = jax.jit(fn) if jit else fn
         dec = make_decode_step(cfg)
         self._decode = jax.jit(dec) if jit else dec
+        self._join_run = jax.jit(join_run) if jit else join_run
+        self._join_rows = jax.jit(join_rows) if jit else join_rows
+        self._split = jax.jit(split_slabs) if jit else split_slabs
         self.resumed_chunks = 0          # served from slabs, cumulative
         self.computed_chunks = 0         # recomputed, cumulative
 
     # ------------------------------------------------------------------
-    def _resume_run(self, fps: np.ndarray, hits: np.ndarray,
-                    s: int) -> int:
-        """Longest leading run of chunks servable for EVERY row: the
-        chunk hit in the index AND its slab resident.  Capped at
+    def _resume_slabs(self, fps: np.ndarray, hits: np.ndarray,
+                      s: int) -> list[list]:
+        """Per row, the slabs of the longest leading run of chunks
+        servable for EVERY row: the chunk hit in the index AND its slab
+        resident, fetched in one pass over the store.  Capped at
         ``(s-1) // CHUNK_TOKENS`` so at least one suffix token is always
         recomputed (last-token logits seed decode) — for chunk-aligned
         prompts that forces the last chunk out of the run; a partial
         trailing chunk is recomputed anyway and lifts the cap."""
-        b, n_chunks = fps.shape
+        b = fps.shape[0]
         cap = max(s - 1, 0) // CHUNK_TOKENS
-        run = cap
-        for r in range(b):
-            k = 0
-            while (k < cap and hits[r, k]
-                   and self.store.get(int(fps[r, k])) is not None):
-                k += 1
-            run = min(run, k)
-        return run
+        # leading hits of each row: the index of its first miss
+        lead = min(int(np.argmin(np.append(hits[r, :cap], False)))
+                   for r in range(b))
+        got = self.store.get_many(fps[:, :lead].reshape(-1))
+        rows = [got[r * lead:(r + 1) * lead] for r in range(b)]
+        run = min(next((k for k, g in enumerate(row) if g is None), lead)
+                  for row in rows)
+        return [row[:run] for row in rows]
 
     def prefill(self, toks: np.ndarray, hits=None) -> PrefillResult:
         """Restore + partial prefill of one request batch.
@@ -188,17 +208,20 @@ class PrefixResumeEngine:
                 fps = self.index.fingerprints(toks)
                 if hits is None:
                     hits = np.zeros((b, n_chunks), bool)
-                run = self._resume_run(fps, np.asarray(hits, bool), s)
+                prefix = self._resume_slabs(fps, np.asarray(hits, bool), s)
+            run = len(prefix[0])
             p_len = run * CHUNK_TOKENS
             outer.set_metadata(prefix=p_len, suffix=s - p_len)
             if run > 0:
+                # Device slabs join on the device; a slab held in host
+                # memory is uploaded by itself as an argument of the join.
                 with span("resume.restore", rows=b) as restore:
-                    prefix_kv = _concat_rows([
-                        _concat_seq([self.store.get(int(fps[r, k]))
-                                     for k in range(run)])
-                        for r in range(b)])
-                    restore.set_metadata(nbytes=_nbytes(prefix_kv))
-                    prefix_kv = jax.tree.map(jnp.asarray, prefix_kv)
+                    restore.set_metadata(
+                        nbytes=_nbytes(prefix),
+                        device_nbytes=_nbytes(prefix, device_only=True))
+                    prefix_kv = [self._join_run(row) for row in prefix]
+                    prefix_kv = (prefix_kv[0] if b == 1
+                                 else self._join_rows(prefix_kv))
                 with span("resume.step"):
                     logits, cache, kv_suffix = self._prefill(
                         self.params, {"tokens": toks[:, p_len:]}, prefix_kv)
@@ -206,17 +229,16 @@ class PrefixResumeEngine:
                 with span("resume.step"):
                     logits, cache, kv_suffix = self._prefill(
                         self.params, {"tokens": toks})
-            # Slice the freshly computed whole chunks into slabs to stage.
+            # Cut the freshly computed whole chunks into slabs to stage,
+            # on the device; the first row holding a fingerprint gives it.
             with span("resume.slice") as sliced:
-                kv_np = jax.tree.map(np.asarray, kv_suffix)
                 slabs: dict[int, Any] = {}
-                for r in range(b):
-                    for c in range(run, n_chunks):
-                        fp = int(fps[r, c])
-                        if fp not in slabs:
-                            lo = c * CHUNK_TOKENS - p_len
-                            slabs[fp] = _slice_chunk(kv_np, r, lo,
-                                                     lo + CHUNK_TOKENS)
+                if n_chunks > run:
+                    pieces = self._split(kv_suffix)
+                    for r in range(b):
+                        for c in range(run, n_chunks):
+                            slabs.setdefault(int(fps[r, c]),
+                                             pieces[r][c - run])
                 sliced.set_metadata(nbytes=sum(map(_nbytes,
                                                    slabs.values())))
         self.resumed_chunks += run * b

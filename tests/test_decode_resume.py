@@ -280,6 +280,102 @@ def test_engine_truncates_run_at_missing_slab(rng):
 
 
 # ---------------------------------------------------------------------------
+# Slab residency: device slabs, host slabs and a mix restore the same bits.
+# ---------------------------------------------------------------------------
+
+def _slab_bytes(cfg) -> int:
+    """Bytes of one chunk's slab (every layer's k and v)."""
+    kv = jax.eval_shape(
+        lambda: transformer.prefill(
+            transformer.init_params(jax.random.PRNGKey(0), cfg), cfg,
+            {"tokens": jnp.zeros((1, CHUNK_TOKENS), jnp.int32)},
+            CHUNK_TOKENS, return_kv=True)[2])
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(kv))
+
+
+def _host_restore(slabs):
+    """The restore as it was done on the host: per-row runs concatenated
+    with NumPy along the sequence axis, then the rows, then one upload."""
+    def cat(axis_from_right, trees):
+        return jax.tree.map(lambda *xs: np.concatenate(
+            xs, axis=xs[0].ndim - axis_from_right), *trees)
+    rows = [cat(3, [jax.tree.map(np.asarray, s) for s in row])
+            for row in slabs]
+    return jax.tree.map(jnp.asarray, cat(4, rows))
+
+
+# Device budget of each residency, in slabs: unbounded (the CPU backend
+# reports no memory stats), none, or three of the eight first computed.
+RESIDENCY = {"device": None, "host": 0, "mixed": 3}
+
+
+@pytest.mark.parametrize("residency", list(RESIDENCY))
+@pytest.mark.parametrize("kind", ["global", "remainder"])
+def test_slab_restore_bit_identity(rng, kind, residency):
+    """A resumed prefill from device-resident slabs, from slabs spilled to
+    host memory, and from a mix of both, through the jitted engine: its
+    logits, decode cache and decoded tokens equal bit for bit those of
+    the host-side restore (NumPy concatenation and one upload of the same
+    slabs), and match a full prefill of the whole prompt as the
+    transformer-level test does.  ``global`` is yi-9b (every layer in the
+    scanned group), ``remainder`` gemma3 at 7 layers (a scanned group of
+    6 plus one unscanned layer)."""
+    cfg = (_arch("global") if kind == "global" else dataclasses.replace(
+        configs.get_arch("gemma3-27b").reduced(), n_layers=7))
+    if kind == "remainder":
+        assert cfg.scan_groups()[1] == 1 and len(cfg.scan_groups()[2]) == 1
+    params = transformer.init_params(jax.random.PRNGKey(1), cfg)
+    n = _slab_bytes(cfg)
+    budget = RESIDENCY[residency]
+    store = KVSlabStore(device_budget=None if budget is None
+                        else budget * n)
+    idx = MonarchKVIndex(KVIndexConfig(
+        n_sets=8, set_ways=8, admit_after_reads=0, rotate_every=1 << 30,
+        fingerprint="prefix"), slab_store=store)
+    engine = PrefixResumeEngine(params, cfg, max_seq=68, index=idx,
+                                decode_tokens=3)
+    q = AdmitQueue(idx)
+    try:
+        toks = rng.integers(1, cfg.vocab_size, (2, 64)).astype(np.int32)
+        first = engine.prefill(toks, q.lookup(toks))
+        assert len(first.slabs) == 8
+        q.submit_tokens(toks, slabs=first.slabs)
+        q.flush()
+        on_device = 8 if budget is None else budget
+        assert store.stats() == {"resident": 8,
+                                 "device_bytes": on_device * n,
+                                 "host_bytes": (8 - on_device) * n,
+                                 "spilled": 8 - on_device}
+        res = engine.prefill(toks, q.lookup(toks))
+        assert res.resumed_chunks == 2 * 3
+    finally:
+        q.close()
+    fps = idx.fingerprints(toks)
+    slabs = [store.get_many(fps[r, :3]) for r in range(2)]
+    placed = {isinstance(a, jax.Array)
+              for row in slabs for a in jax.tree.leaves(row)}
+    assert placed == {"device": {True}, "host": {False},
+                      "mixed": {True, False}}[residency]
+    lg_h, cache_h, _ = engine._prefill(
+        params, {"tokens": toks[:, 3 * CHUNK_TOKENS:]}, _host_restore(slabs))
+    got = res.state
+    np.testing.assert_array_equal(np.asarray(got["logits"]),
+                                  np.asarray(lg_h))
+    for a, b in zip(jax.tree.leaves(got["cache"]), jax.tree.leaves(cache_h)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    dec = engine.decode(res)
+    np.testing.assert_array_equal(
+        dec, engine.decode({"logits": lg_h, "cache": cache_h, "pos": 64}))
+    lg_f, cache_f, _ = engine._prefill(params, {"tokens": toks})
+    _arrays_match(got["logits"], lg_f, residency)
+    _tree_match(got["cache"], cache_f, residency)
+    _, margin = _greedy(params, cfg, lg_f, cache_f, 64)
+    want = engine.decode({"logits": lg_f, "cache": cache_f, "pos": 64})
+    clear = margin > 2 * _tol(lg_f)
+    np.testing.assert_array_equal(dec[clear], want[clear])
+
+
+# ---------------------------------------------------------------------------
 # Schedule replay: shard counts + the fan-out oracle stay in lockstep.
 # ---------------------------------------------------------------------------
 

@@ -214,3 +214,34 @@ def test_yi9b_resume_prefill_and_decode_compile(yi9b, one_chip):
     jax.jit(make_decode_step(cfg)).lower(
         params, cache, _sds((b, 1), jnp.int32, one_chip),
         _sds((), jnp.int32, one_chip)).compile()
+
+
+def test_yi9b_slab_split_and_join_compile(yi9b, one_chip):
+    """The resume engine's device slab programs: the split of a batch's
+    new KV into chunk slabs, one row's join along the sequence axis and
+    the join of the rows.  A slab's (..., 16, 4, 128) bf16 leaves take
+    no more device bytes than their elements: the tiled layout pads
+    nothing."""
+    from repro.serve.resume import join_rows, join_run, split_slabs
+    cfg, params = yi9b
+    rows, chunks = 2, 4
+    kv = jax.eval_shape(
+        make_resume_prefill_step(cfg, chunks * kv_index.CHUNK_TOKENS),
+        params, {"tokens": jax.ShapeDtypeStruct(
+            (rows, chunks * kv_index.CHUNK_TOKENS), jnp.int32)})[2]
+    kv = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), kv)
+    split = jax.jit(split_slabs).lower(kv).compile()
+    slabs = jax.eval_shape(split_slabs, kv)
+    assert len(slabs) == rows and len(slabs[0]) == chunks
+    slab_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(slabs[0][0]))
+    assert split.memory_analysis().output_size_in_bytes >= \
+        rows * chunks * slab_bytes
+    run = [jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), s)
+           for s in slabs[0]]
+    joined = jax.jit(join_run).lower(run).compile()
+    assert joined.memory_analysis().argument_size_in_bytes == \
+        chunks * slab_bytes
+    row = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                       jax.eval_shape(join_run, run))
+    _compile(join_rows, [row] * rows)

@@ -165,8 +165,11 @@ def test_prefill_args_are_the_shapes(served):
     (restore,) = _named(served, "monarch.resume.restore")
     assert _inside(restore, hit)
     restored = served["on"][2]
-    assert restore.args == {"rows": ROWS, "nbytes": sum(
-        a.nbytes for slab in restored for a in jax.tree.leaves(slab))}
+    nbytes = sum(a.nbytes for slab in restored
+                 for a in jax.tree.leaves(slab))
+    # The slabs live on the device (unbounded tier on the CPU backend).
+    assert restore.args == {"rows": ROWS, "nbytes": nbytes,
+                            "device_nbytes": nbytes}
     first_slice = _named(served, "monarch.resume.slice")[0]
     assert first_slice.args["nbytes"] == ROWS * PROMPT // CHUNK_TOKENS \
         * sum(a.nbytes for a in jax.tree.leaves(restored[0]))
