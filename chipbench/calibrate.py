@@ -46,7 +46,6 @@ def main(argv=None) -> int:
     import jax
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     from chipbench import harness, traffic
-    from chipbench.reference import dense_lm
 
     if jax.devices()[0].platform != "tpu":
         raise SystemExit("calibration reads the chip: no TPU found")
@@ -81,11 +80,11 @@ def main(argv=None) -> int:
     readings, dump = [], {}
     for k, (seed, prompts, served) in enumerate(samples):
         ref = harness.reference_logits(cell, prompts, served)
-        gaps = {"program": dense_lm.served_gap(ref, served)}
+        gaps = {"program": harness.served_gap(ref, served)}
         if k < args.control_seeds:
             for q in QUANTS:
                 ctl = harness.reference_logits(cell, prompts, served, quant=q)
-                gaps[q] = dense_lm.served_gap(ref, ctl.argmax(-1))
+                gaps[q] = harness.served_gap(ref, ctl.argmax(-1))
         line = {"seed": seed}
         for who, g in gaps.items():
             line[who] = harness.gap_stats(g)

@@ -6,6 +6,13 @@ Everything a cell needs is found by name in data files: the cell in
 ``traffic/``, its correctness limits in ``limits/<cell>.json``, and each
 per-layer metric's reader in ``metrics/``.  Adding a cell is adding such
 files and entries.
+
+A configuration names its plain reference with the key ``reference``:
+the module ``reference/<reference>.py``, which gives the logits that
+``correct`` compares with the served tokens and the counts of a model
+step that ``cost`` hands to the readers (the interface is in
+``reference/__init__.py``).  A new architecture is a new reference
+module beside its configuration; nothing here names one.
 """
 from __future__ import annotations
 
@@ -23,7 +30,7 @@ import urllib.request
 
 import numpy as np
 
-from chipbench import traffic
+from chipbench import reference, traffic
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -68,6 +75,20 @@ def _reports(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def load_config(path: pathlib.Path, root: pathlib.Path = ROOT) -> dict:
+    """A configuration file, with the file of the reference module it
+    names (``chipbench/reference/<reference>.py`` under ``root``) added
+    under ``reference_path``.  The key has no default."""
+    cfg = json.loads(path.read_text())
+    if "reference" not in cfg:
+        raise KeyError(f"{path}: no 'reference' key naming its module in "
+                       "chipbench/reference/")
+    ref = root / "chipbench" / "reference" / f"{cfg['reference']}.py"
+    if not ref.is_file():
+        raise FileNotFoundError(f"{path}: reference module {ref} not found")
+    return {**cfg, "reference_path": str(ref)}
+
+
 def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     bench = json.loads((root / "BENCHMARK.json").read_text())
     specs = {w["name"]: w for w in bench["workloads"]}
@@ -75,7 +96,7 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(specs)}")
     spec = specs[name]
     cfgs = {c["name"]: c for c in bench["configs"]}
-    cfg = json.loads((root / cfgs[spec["config"]]["file"]).read_text())
+    cfg = load_config(root / cfgs[spec["config"]]["file"], root)
     mix = traffic.load(root / "chipbench" / "traffic"
                        / f"{spec['traffic']}.json")
     limits = json.loads(
@@ -548,14 +569,22 @@ def sample(cell: Cell, plan: traffic.Plan, answered: list, seed: int):
 
 def reference_logits(cell: Cell, prompts, served, quant=None):
     """Reference logits at every served position: the prompt and the
-    served tokens before each one, in blocks of ``reference_rows``."""
-    from chipbench.reference import dense_lm
+    served tokens before each one, in blocks of ``reference_rows``, from
+    the reference module the configuration names."""
+    logits = reference.module(cell.cfg).logits
     seq = np.concatenate([prompts, served[:, :-1]], axis=1)
     rows = cell.limits["reference_rows"]
     return np.concatenate([
-        dense_lm.logits(cell.cfg, seq[lo:lo + rows], prompts.shape[1] - 1,
-                        quant=quant)
+        logits(cell.cfg, seq[lo:lo + rows], prompts.shape[1] - 1,
+               quant=quant)
         for lo in range(0, len(seq), rows)])
+
+
+def served_gap(ref: np.ndarray, served: np.ndarray) -> np.ndarray:
+    """How far each served token's reference logit lies below the
+    reference's best at its position: ref (B, T, V), served (B, T)."""
+    chosen = np.take_along_axis(ref, served[..., None], axis=-1)[..., 0]
+    return ref.max(axis=-1) - chosen
 
 
 #: The numbers compared on the served tokens' gaps below the reference's
@@ -578,12 +607,11 @@ def gap_stats(gaps: np.ndarray) -> dict:
 def check_sample(cell: Cell, plan: traffic.Plan, answered: list, seed: int):
     """Gaps between the reference's best logit and each served token's,
     over a sample of answered requests drawn from the seed."""
-    from chipbench.reference import dense_lm
     if not answered:
         return np.zeros((0, 0), np.float32)
     prompts, served = sample(cell, plan, answered, seed)
     ref = reference_logits(cell, prompts, served)
-    return dense_lm.served_gap(ref, served)
+    return served_gap(ref, served)
 
 
 # ---------------------------------------------------------------------------

@@ -24,6 +24,13 @@ jitted program per layer, so the whole float32 model is never resident.
 ``quant`` gives the control: the same forward with every weight matrix
 rounded to int8 (per output channel) or float8 e4m3, the precision step
 below the bfloat16 the configurations serve in.
+
+Every configuration with ``"reference": "dense_lm"`` is checked by
+``logits`` and counted by ``prefill_flops``, ``decode_flops`` and
+``decode_bytes`` here (``cost`` dispatches to them): attention and the
+MLP of every layer for each computed token, the output head for the
+one position a prefill or a decode step answers.  These four functions
+are what every reference module provides (``reference/__init__.py``).
 """
 from __future__ import annotations
 
@@ -33,6 +40,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+BF16 = 2
 
 
 def _bf16_normal(key, shape, scale):
@@ -184,8 +193,60 @@ def logits(cfg: dict, tokens: np.ndarray, first: int, quant=None):
     return np.asarray(out, np.float32)
 
 
-def served_gap(ref: np.ndarray, served: np.ndarray) -> np.ndarray:
-    """How far each served token's reference logit lies below the
-    reference's best at its position: ref (B, T, V), served (B, T)."""
-    chosen = np.take_along_axis(ref, served[..., None], axis=-1)[..., 0]
-    return ref.max(axis=-1) - chosen
+# ---------------------------------------------------------------------------
+# Counts: what a prefill or a decode step needs, from shapes alone.
+# ---------------------------------------------------------------------------
+
+def layer_matmul_params(cfg: dict) -> int:
+    """Weights one decoder layer multiplies by (attention + MLP)."""
+    d, f = cfg["hidden_size"], cfg["intermediate_size"]
+    q = cfg["num_attention_heads"] * cfg["head_dim"]
+    kv = cfg["num_key_value_heads"] * cfg["head_dim"]
+    mlp = (3 if cfg["hidden_act"] == "silu" else 2) * d * f
+    return d * q + 2 * d * kv + q * d + mlp
+
+
+def weight_bytes(cfg: dict) -> int:
+    """Bytes of every served weight a decode step must read: all layers
+    and the output head (the embedding table is gathered a row a token)."""
+    return BF16 * (cfg["num_hidden_layers"] * layer_matmul_params(cfg)
+                   + cfg["hidden_size"] * cfg["vocab_size"])
+
+
+def kv_bytes_per_token(cfg: dict) -> int:
+    return (BF16 * 2 * cfg["num_hidden_layers"] * cfg["num_key_value_heads"]
+            * cfg["head_dim"])
+
+
+def _attn_flops(cfg: dict, q_tokens: int, first_pos: int) -> float:
+    """Causal attention of ``q_tokens`` queries at positions
+    ``first_pos..first_pos+q_tokens-1``: QK^T and PV, keys up to and
+    including each query's own position."""
+    keys = q_tokens * first_pos + q_tokens * (q_tokens + 1) / 2
+    return (4.0 * cfg["num_hidden_layers"] * cfg["num_attention_heads"]
+            * cfg["head_dim"] * keys)
+
+
+def prefill_flops(cfg: dict, rows: int, prefix: int, suffix: int) -> float:
+    """Model FLOPs of a prefill that computes ``suffix`` tokens per row
+    after a restored ``prefix``: the layers for every computed token,
+    attention over prefix and suffix, the output head for the last."""
+    lin = 2.0 * cfg["num_hidden_layers"] * layer_matmul_params(cfg) * suffix
+    head = 2.0 * cfg["hidden_size"] * cfg["vocab_size"]
+    return rows * (lin + _attn_flops(cfg, suffix, prefix) + head)
+
+
+def decode_flops(cfg: dict, rows: int, pos: int) -> float:
+    """One decode step writing position ``pos`` for ``rows`` rows."""
+    lin = 2.0 * cfg["num_hidden_layers"] * layer_matmul_params(cfg)
+    head = 2.0 * cfg["hidden_size"] * cfg["vocab_size"]
+    return rows * (lin + _attn_flops(cfg, 1, pos) + head)
+
+
+def decode_bytes(cfg: dict, rows: int, pos: int) -> float:
+    """Bytes one decode step must move: the weights once, each row's
+    embedding row and cache keys/values up to ``pos``, and the new
+    key/value it writes."""
+    kv = kv_bytes_per_token(cfg)
+    return (weight_bytes(cfg)
+            + rows * (BF16 * cfg["hidden_size"] + kv * (pos + 1) + kv))

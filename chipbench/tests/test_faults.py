@@ -17,7 +17,7 @@ def _cell():
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     return harness.Cell(
         name="tiny", spec={"name": "tiny", "chips": 1},
-        cfg=json.loads((TINY / "tiny-yi.json").read_text()),
+        cfg=harness.load_config(TINY / "tiny-yi.json"),
         mix=traffic.load(TINY / "tiny-shared.json"),
         limits=json.loads((TINY / "tiny-limits.json").read_text()),
         end_to_end=[m for m in bench["end_to_end"]

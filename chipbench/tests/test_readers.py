@@ -1,7 +1,6 @@
 """The per-layer readers on a made-up trace: program runs cut by the
 window's edges are left out, and the shares follow from the cost
 functions and the peaks."""
-import json
 import pathlib
 import types
 
@@ -10,7 +9,7 @@ import pytest
 from chipbench import cost, harness
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
-CFG = json.loads((ROOT / "chipbench/configs/yi-9b-24L.json").read_text())
+CFG = harness.load_config(ROOT / "chipbench/configs/yi-9b-24L.json")
 PEAKS = {"bf16_flops": 197e12, "int8_ops": 393e12, "hbm_bytes_per_s": 819e9}
 
 

@@ -10,6 +10,7 @@ import pytest
 
 import jax
 
+from chipbench import harness
 from chipbench.reference import dense_lm
 
 PREFIX, SUFFIX, DECODE, ROWS = 48, 16, 16, 8
@@ -69,9 +70,9 @@ def test_program_matches_reference_and_control_does_not(arch):
     for toks, dec in served:
         seq = np.concatenate([toks, dec[:, :-1]], axis=1)
         ref = dense_lm.logits(rc, seq, toks.shape[1] - 1)
-        program = dense_lm.served_gap(ref, dec).max()
+        program = harness.served_gap(ref, dec).max()
         ctl = dense_lm.logits(rc, seq, toks.shape[1] - 1, quant="fp8")
-        control = dense_lm.served_gap(ref, ctl.argmax(-1)).max()
+        control = harness.served_gap(ref, ctl.argmax(-1)).max()
         # bf16 rounding only: the served tokens are the reference's best
         # or within a few bf16 ulps of a logit of magnitude ~4.
         assert program <= 0.05, program
