@@ -13,11 +13,13 @@ from repro.configs.falcon_mamba_7b import CONFIG as _falcon_mamba
 from repro.configs.hubert_xlarge import CONFIG as _hubert
 from repro.configs.qwen3_moe_30b_a3b import CONFIG as _qwen3
 from repro.configs.arctic_480b import CONFIG as _arctic
+from repro.configs.granite_4_0_h_micro import CONFIG as _granite_h_micro
 
 ARCHS: dict[str, ArchConfig] = {
     c.name: c for c in [
         _gemma3, _starcoder2, _command_r, _yi, _zamba2,
         _paligemma, _falcon_mamba, _hubert, _qwen3, _arctic,
+        _granite_h_micro,
     ]
 }
 
@@ -35,7 +37,7 @@ def get_shape(name: str) -> ShapeConfig:
 
 
 def all_cells():
-    """All (arch, shape, runnable, reason) assignment cells (10 x 4)."""
+    """All (arch, shape, runnable, reason) assignment cells (arch x shape)."""
     out = []
     for a in ARCHS.values():
         for s in SHAPES.values():

@@ -38,6 +38,9 @@ class ArchConfig:
     causal: bool = True
     encoder_only: bool = False
     logit_softcap: float = 0.0
+    # Keys a step of the online-softmax attention scans: its fp32 logits
+    # take rows x heads x queries x attn_kv_chunk x 4 bytes in a prefill.
+    attn_kv_chunk: int = 1024
     # MoE.
     n_experts: int = 0
     top_k: int = 0
@@ -50,11 +53,26 @@ class ArchConfig:
     ssm_conv: int = 4
     ssm_head_dim: int = 64
     shared_attn_every: int = 0      # zamba2: shared attn block cadence
+    # Hybrid of Mamba-2 and attention layers in a fixed period (granite-4.0-h):
+    # layer i is attention when i % attn_period == attn_index, else Mamba-2.
+    attn_period: int = 0
+    attn_index: int = 0
+    ssm_mlp: bool = False           # an MLP after every Mamba layer too
+    ssm_chunk: int = 256            # Mamba-2 SSD chunk (mamba_chunk_size)
     # Multimodal stub frontends.
     n_prefix_embeds: int = 0        # vlm: image patches; audio: frames are the seq
     # Norm/MLP details.
     mlp_gated: bool = True          # SwiGLU vs plain GELU
     tie_embeddings: bool = False
+    embed_init_std: float = 0.02    # std of the embedding's random draw
+    rms_norm_eps: float = 1e-6
+    # Published scalars (granite): None / 1.0 keep the plain transformer:
+    # embeddings times sqrt(d_model), softmax scale d_head ** -0.5, residual
+    # branches unscaled, logits undivided.
+    embedding_multiplier: float | None = None
+    attention_multiplier: float | None = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # §Perf knobs (beyond-paper; defaults = the measured baseline).
     moe_dispatch: str = "gather"    # "gather" | "einsum" (GShard one-hot)
     # Sequence-sharded attention (megatron-SP style): shard the sequence
@@ -91,6 +109,9 @@ class ArchConfig:
         """Expanded per-layer kinds, length n_layers."""
         if self.family == "ssm":
             return [MAMBA1] * self.n_layers
+        if self.family == "hybrid" and self.attn_period:
+            return [ATTN_GLOBAL if i % self.attn_period == self.attn_index
+                    else MAMBA2 for i in range(self.n_layers)]
         if self.family == "hybrid":
             out = []
             for i in range(self.n_layers):
@@ -116,7 +137,7 @@ class ArchConfig:
         pat = self.layer_pattern()
         if self.local_global_pattern > 0 or self.family == "hybrid":
             g = (self.local_global_pattern + 1 if self.local_global_pattern
-                 else self.shared_attn_every)
+                 else self.attn_period or self.shared_attn_every)
         else:
             g = 1
         g = max(g, 1)
@@ -128,7 +149,9 @@ class ArchConfig:
         """Smoke-test variant: same family/topology, tiny dims."""
         return dataclasses.replace(
             self,
-            n_layers=min(self.n_layers, 4 if self.family != "hybrid" else 6),
+            # a hybrid keeps one whole period of its layer pattern
+            n_layers=min(self.n_layers, self.attn_period
+                         or (4 if self.family != "hybrid" else 6)),
             d_model=128,
             n_heads=4,
             n_kv_heads=min(self.n_kv_heads, 4) if self.n_kv_heads > 1 else 1,

@@ -132,7 +132,7 @@ def build_frontend(args) -> tuple[HttpFrontend, AdmitQueue]:
 
     with mesh:
         params = init_placed_params(cfg, mesh)
-        prefill_fn, decode_fn, _ = build_model_fns(
+        prefill_fn, decode_fn, engine = build_model_fns(
             params, cfg, max_seq=max_seq,
             decode_tokens=args.decode_tokens, index=idx, resume=resume)
         # one throwaway prefill compiles the hot path before the socket
@@ -146,7 +146,8 @@ def build_frontend(args) -> tuple[HttpFrontend, AdmitQueue]:
     router = ServeRouter(
         admit_q, prefill_fn=prefill_fn, decode_fn=decode_fn,
         n_workers=args.n_workers, max_queue=args.max_queue,
-        batch_window_s=args.batch_window_ms / 1e3)
+        batch_window_s=args.batch_window_ms / 1e3,
+        resume_stats=None if engine is None else engine.stats)
     frontend = HttpFrontend(router, host=args.host, port=args.port,
                             verbose=args.verbose)
     if args.profiler_port is not None:

@@ -98,19 +98,27 @@ def _soft_cap(logits, cap: float):
     return logits
 
 
+def attn_scale(cfg: ArchConfig) -> float:
+    """Softmax scale: the published ``attention_multiplier``, else
+    d_head ** -0.5."""
+    if cfg.attention_multiplier is not None:
+        return cfg.attention_multiplier
+    return cfg.d_head ** -0.5
+
+
 def chunked_attention(q, k, v, *, causal: bool, window: int, softcap: float,
-                      q_offset, kv_chunk: int = 1024):
+                      q_offset, kv_chunk: int = 1024, scale=None):
     """Online-softmax attention, scanning KV in chunks.
 
     q: (B, Sq, H, dh); k/v: (B, Skv, KV, dh).  ``q_offset`` = absolute
     position of q[0] relative to k[0] (0 for self-attn; >0 for decode).
     window > 0 applies sliding-window masking (local attention).
-    Returns (B, Sq, H, dh).
+    ``scale`` defaults to dh ** -0.5.  Returns (B, Sq, H, dh).
     """
     b, sq, h, dh = q.shape
     skv, kvh = k.shape[1], k.shape[2]
     rep = h // kvh
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
     kv_chunk = min(kv_chunk, skv)
     n_chunks = (skv + kv_chunk - 1) // kv_chunk
     pad = n_chunks * kv_chunk - skv
@@ -169,8 +177,7 @@ def _seq_shard(t, cfg: ArchConfig):
     return jax.lax.with_sharding_constraint(t, spec)
 
 
-def attention_block(params, x, cfg: ArchConfig, positions, *, local: bool,
-                    kv_chunk: int = 1024):
+def attention_block(params, x, cfg: ArchConfig, positions, *, local: bool):
     """Self-attention over x (B, S, D)."""
     q, k, v = _qkv(params, x, cfg, positions)
     q, k, v = _seq_shard(q, cfg), _seq_shard(k, cfg), _seq_shard(v, cfg)
@@ -178,7 +185,7 @@ def attention_block(params, x, cfg: ArchConfig, positions, *, local: bool,
     out = chunked_attention(
         q, k, v, causal=cfg.causal and not cfg.encoder_only,
         window=window, softcap=cfg.logit_softcap, q_offset=0,
-        kv_chunk=kv_chunk)
+        kv_chunk=cfg.attn_kv_chunk, scale=attn_scale(cfg))
     b, s, _, _ = out.shape
     return _seq_shard(out.reshape(b, s, -1) @ params["wo"], cfg)
 
@@ -202,7 +209,7 @@ def decode_attention(params, x, cfg: ArchConfig, cache_k, cache_v, pos,
     s_max = cache_k.shape[1]
     kvh = cfg.n_kv_heads
     rep = cfg.n_heads // kvh
-    scale = cfg.d_head ** -0.5
+    scale = attn_scale(cfg)
     qg = (q * scale).astype(DTYPE).reshape(b, 1, kvh, rep, cfg.d_head)
     logits = jnp.einsum("bqgrd,bcgd->bgrqc", qg, cache_k.astype(DTYPE),
                         preferred_element_type=jnp.float32)
@@ -242,7 +249,7 @@ def decode_attention_ring(params, x, cfg: ArchConfig, cache_k, cache_v, pos,
 
     kvh = cfg.n_kv_heads
     rep = cfg.n_heads // kvh
-    scale = cfg.d_head ** -0.5
+    scale = attn_scale(cfg)
     qg = (q * scale).astype(DTYPE).reshape(b, 1, kvh, rep, cfg.d_head)
     logits = jnp.einsum("bqgrd,bcgd->bgrqc", qg, cache_k.astype(DTYPE),
                         preferred_element_type=jnp.float32)
@@ -289,7 +296,8 @@ def mlp_block(params, x, cfg: ArchConfig):
 
 def init_embed(key, cfg: ArchConfig):
     k = split_keys(key, 2)
-    p = {"embed": dense_init(k[0], (cfg.vocab_size, cfg.d_model), scale=0.02)}
+    p = {"embed": dense_init(k[0], (cfg.vocab_size, cfg.d_model),
+                             scale=cfg.embed_init_std)}
     if not cfg.tie_embeddings:
         p["unembed"] = dense_init(k[1], (cfg.d_model, cfg.vocab_size))
     return p
@@ -306,9 +314,11 @@ def unembed_logits(params, x):
     return jnp.einsum("bsd,dv->bsv", x, w, preferred_element_type=jnp.float32)
 
 
-def chunked_ce_loss(params, x, labels, *, chunk: int = 512):
+def chunked_ce_loss(params, x, labels, *, chunk: int = 512,
+                    logits_scaling: float = 1.0):
     """Cross-entropy over the vocab, scanning sequence chunks so the full
-    (B, S, V) logits plane is never resident (rematted chunk body)."""
+    (B, S, V) logits plane is never resident (rematted chunk body).
+    The logits are divided by ``logits_scaling`` (granite's)."""
     b, s, d = x.shape
     chunk = min(chunk, s)
     n_chunks = (s + chunk - 1) // chunk
@@ -321,6 +331,8 @@ def chunked_ce_loss(params, x, labels, *, chunk: int = 512):
         xc = jax.lax.dynamic_slice_in_dim(x, idx * chunk, chunk, axis=1)
         lc = jax.lax.dynamic_slice_in_dim(labels, idx * chunk, chunk, axis=1)
         logits = unembed_logits(params, xc)                   # (B, C, V) fp32
+        if logits_scaling != 1.0:
+            logits = logits / logits_scaling
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(
             logits, jnp.maximum(lc, 0)[..., None], axis=-1)[..., 0]

@@ -51,10 +51,12 @@ def init_mamba1(key, cfg: ArchConfig):
     }
 
 
-def _causal_depthwise_conv(x, w, b):
-    """x: (B, S, C); w: (K, C) — causal per-channel conv, unrolled taps."""
+def _causal_depthwise_conv(x, w, b, tail=None):
+    """x: (B, S, C); w: (K, C) — causal per-channel conv, unrolled taps.
+    ``tail`` (B, K-1, C) holds the inputs before x[0] (zeros if None)."""
     k = w.shape[0]
-    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    xp = (jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))) if tail is None
+          else jnp.concatenate([tail.astype(x.dtype), x], axis=1))
     s = x.shape[1]
     acc = jnp.zeros_like(x, dtype=jnp.float32)
     for i in range(k):
@@ -169,17 +171,28 @@ def init_mamba2(key, cfg: ArchConfig):
         "conv_w": layers.dense_init(k[1], (cfg.ssm_conv, di + 2 * n), scale=0.5),
         "conv_b": jnp.zeros((di + 2 * n,), DTYPE),
         "a_log": jnp.log(jnp.linspace(1.0, 16.0, h)).astype(jnp.float32),
-        "dt_b": (jnp.log(jnp.expm1(jnp.full((h,), 0.01)))).astype(jnp.float32),
+        # Mamba-2's dt range [0.001, 0.1], spread over the heads: the
+        # slowest keep a state over about a thousand tokens.
+        "dt_b": jnp.log(jnp.expm1(jnp.geomspace(0.001, 0.1, h))
+                        ).astype(jnp.float32),
         "d_skip": jnp.ones((h,), jnp.float32),
         "norm_w": jnp.zeros((di,), DTYPE),
         "out_proj": layers.dense_init(k[2], (di, cfg.d_model)),
     }
 
 
-def mamba2_block(params, x, cfg: ArchConfig, *, chunk: int = 256,
-                 return_state: bool = False):
-    """SSD forward, chunked (Mamba-2 minimal algorithm).  x: (B,S,D).
-    With ``return_state``: also returns (h_final, conv_tail) for prefill."""
+def mamba2_block(params, x, cfg: ArchConfig, *, h0=None, conv0=None,
+                 return_state: bool = False, snap_at: int | None = None):
+    """SSD forward, chunked (Mamba-2 minimal algorithm) in chunks of
+    ``cfg.ssm_chunk`` tokens.  x: (B,S,D).
+
+    ``h0`` (B, H, P, N) fp32 and ``conv0`` (B, K-1, di + 2n) start the
+    block mid-sequence, from the state and conv tail a previous call
+    returned (zeros when None).  With ``return_state``: also returns
+    (h_final, conv_tail) for prefill.  With ``snap_at`` (a token offset,
+    a multiple of the chunk): the returns gain the (state, conv tail)
+    after the first ``snap_at`` tokens, taken between the chunk scans,
+    so no state is kept at any other boundary."""
     bsz, s, _ = x.shape
     di, n, h = d_inner(cfg), cfg.ssm_state, m2_heads(cfg)
     p = cfg.ssm_head_dim
@@ -188,13 +201,13 @@ def mamba2_block(params, x, cfg: ArchConfig, *, chunk: int = 256,
     xbc_raw = x @ params["wxbc"]
     dt_in = x @ params["wdt"]
     xbc = jax.nn.silu(_causal_depthwise_conv(xbc_raw, params["conv_w"],
-                                             params["conv_b"]))
+                                             params["conv_b"], conv0))
     xh, b_in, c_in = jnp.split(xbc, [di, di + n], axis=-1)
     dt = jax.nn.softplus(dt_in.astype(jnp.float32) + params["dt_b"])   # (B,S,H)
     a = -jnp.exp(params["a_log"])                                      # (H,)
     log_a = dt * a[None, None, :]                                      # (B,S,H) <= 0
 
-    chunk = min(chunk, s)
+    chunk = min(cfg.ssm_chunk, s)
     n_chunks = (s + chunk - 1) // chunk
     pad = n_chunks * chunk - s
     if pad:
@@ -235,18 +248,39 @@ def mamba2_block(params, x, cfg: ArchConfig, *, chunk: int = 256,
         chunk_state = jnp.einsum("blh,bln,blhp->bhpn", tail * d, bc, xc)
         return new_state + chunk_state, y
 
-    h0 = jnp.zeros((bsz, h, p, n), jnp.float32)
-    h_final, ys = jax.lax.scan(jax.checkpoint(chunk_body), h0, jnp.arange(n_chunks))
+    if h0 is None:
+        h0 = jnp.zeros((bsz, h, p, n), jnp.float32)
+    body = jax.checkpoint(chunk_body)
+    if snap_at is None:
+        h_final, ys = jax.lax.scan(body, h0, jnp.arange(n_chunks))
+    else:
+        # Two scans meeting at the snapshot boundary: the state there is
+        # the first scan's carry.
+        if snap_at % chunk or not 0 <= snap_at <= s:
+            raise ValueError(f"snapshot at token {snap_at}: not a chunk "
+                             f"boundary of {s} tokens in chunks of {chunk}")
+        j = snap_at // chunk
+        h_snap, ys_a = jax.lax.scan(body, h0, jnp.arange(j))
+        h_final, ys_b = jax.lax.scan(body, h_snap, jnp.arange(j, n_chunks))
+        ys = jnp.concatenate([ys_a, ys_b], axis=0)
     y = ys.transpose(1, 0, 2, 3, 4).reshape(bsz, n_chunks * chunk, di)[:, :s]
     # gated RMSNorm then out-projection.
     y = layers.rms_norm(y.astype(DTYPE) * jax.nn.silu(z.astype(jnp.float32)).astype(DTYPE),
-                        params["norm_w"])
+                        params["norm_w"], cfg.rms_norm_eps)
     out = y @ params["out_proj"]
+    if not return_state and snap_at is None:
+        return out
+    # Conv tails: the K-1 raw inputs before a boundary (token t of this
+    # call is row t + K-1 of the extended input).
+    k = cfg.ssm_conv
+    ext = (jnp.pad(xbc_raw, ((0, 0), (k - 1, 0), (0, 0))) if conv0 is None
+           else jnp.concatenate([conv0.astype(xbc_raw.dtype), xbc_raw], 1))
+    res = (out,)
     if return_state:
-        k = cfg.ssm_conv
-        tail = jnp.pad(xbc_raw, ((0, 0), (k - 1, 0), (0, 0)))[:, s:s + k - 1]
-        return out, h_final, tail.astype(DTYPE)
-    return out
+        res += (h_final, ext[:, s:s + k - 1].astype(DTYPE))
+    if snap_at is not None:
+        res += (h_snap, ext[:, snap_at:snap_at + k - 1].astype(DTYPE))
+    return res
 
 
 def mamba2_decode(params, x, cfg: ArchConfig, hstate, conv_buf):
@@ -273,5 +307,5 @@ def mamba2_decode(params, x, cfg: ArchConfig, hstate, conv_buf):
     y = y.reshape(bsz, di)
     y = layers.rms_norm((y[:, None, :].astype(DTYPE)
                          * jax.nn.silu(z.astype(jnp.float32))[:, None, :].astype(DTYPE)),
-                        params["norm_w"])
+                        params["norm_w"], cfg.rms_norm_eps)
     return y @ params["out_proj"], hstate, conv_buf

@@ -1,7 +1,8 @@
 """Model assembly: composable blocks -> scan-over-layer-groups stack.
 
 The per-layer pattern of each architecture (dense / 5:1 local:global /
-MoE / Mamba / hybrid-with-shared-attention) is factored into a repeating
+MoE / Mamba / hybrid-with-shared-attention / Mamba-2 with periodic
+attention) is factored into a repeating
 *group* that is scanned with stacked parameters (plus an unscanned
 remainder), so HLO size and compile time are independent of depth — a 62
 layer model lowers as one group body.
@@ -12,7 +13,7 @@ Public entry points (used by train/serve/launch):
     forward(params, cfg, batch)                -> final hidden states
     train_loss(params, cfg, batch)             -> scalar CE loss
     init_cache(cfg, batch, max_seq)            -> decode cache pytree
-    prefill(params, cfg, batch, cache)         -> (last-token logits, cache)
+    prefill(params, cfg, batch, max_seq)       -> (last-token logits, cache)
     decode_step(params, cfg, tokens, cache, pos) -> (logits, cache)
 
 Batch dict keys: "tokens" (B, S) int32 and/or "embeds" (B, P, D) bf16
@@ -45,35 +46,52 @@ def init_block(key, kind: str, cfg: ArchConfig):
     p = {"ln1": jnp.zeros((cfg.d_model,), DTYPE)}
     if _is_attn(kind):
         p["attn"] = layers.init_attention(k[0], cfg)
-        p["ln2"] = jnp.zeros((cfg.d_model,), DTYPE)
-        if cfg.n_experts and kind != SHARED_ATTN:
-            p["moe"] = moe.init_moe(k[1], cfg)
-        else:
-            p["mlp"] = layers.init_mlp(k[1], cfg)
     elif kind == MAMBA1:
         p["ssm"] = ssm.init_mamba1(k[0], cfg)
     elif kind == MAMBA2:
         p["ssm"] = ssm.init_mamba2(k[0], cfg)
     else:
         raise ValueError(kind)
+    if _is_attn(kind) or cfg.ssm_mlp:
+        p["ln2"] = jnp.zeros((cfg.d_model,), DTYPE)
+        if cfg.n_experts and kind != SHARED_ATTN:
+            p["moe"] = moe.init_moe(k[1], cfg)
+        else:
+            p["mlp"] = layers.init_mlp(k[1], cfg)
     return p
 
 
+def _norm(x, w, cfg: ArchConfig):
+    return layers.rms_norm(x, w, cfg.rms_norm_eps)
+
+
+def _residual(x, h, cfg: ArchConfig):
+    """x + h, the branch scaled by the published residual multiplier (in
+    fp32: the multiplier itself is not rounded to bf16)."""
+    if cfg.residual_multiplier != 1.0:
+        h = (h.astype(jnp.float32) * cfg.residual_multiplier).astype(h.dtype)
+    return x + h
+
+
+def _ffn(p, x, cfg: ArchConfig):
+    """The block's MLP (or MoE) sub-block after its mixer, if it has one."""
+    if "ln2" not in p:
+        return x
+    h = _norm(x, p["ln2"], cfg)
+    h = (moe.moe_block(p["moe"], h, cfg) if "moe" in p
+         else layers.mlp_block(p["mlp"], h, cfg))
+    return _residual(x, h, cfg)
+
+
 def apply_block(p, kind: str, x, cfg: ArchConfig, positions):
-    h = layers.rms_norm(x, p["ln1"])
+    h = _norm(x, p["ln1"], cfg)
     if _is_attn(kind):
         h = layers.attention_block(p["attn"], h, cfg, positions,
                                    local=(kind == ATTN_LOCAL))
-        x = x + h
-        h2 = layers.rms_norm(x, p["ln2"])
-        if "moe" in p:
-            h2 = moe.moe_block(p["moe"], h2, cfg)
-        else:
-            h2 = layers.mlp_block(p["mlp"], h2, cfg)
-        return x + h2
     else:
         fn = ssm.mamba1_block if kind == MAMBA1 else ssm.mamba2_block
-        return x + fn(p["ssm"], h, cfg)
+        h = fn(p["ssm"], h, cfg)
+    return _ffn(p, _residual(x, h, cfg), cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -112,13 +130,27 @@ def param_count(params) -> int:
 # Forward (training / encoder / prefill trunk).
 # ---------------------------------------------------------------------------
 
+def _embed_scale(cfg: ArchConfig):
+    """The published ``embedding_multiplier``, else sqrt(d_model)."""
+    m = cfg.embedding_multiplier
+    return jnp.asarray(cfg.d_model ** 0.5 if m is None else m, DTYPE)
+
+
+def _logits(params, cfg: ArchConfig, x):
+    """Output-head logits (fp32), divided by ``logits_scaling``."""
+    logits = layers.unembed_logits(params["embed"], x)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
+
+
 def _input_embeds(params, cfg: ArchConfig, batch):
     parts = []
     if "embeds" in batch:
         parts.append(batch["embeds"].astype(DTYPE))
     if "tokens" in batch:
-        scale = jnp.asarray(cfg.d_model ** 0.5, DTYPE)
-        parts.append(layers.embed(params["embed"], batch["tokens"]) * scale)
+        parts.append(layers.embed(params["embed"], batch["tokens"])
+                     * _embed_scale(cfg))
     x = jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
@@ -140,7 +172,7 @@ def forward(params, cfg: ArchConfig, batch):
     for i, kind in enumerate(rem):
         p = shared if kind == SHARED_ATTN else params[f"rem{i}"]
         x = apply_block(p, kind, x, cfg, positions)
-    return layers.rms_norm(x, params["final_ln"])
+    return _norm(x, params["final_ln"], cfg)
 
 
 def train_loss(params, cfg: ArchConfig, batch):
@@ -149,7 +181,8 @@ def train_loss(params, cfg: ArchConfig, batch):
     if "embeds" in batch and "tokens" in batch:
         # VLM: loss only over the text tail (prefix embeds carry no labels).
         x = x[:, batch["embeds"].shape[1]:]
-    loss = layers.chunked_ce_loss(params["embed"], x, labels)
+    loss = layers.chunked_ce_loss(params["embed"], x, labels,
+                                  logits_scaling=cfg.logits_scaling)
     if cfg.n_experts:
         # aux load-balance term over the last hidden states (cheap proxy;
         # the per-layer routers see rebalanced inputs anyway).
@@ -193,7 +226,7 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int):
 
 
 def _decode_block(p, kind: str, x, cfg: ArchConfig, bcache, pos):
-    h = layers.rms_norm(x, p["ln1"])
+    h = _norm(x, p["ln1"], cfg)
     if _is_attn(kind):
         local = kind == ATTN_LOCAL
         if local:
@@ -205,27 +238,18 @@ def _decode_block(p, kind: str, x, cfg: ArchConfig, bcache, pos):
         else:
             out, ck, cv = layers.decode_attention(
                 p["attn"], h, cfg, bcache["k"], bcache["v"], pos, local=False)
-        x = x + out
-        h2 = layers.rms_norm(x, p["ln2"])
-        if "moe" in p:
-            h2 = moe.moe_block(p["moe"], h2, cfg)
-        else:
-            h2 = layers.mlp_block(p["mlp"], h2, cfg)
-        return x + h2, {"k": ck, "v": cv}
-    if kind == MAMBA1:
-        out, hh, conv = ssm.mamba1_decode(p["ssm"], h, cfg, bcache["h"],
-                                          bcache["conv"])
-        return x + out, {"h": hh, "conv": conv}
-    out, hh, conv = ssm.mamba2_decode(p["ssm"], h, cfg, bcache["h"],
-                                      bcache["conv"])
-    return x + out, {"h": hh, "conv": conv}
+        new = {"k": ck, "v": cv}
+    else:
+        fn = ssm.mamba1_decode if kind == MAMBA1 else ssm.mamba2_decode
+        out, hh, conv = fn(p["ssm"], h, cfg, bcache["h"], bcache["conv"])
+        new = {"h": hh, "conv": conv}
+    return _ffn(p, _residual(x, out, cfg), cfg), new
 
 
 def decode_step(params, cfg: ArchConfig, tokens, cache, pos):
     """tokens: (B, 1) int32; pos: scalar int32 (next position).
     Returns (logits (B, V) fp32, new cache)."""
-    scale = jnp.asarray(cfg.d_model ** 0.5, DTYPE)
-    x = layers.embed(params["embed"], tokens) * scale
+    x = layers.embed(params["embed"], tokens) * _embed_scale(cfg)
     group, n_groups, rem = cfg.scan_groups()
     shared = params.get("shared")
 
@@ -247,17 +271,37 @@ def decode_step(params, cfg: ArchConfig, tokens, cache, pos):
         p = shared if kind == SHARED_ATTN else params[f"rem{i}"]
         x, new_cache[f"rem{i}"] = _decode_block(p, kind, x, cfg,
                                                 cache[f"rem{i}"], pos)
-    x = layers.rms_norm(x, params["final_ln"])
-    logits = layers.unembed_logits(params["embed"], x)[:, 0]
+    x = _norm(x, params["final_ln"], cfg)
+    logits = _logits(params, cfg, x)[:, 0]
     return logits, new_cache
 
 
+def resume_blocker(cfg: ArchConfig) -> str | None:
+    """Why the prefix-cache resume path cannot serve this arch, or None
+    when it can.  Attention layers resume from per-position KV; Mamba-2
+    layers resume from a snapshot of their recurrent state and conv tail
+    taken at one chunk boundary of the SSD (``prefill(snapshot_at=)``).
+    Other recurrent layers have no snapshot."""
+    kinds = set(cfg.layer_pattern())
+    if MAMBA1 in kinds:
+        return ("its Mamba-1 layers have no state snapshot (only the "
+                "Mamba-2 SSD returns its state at a chunk boundary)")
+    if SHARED_ATTN in kinds:
+        return ("its shared attention block (one set of weights reused "
+                "at several depths, zamba2) has no resume path")
+    return None
+
+
 def resume_supported(cfg: ArchConfig) -> bool:
-    """True when the prefix-cache resume path can serve this arch: every
-    layer's decode state must be reconstructible from per-position KV
-    (attention only).  SSM/hybrid recurrent states fold the whole prefix
-    into one vector and cannot be restored from chunk slabs."""
-    return all(k in (ATTN_GLOBAL, ATTN_LOCAL) for k in cfg.layer_pattern())
+    """True when the prefix-cache resume path can serve this arch
+    (:func:`resume_blocker` says why not)."""
+    return resume_blocker(cfg) is None
+
+
+def has_recurrent_state(cfg: ArchConfig) -> bool:
+    """True when some layer folds the prefix into a recurrent state, so a
+    resume needs a state snapshot besides the attention KV."""
+    return any(k in (MAMBA1, MAMBA2) for k in cfg.layer_pattern())
 
 
 def prefix_length(prefix_kv) -> int:
@@ -269,10 +313,12 @@ def prefix_length(prefix_kv) -> int:
 
 
 def prefill(params, cfg: ArchConfig, batch, max_seq: int, *,
-            prefix_kv=None, return_kv: bool = False):
+            prefix_kv=None, prefix_state=None, return_kv: bool = False,
+            snapshot_at: int | None = None):
     """Run the trunk over a prompt and build the decode cache.
     Returns (last-token logits (B, V), cache) — plus a per-layer KV
-    pytree for the tokens of THIS call when ``return_kv=True``.
+    pytree for the tokens of THIS call when ``return_kv=True``, plus
+    the recurrent state at token ``snapshot_at`` when that is given.
 
     ``prefix_kv`` resumes from a cached prefix: a pytree mirroring the
     cache layout with post-RoPE k/v of the first P prompt tokens (seq
@@ -282,94 +328,123 @@ def prefill(params, cfg: ArchConfig, batch, max_seq: int, *,
     concat(prefix, suffix) with ``q_offset=P``, and the cache is built
     over the combined sequence — bit-identical to a full prefill of the
     whole prompt, since the slabs hold exactly the k/v a full prefill
-    would compute."""
-    if prefix_kv is not None and not resume_supported(cfg):
+    would compute.
+
+    Mamba-2 layers resume from ``prefix_state``, a pytree mirroring the
+    cache layout with each Mamba-2 layer's {"h": fp32 state, "conv":
+    conv tail} after the first P tokens (None at attention layers).
+    ``snapshot_at`` (an absolute token position, P < snapshot_at <= P +
+    suffix, on a ``cfg.ssm_chunk`` boundary counted from P) returns the
+    same pytree for the state after that token: the snapshot a later
+    prefill resumes from."""
+    blocker = resume_blocker(cfg)
+    if prefix_kv is not None and blocker is not None:
         raise NotImplementedError(
-            f"prefix resume needs attention-only layers; {cfg.name} "
-            "has recurrent (SSM) state that chunk slabs cannot restore")
+            f"prefix resume cannot serve {cfg.name}: {blocker}")
     x, positions = _input_embeds(params, cfg, batch)
     b, s, _ = x.shape
     p_len = 0
     if prefix_kv is not None:
         p_len = prefix_length(prefix_kv)
         positions = positions + jnp.int32(p_len)
+    if has_recurrent_state(cfg) and (prefix_kv is None) != (prefix_state is None):
+        raise ValueError("a resumed prefill of a recurrent arch needs both "
+                         "the prefix KV and the prefix state")
+    snap = None if snapshot_at is None else snapshot_at - p_len
     group, n_groups, rem = cfg.scan_groups()
     shared = params.get("shared")
 
-    def fill_block(p, kind, xc, bcache, pk):
-        h = layers.rms_norm(xc, p["ln1"])
-        if _is_attn(kind):
-            local = kind == ATTN_LOCAL
-            q, k, v = layers._qkv(p["attn"], h, cfg, positions)
-            q = layers._seq_shard(q, cfg)
-            k = layers._seq_shard(k, cfg)
-            v = layers._seq_shard(v, cfg)
-            if pk is not None:
-                # k/v over the COMBINED sequence: cached prefix ++ new.
-                k_all = jnp.concatenate([pk["k"].astype(k.dtype), k], axis=1)
-                v_all = jnp.concatenate([pk["v"].astype(v.dtype), v], axis=1)
+    def fill_block(p, kind, xc, bcache, pk, ps):
+        h = _norm(xc, p["ln1"], cfg)
+        if not _is_attn(kind):
+            # SSM prefill: the chunked block carries the recurrent state
+            # across chunks and returns (h_final, conv tail) to seed decode
+            # exactly; a resumed Mamba-2 layer starts from ``ps``.
+            if kind == MAMBA1:
+                out, h_final, conv_tail = ssm.mamba1_block(
+                    p["ssm"], h, cfg, return_state=True)
+                snap_state = None
             else:
-                k_all, v_all = k, v
-            s_tot = k_all.shape[1]
-            out = layers.chunked_attention(
-                q, k_all, v_all, causal=cfg.causal and not cfg.encoder_only,
-                window=cfg.sliding_window if local else 0,
-                softcap=cfg.logit_softcap, q_offset=p_len)
-            out = out.reshape(b, s, -1) @ p["attn"]["wo"]
-            xc = xc + out
-            h2 = layers.rms_norm(xc, p["ln2"])
-            h2 = (moe.moe_block(p["moe"], h2, cfg) if "moe" in p
-                  else layers.mlp_block(p["mlp"], h2, cfg))
-            xc = xc + h2
-            # write cache (ring layout for local, plain for global) over
-            # the combined sequence — same formulas as a full prefill of
-            # s_tot tokens.
-            cw = bcache["k"].shape[1]
-            if local:
-                take = min(cw, s_tot)
-                ks, vs = k_all[:, -take:], v_all[:, -take:]
-                slots = (jnp.arange(s_tot - take, s_tot) % cw).astype(jnp.int32)
-                ck = bcache["k"].at[:, slots].set(ks.astype(DTYPE))
-                cv = bcache["v"].at[:, slots].set(vs.astype(DTYPE))
-            else:
-                ck = jax.lax.dynamic_update_slice_in_dim(
-                    bcache["k"], k_all.astype(DTYPE), 0, axis=1)
-                cv = jax.lax.dynamic_update_slice_in_dim(
-                    bcache["v"], v_all.astype(DTYPE), 0, axis=1)
-            kv = {"k": k.astype(DTYPE), "v": v.astype(DTYPE)}
-            return xc, {"k": ck, "v": cv}, kv
-        # SSM prefill: the chunked block already carries the recurrent state
-        # across chunks; return_state hands back (h_final, conv tail) to
-        # seed decode exactly.
-        fn = ssm.mamba1_block if kind == MAMBA1 else ssm.mamba2_block
-        out, h_final, conv_tail = fn(p["ssm"], h, cfg, return_state=True)
-        return xc + out, {"h": h_final, "conv": conv_tail}, None
+                res = ssm.mamba2_block(
+                    p["ssm"], h, cfg, return_state=True, snap_at=snap,
+                    h0=None if ps is None else ps["h"],
+                    conv0=None if ps is None else ps["conv"])
+                out, h_final, conv_tail = res[:3]
+                snap_state = (None if snap is None
+                              else {"h": res[3], "conv": res[4]})
+            xc = _ffn(p, _residual(xc, out, cfg), cfg)
+            return xc, {"h": h_final, "conv": conv_tail}, None, snap_state
+        local = kind == ATTN_LOCAL
+        q, k, v = layers._qkv(p["attn"], h, cfg, positions)
+        q = layers._seq_shard(q, cfg)
+        k = layers._seq_shard(k, cfg)
+        v = layers._seq_shard(v, cfg)
+        if pk is not None:
+            # k/v over the COMBINED sequence: cached prefix ++ new.
+            k_all = jnp.concatenate([pk["k"].astype(k.dtype), k], axis=1)
+            v_all = jnp.concatenate([pk["v"].astype(v.dtype), v], axis=1)
+        else:
+            k_all, v_all = k, v
+        s_tot = k_all.shape[1]
+        out = layers.chunked_attention(
+            q, k_all, v_all, causal=cfg.causal and not cfg.encoder_only,
+            window=cfg.sliding_window if local else 0,
+            softcap=cfg.logit_softcap, q_offset=p_len,
+            kv_chunk=cfg.attn_kv_chunk, scale=layers.attn_scale(cfg))
+        out = out.reshape(b, s, -1) @ p["attn"]["wo"]
+        xc = _ffn(p, _residual(xc, out, cfg), cfg)
+        # write cache (ring layout for local, plain for global) over
+        # the combined sequence — same formulas as a full prefill of
+        # s_tot tokens.
+        cw = bcache["k"].shape[1]
+        if local:
+            take = min(cw, s_tot)
+            ks, vs = k_all[:, -take:], v_all[:, -take:]
+            slots = (jnp.arange(s_tot - take, s_tot) % cw).astype(jnp.int32)
+            ck = bcache["k"].at[:, slots].set(ks.astype(DTYPE))
+            cv = bcache["v"].at[:, slots].set(vs.astype(DTYPE))
+        else:
+            ck = jax.lax.dynamic_update_slice_in_dim(
+                bcache["k"], k_all.astype(DTYPE), 0, axis=1)
+            cv = jax.lax.dynamic_update_slice_in_dim(
+                bcache["v"], v_all.astype(DTYPE), 0, axis=1)
+        kv = {"k": k.astype(DTYPE), "v": v.astype(DTYPE)}
+        return xc, {"k": ck, "v": cv}, kv, None
 
     cache = init_cache(cfg, b, max_seq)
-    kv_out = {}
+    kv_out, snap_out = {}, {}
+
+    def part(tree, key):
+        return None if tree is None else tree.get(key)
+
     if n_groups > 0:
-        pk_groups = None if prefix_kv is None else prefix_kv["groups"]
         def body(xc, scanned):
-            gp, gc, gpk = scanned
-            new_gc, new_kv = {}, {}
+            gp, gc, gpk, gps = scanned
+            new_gc, new_kv, new_snap = {}, {}, {}
             for i, kind in enumerate(group):
                 p = shared if kind == SHARED_ATTN else gp[f"b{i}"]
-                bpk = None if gpk is None else gpk[f"b{i}"]
-                xc, new_gc[f"b{i}"], new_kv[f"b{i}"] = fill_block(
-                    p, kind, xc, gc[f"b{i}"], bpk)
-            return xc, (new_gc, new_kv)
-        x, (new_groups, kv_groups) = jax.lax.scan(
+                xc, new_gc[f"b{i}"], new_kv[f"b{i}"], new_snap[f"b{i}"] = \
+                    fill_block(p, kind, xc, gc[f"b{i}"], part(gpk, f"b{i}"),
+                               part(gps, f"b{i}"))
+            return xc, (new_gc, new_kv, new_snap)
+        x, (new_groups, kv_groups, snap_groups) = jax.lax.scan(
             jax.checkpoint(body), x,
-            (params["groups"], cache["groups"], pk_groups))
+            (params["groups"], cache["groups"], part(prefix_kv, "groups"),
+             part(prefix_state, "groups")))
         cache = dict(cache, groups=new_groups)
         kv_out["groups"] = kv_groups
+        snap_out["groups"] = snap_groups
     for i, kind in enumerate(rem):
         p = shared if kind == SHARED_ATTN else params[f"rem{i}"]
-        rpk = None if prefix_kv is None else prefix_kv.get(f"rem{i}")
-        x, cache[f"rem{i}"], kv_out[f"rem{i}"] = fill_block(
-            p, kind, x, cache[f"rem{i}"], rpk)
-    x = layers.rms_norm(x, params["final_ln"])
-    logits = layers.unembed_logits(params["embed"], x[:, -1:])[:, 0]
+        x, cache[f"rem{i}"], kv_out[f"rem{i}"], snap_out[f"rem{i}"] = \
+            fill_block(p, kind, x, cache[f"rem{i}"],
+                       part(prefix_kv, f"rem{i}"),
+                       part(prefix_state, f"rem{i}"))
+    x = _norm(x, params["final_ln"], cfg)
+    logits = _logits(params, cfg, x[:, -1:])[:, 0]
+    out = (logits, cache)
     if return_kv:
-        return logits, cache, kv_out
-    return logits, cache
+        out += (kv_out,)
+    if snapshot_at is not None:
+        out += (snap_out,)
+    return out

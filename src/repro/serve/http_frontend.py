@@ -14,7 +14,8 @@ Endpoints (operator guide: docs/SERVING.md "Network edge"):
   admission outcome, queue + service time).
 * ``GET /healthz`` — liveness; 200 while accepting, 503 once draining.
 * ``GET /stats`` — JSON snapshot: ``idx.stats``, ``admit_q.stats``,
-  ``wear_report()``, ``lifetime_estimate()``, router counters.
+  ``wear_report()``, ``lifetime_estimate()``, router counters, the
+  slab store's and the resume engine's counters.
 
 Layering (who does what):
 
@@ -156,6 +157,9 @@ class ServeRouter:
     retry_wait_s : float
         Passed through to ``run_request_loop`` (bounded drain-wait
         before the one defer retry).
+    resume_stats : callable, optional
+        The resume engine's counters (``PrefixResumeEngine.stats``),
+        reported under ``"resume"`` by ``GET /stats``.
     now_fn : callable
         Clock injection for tests.
     """
@@ -163,7 +167,8 @@ class ServeRouter:
     def __init__(self, admit_q: AdmitQueue, *, prefill_fn, decode_fn=None,
                  n_workers: int = 2, max_queue: int = 64,
                  batch_window_s: float = 0.002, max_batch_rows: int = 8,
-                 retry_wait_s: float = 0.05, now_fn=time.monotonic):
+                 retry_wait_s: float = 0.05, resume_stats=None,
+                 now_fn=time.monotonic):
         if n_workers < 1:
             raise ValueError(f"ServeRouter n_workers={n_workers}: expected "
                              ">= 1")
@@ -178,6 +183,7 @@ class ServeRouter:
         self.batch_window_s = float(batch_window_s)
         self.max_batch_rows = max_batch_rows
         self.retry_wait_s = retry_wait_s
+        self.resume_stats = resume_stats
         self._now = now_fn
         self.stats = RouterStats()
         self._cv = threading.Condition()
@@ -415,6 +421,8 @@ def stats_snapshot(router: ServeRouter) -> dict:
         "lifetime": dataclasses.asdict(lt),
         "router": rstats | {"depth": depth, "workers": router.n_workers},
         "slab_store": None if store is None else store.stats(),
+        "resume": (None if router.resume_stats is None
+                   else router.resume_stats()),
     }
 
 

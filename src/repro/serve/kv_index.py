@@ -688,6 +688,11 @@ class KVSlabStore:
         with self._lock:
             return set(self._resident)
 
+    def resident_slabs(self) -> list:
+        """Every resident slab, read under one hold of the store lock."""
+        with self._lock:
+            return [slab for slab, _, _ in self._resident.values()]
+
     def staged_fps(self) -> set[int]:
         with self._lock:
             return set(self._staged)

@@ -37,9 +37,17 @@ Correctness ground rules (all pinned by ``tests/test_decode_resume.py``):
 * A hit whose slab is missing (admitted slab-less, or shed/evicted
   between lookup and fetch) truncates the resume run — graceful
   recompute, never a wrong answer.
-* Only attention layers resume (``transformer.resume_supported``): SSM
-  recurrent state folds the whole prefix into one vector and cannot be
-  restored from per-chunk slabs.
+* Recurrent layers fold the whole prefix into one state, which no KV
+  slab holds.  Mamba-2 hybrids (``transformer.resume_supported``)
+  resume from a state snapshot instead: a prefill that computes the
+  token at :func:`snapshot_token` hands back the Mamba-2 layers' fp32
+  state and conv tail there, and the snapshot rides in the slab of the
+  chunk ending at that token (``{"kv": ..., "state": ...}``; the other
+  chunks' slabs carry ``"state": None``), so eviction drops the KV and
+  the state together and the store's budget counts both.  A resume run
+  is cut back to the deepest chunk whose slab holds a snapshot in every
+  row; with none in reach the batch takes a full prefill.  Other
+  recurrent layers (Mamba-1, zamba2's shared block) are refused.
 """
 from __future__ import annotations
 
@@ -57,6 +65,14 @@ from repro.models import transformer
 from repro.serve.kv_index import CHUNK_TOKENS, MonarchKVIndex
 from repro.serve.spans import span
 from repro.serve.step import make_decode_step, make_resume_prefill_step
+
+
+def snapshot_token(cfg: ArchConfig, s: int) -> int:
+    """Where a prompt of ``s`` tokens takes its state snapshot: the
+    deepest multiple of ``cfg.ssm_chunk`` at or below the resumable cap
+    of ``(s-1) // CHUNK_TOKENS`` chunks (0: no snapshot)."""
+    cap = max(s - 1, 0) // CHUNK_TOKENS * CHUNK_TOKENS
+    return cap // cfg.ssm_chunk * cfg.ssm_chunk
 
 
 @dataclasses.dataclass
@@ -112,6 +128,30 @@ def join_rows(rows):
         lambda *xs: jnp.concatenate(xs, axis=xs[0].ndim - 4), *rows)
 
 
+# A state pytree (``prefill(snapshot_at=...)``) mirrors the cache: its
+# leaves are (B, ...) at remainder layers and (G, B, ...) under "groups".
+
+def _batch_axis(path) -> int:
+    return 1 if getattr(path[0], "key", None) == "groups" else 0
+
+
+def split_state(state):
+    """A batch's state snapshot -> per row, a snapshot of batch 1."""
+    path, leaf = jax.tree_util.tree_leaves_with_path(state)[0]
+    rows = leaf.shape[_batch_axis(path)]
+    return tuple(jax.tree_util.tree_map_with_path(
+        lambda path, a: jax.lax.slice_in_dim(a, r, r + 1,
+                                             axis=_batch_axis(path)),
+        state) for r in range(rows))
+
+
+def join_state(rows):
+    """Per-row state snapshots -> the batch's."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, *xs: jnp.concatenate(xs, axis=_batch_axis(path)),
+        *rows)
+
+
 def _nbytes(tree, device_only: bool = False) -> int:
     return sum(int(a.nbytes) for a in jax.tree.leaves(tree)
                if not device_only or isinstance(a, jax.Array))
@@ -125,7 +165,8 @@ class PrefixResumeEngine:
     params : pytree
         Model parameters (already placed on the serving mesh).
     cfg : ArchConfig
-        Must be attention-only (``transformer.resume_supported``).
+        Attention-only, or a Mamba-2 hybrid
+        (``transformer.resume_supported``).
     max_seq : int
         Decode-cache capacity; prompts + decode tokens must fit.
     index : MonarchKVIndex
@@ -143,11 +184,16 @@ class PrefixResumeEngine:
     def __init__(self, params, cfg: ArchConfig, *, max_seq: int,
                  index: MonarchKVIndex, decode_tokens: int = 8,
                  jit: bool = True):
-        if not transformer.resume_supported(cfg):
+        blocker = transformer.resume_blocker(cfg)
+        if blocker is not None:
             raise NotImplementedError(
-                f"prefix resume needs attention-only layers; {cfg.name} "
-                "carries recurrent (SSM) state that chunk slabs cannot "
-                "restore")
+                f"prefix resume cannot serve {cfg.name}: {blocker}")
+        self.recurrent = transformer.has_recurrent_state(cfg)
+        if self.recurrent and cfg.ssm_chunk % CHUNK_TOKENS:
+            raise ValueError(
+                f"{cfg.name}: ssm_chunk {cfg.ssm_chunk} is not a multiple "
+                f"of CHUNK_TOKENS {CHUNK_TOKENS}, so no chunk slab ends at "
+                "a state snapshot")
         if index.cfg.fingerprint != "prefix":
             raise ValueError(
                 "PrefixResumeEngine needs KVIndexConfig(fingerprint="
@@ -164,25 +210,33 @@ class PrefixResumeEngine:
         self.store = index.slab_store
         self.decode_tokens = decode_tokens
         fn = make_resume_prefill_step(cfg, max_seq)
-        self._prefill = jax.jit(fn) if jit else fn
+        self._prefill = (jax.jit(fn, static_argnames="snapshot_at") if jit
+                         else fn)
         dec = make_decode_step(cfg)
         self._decode = jax.jit(dec) if jit else dec
         self._join_run = jax.jit(join_run) if jit else join_run
         self._join_rows = jax.jit(join_rows) if jit else join_rows
         self._split = jax.jit(split_slabs) if jit else split_slabs
+        self._split_state = jax.jit(split_state) if jit else split_state
+        self._join_state = jax.jit(join_state) if jit else join_state
         self.resumed_chunks = 0          # served from slabs, cumulative
         self.computed_chunks = 0         # recomputed, cumulative
+        self.snapshots_staged = 0        # state snapshots offered
+        self.snapshot_misses = 0         # rows cut back to a snapshot
 
     # ------------------------------------------------------------------
     def _resume_slabs(self, fps: np.ndarray, hits: np.ndarray,
-                      s: int) -> list[list]:
+                      s: int) -> tuple[list[list], list[list]]:
         """Per row, the slabs of the longest leading run of chunks
         servable for EVERY row: the chunk hit in the index AND its slab
         resident, fetched in one pass over the store.  Capped at
         ``(s-1) // CHUNK_TOKENS`` so at least one suffix token is always
         recomputed (last-token logits seed decode) — for chunk-aligned
         prompts that forces the last chunk out of the run; a partial
-        trailing chunk is recomputed anyway and lifts the cap."""
+        trailing chunk is recomputed anyway and lifts the cap.  A
+        recurrent arch cuts the run back to the deepest chunk whose slab
+        holds a state snapshot in every row.  Also returns every slab
+        fetched, per row."""
         b = fps.shape[0]
         cap = max(s - 1, 0) // CHUNK_TOKENS
         # leading hits of each row: the index of its first miss
@@ -192,7 +246,35 @@ class PrefixResumeEngine:
         rows = [got[r * lead:(r + 1) * lead] for r in range(b)]
         run = min(next((k for k, g in enumerate(row) if g is None), lead)
                   for row in rows)
-        return [row[:run] for row in rows]
+        if self.recurrent:
+            held = run
+            while run and any(row[run - 1]["state"] is None for row in rows):
+                run -= 1
+            if run < held:
+                self.snapshot_misses += b
+        return [row[:run] for row in rows], rows
+
+    def _kv(self, slab):
+        return slab["kv"] if self.recurrent else slab
+
+    def _restore(self, prefix: list[list]):
+        """The batch's (prefix kv, prefix state) from its rows' slab runs,
+        joined on the device, with the restore span's byte counts."""
+        b = len(prefix)
+        with span("resume.restore", rows=b) as restore:
+            kv = [[self._kv(slab) for slab in row] for row in prefix]
+            state = ([row[-1]["state"] for row in prefix] if self.recurrent
+                     else None)
+            restore.set_metadata(
+                nbytes=_nbytes((kv, state)),
+                device_nbytes=_nbytes((kv, state), device_only=True),
+                state_nbytes=_nbytes(state))
+            prefix_kv = [self._join_run(row) for row in kv]
+            prefix_kv = (prefix_kv[0] if b == 1
+                         else self._join_rows(prefix_kv))
+            if state is not None:
+                state = state[0] if b == 1 else self._join_state(state)
+        return prefix_kv, state
 
     def prefill(self, toks: np.ndarray, hits=None) -> PrefillResult:
         """Restore + partial prefill of one request batch.
@@ -208,27 +290,29 @@ class PrefixResumeEngine:
                 fps = self.index.fingerprints(toks)
                 if hits is None:
                     hits = np.zeros((b, n_chunks), bool)
-                prefix = self._resume_slabs(fps, np.asarray(hits, bool), s)
+                prefix, fetched = self._resume_slabs(
+                    fps, np.asarray(hits, bool), s)
             run = len(prefix[0])
             p_len = run * CHUNK_TOKENS
             outer.set_metadata(prefix=p_len, suffix=s - p_len)
+            # A recurrent arch snapshots its state at snapshot_token,
+            # unless the resumed prefix already reaches it.
+            snap_t = snapshot_token(self.cfg, s) if self.recurrent else 0
+            kw = {"snapshot_at": snap_t} if snap_t > p_len else {}
             if run > 0:
                 # Device slabs join on the device; a slab held in host
                 # memory is uploaded by itself as an argument of the join.
-                with span("resume.restore", rows=b) as restore:
-                    restore.set_metadata(
-                        nbytes=_nbytes(prefix),
-                        device_nbytes=_nbytes(prefix, device_only=True))
-                    prefix_kv = [self._join_run(row) for row in prefix]
-                    prefix_kv = (prefix_kv[0] if b == 1
-                                 else self._join_rows(prefix_kv))
+                prefix_kv, prefix_state = self._restore(prefix)
+                if self.recurrent:
+                    kw["prefix_state"] = prefix_state
                 with span("resume.step"):
-                    logits, cache, kv_suffix = self._prefill(
-                        self.params, {"tokens": toks[:, p_len:]}, prefix_kv)
+                    out = self._prefill(
+                        self.params, {"tokens": toks[:, p_len:]}, prefix_kv,
+                        **kw)
             else:
                 with span("resume.step"):
-                    logits, cache, kv_suffix = self._prefill(
-                        self.params, {"tokens": toks})
+                    out = self._prefill(self.params, {"tokens": toks}, **kw)
+            logits, cache, kv_suffix = out[:3]
             # Cut the freshly computed whole chunks into slabs to stage,
             # on the device; the first row holding a fingerprint gives it.
             with span("resume.slice") as sliced:
@@ -241,12 +325,52 @@ class PrefixResumeEngine:
                                              pieces[r][c - run])
                 sliced.set_metadata(nbytes=sum(map(_nbytes,
                                                    slabs.values())))
+            if self.recurrent:
+                self._attach_states(slabs, fps, fetched, run, out, snap_t)
         self.resumed_chunks += run * b
         self.computed_chunks += (n_chunks - run) * b
         state = {"logits": logits, "cache": cache, "pos": s}
         return PrefillResult(state=state, slabs=slabs,
                              resumed_chunks=run * b,
                              computed_chunks=(n_chunks - run) * b)
+
+    def _attach_states(self, slabs: dict, fps, fetched, run: int, out,
+                       snap_t: int) -> None:
+        """Give a recurrent arch's slabs their ``"state"``: each row's
+        snapshot (when this prefill took one) in the slab of the chunk
+        ending at ``snap_t``, None elsewhere.  A slab without a snapshot
+        never replaces a resident one holding a snapshot (the KV is the
+        same): its chunk is offered without a slab."""
+        snaps = {}
+        if len(out) > 3:
+            with span("resume.snapshot", rows=len(fps)) as sp:
+                c = snap_t // CHUNK_TOKENS - 1
+                for r, st in enumerate(self._split_state(out[3])):
+                    snaps.setdefault(int(fps[r, c]), st)
+                sp.set_metadata(nbytes=sum(map(_nbytes, snaps.values())))
+            self.snapshots_staged += len(snaps)
+        held = {int(fps[r, c]): slab
+                for r, row in enumerate(fetched)
+                for c, slab in enumerate(row) if c >= run}
+        for fp in list(slabs):
+            state = snaps.get(fp)
+            old = held.get(fp)
+            if state is None and old is not None and old["state"] is not None:
+                del slabs[fp]
+            else:
+                slabs[fp] = {"kv": slabs[fp], "state": state}
+
+    def stats(self) -> dict:
+        """Snapshot counters, for ``GET /stats``: snapshots staged (one a
+        prompt that computes its snapshot token), resident with their
+        state bytes, and ``snapshot_misses``, rows whose resident hit run
+        was cut short or dropped for want of a snapshot."""
+        resident = ([slab["state"] for slab in self.store.resident_slabs()
+                     if slab["state"] is not None] if self.recurrent else [])
+        return {"snapshots_staged": self.snapshots_staged,
+                "snapshots_resident": len(resident),
+                "snapshot_bytes": sum(map(_nbytes, resident)),
+                "snapshot_misses": self.snapshot_misses}
 
     def decode(self, result, n_tokens: int | None = None) -> np.ndarray:
         """Greedy decode from a :meth:`prefill` result (or its bare

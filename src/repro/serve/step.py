@@ -25,10 +25,18 @@ def make_resume_prefill_step(cfg: ArchConfig, max_seq: int):
     pytree is what the caller slices into per-chunk slabs to stage for
     admission.  jit-compatible: prefix/suffix lengths are static shapes,
     so each distinct (P, S_suffix) pair compiles once.
+
+    A recurrent (Mamba-2 hybrid) arch also takes ``prefix_state``, the
+    state snapshot at the prefix's end, and ``snapshot_at`` (static: jit
+    it with ``static_argnames="snapshot_at"``), which appends the state
+    snapshot at that token to the returns.
     """
-    def resume_prefill_step(params, batch, prefix_kv=None):
+    def resume_prefill_step(params, batch, prefix_kv=None,
+                            prefix_state=None, snapshot_at=None):
         return transformer.prefill(params, cfg, batch, max_seq,
-                                   prefix_kv=prefix_kv, return_kv=True)
+                                   prefix_kv=prefix_kv,
+                                   prefix_state=prefix_state,
+                                   return_kv=True, snapshot_at=snapshot_at)
     return resume_prefill_step
 
 

@@ -2,6 +2,8 @@
 shape/NaN assertions, decode-vs-forward consistency, cache plumbing."""
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -297,11 +299,12 @@ def test_mamba1_chunked_matches_sequential(rng):
 
 def test_mamba2_chunked_matches_decode(rng):
     from repro.models import ssm
-    cfg = configs.get_arch("zamba2-2.7b").reduced()
+    cfg = dataclasses.replace(configs.get_arch("zamba2-2.7b").reduced(),
+                              ssm_chunk=4)
     b, s = 1, 8
     params = ssm.init_mamba2(jax.random.PRNGKey(0), cfg)
     x = jnp.asarray(rng.normal(size=(b, s, cfg.d_model)) * 0.3, jnp.bfloat16)
-    out_chunked, h_fin, _ = ssm.mamba2_block(params, x, cfg, chunk=4,
+    out_chunked, h_fin, _ = ssm.mamba2_block(params, x, cfg,
                                              return_state=True)
     h = jnp.zeros((b, ssm.m2_heads(cfg), cfg.ssm_head_dim, cfg.ssm_state),
                   jnp.float32)
@@ -323,7 +326,7 @@ def test_mamba2_chunked_matches_decode(rng):
 def test_cell_skip_matrix():
     """The assignment's exact skip set."""
     cells = {(a.name, s.name): ok for a, s, ok, _ in configs.all_cells()}
-    assert len(cells) == 40
+    assert len(cells) == 4 * len(configs.ARCHS) == 44
     expected_skips = {
         ("hubert-xlarge", "decode_32k"),
         ("hubert-xlarge", "long_500k"),
@@ -339,6 +342,7 @@ def test_cell_skip_matrix():
     # long_500k runs for SSM / hybrid / local-attention archs
     assert cells[("falcon-mamba-7b", "long_500k")]
     assert cells[("zamba2-2.7b", "long_500k")]
+    assert cells[("granite-4.0-h-micro", "long_500k")]
     assert cells[("gemma3-27b", "long_500k")]
 
 

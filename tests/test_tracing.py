@@ -167,9 +167,10 @@ def test_prefill_args_are_the_shapes(served):
     restored = served["on"][2]
     nbytes = sum(a.nbytes for slab in restored
                  for a in jax.tree.leaves(slab))
-    # The slabs live on the device (unbounded tier on the CPU backend).
+    # The slabs live on the device (unbounded tier on the CPU backend);
+    # an attention-only model restores no recurrent state.
     assert restore.args == {"rows": ROWS, "nbytes": nbytes,
-                            "device_nbytes": nbytes}
+                            "device_nbytes": nbytes, "state_nbytes": 0}
     first_slice = _named(served, "monarch.resume.slice")[0]
     assert first_slice.args["nbytes"] == ROWS * PROMPT // CHUNK_TOKENS \
         * sum(a.nbytes for a in jax.tree.leaves(restored[0]))
