@@ -24,7 +24,10 @@ batch (driven by ``launch/serve.py::run_request_loop``):
    ``AdmitQueue.submit_tokens(toks, slabs=...)`` — submit-after-prefill,
    so the async admission worker commits slabs while decode runs.
 4. :meth:`PrefixResumeEngine.decode` greedily decodes from the restored
-   cache, positions continuing at the full prompt length.
+   cache, positions continuing at the full prompt length.  Each step is
+   dispatched before the host reads the token it consumes, so the
+   device always has the next step queued while the host reads; the
+   last token is read with no step after it (n-1 steps for n tokens).
 
 Correctness ground rules (all pinned by ``tests/test_decode_resume.py``):
 
@@ -377,7 +380,14 @@ class PrefixResumeEngine:
         ``state``).  Returns the (B, n_tokens) decoded ids; positions
         continue at the full prompt length regardless of how much
         prefill was skipped.  Stamps ``state["first_token_at"]`` when the
-        first token reaches the host."""
+        first token reaches the host.
+
+        Step t (token t in, token t+1 out) is dispatched before token t
+        is read, so the host's read and its next dispatch overlap the
+        step running on the device.  At most one step is queued beyond
+        the running one, which holds one more cache in flight than a
+        loop that reads before it dispatches.  The last token is read
+        with no step after it: n-1 steps for n tokens."""
         state = result.state if isinstance(result, PrefillResult) else result
         n = self.decode_tokens if n_tokens is None else n_tokens
         logits, cache, pos = state["logits"], state["cache"], state["pos"]
@@ -385,19 +395,20 @@ class PrefixResumeEngine:
             raise ValueError(
                 f"decode of {n} tokens from position {pos} overflows "
                 f"max_seq={self.max_seq}")
-        with span("decode", rows=logits.shape[0], pos=pos, steps=n):
+        with span("decode", rows=logits.shape[0], pos=pos, steps=n,
+                  dispatched=max(n - 1, 0)):
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
             outs = []
             for t in range(n):
-                # Each token is read on the host before the next step is
-                # dispatched: the device idles between steps meanwhile.
+                tok = nxt
+                if t + 1 < n:
+                    with span("decode.dispatch"):
+                        nxt, _, cache = self._decode(
+                            self.params, cache, tok, jnp.int32(pos + t))
                 with span("decode.sync"):
-                    outs.append(np.asarray(nxt))
+                    outs.append(np.asarray(tok))
                 if t == 0:
                     state["first_token_at"] = time.monotonic()
-                with span("decode.dispatch"):
-                    nxt, _, cache = self._decode(
-                        self.params, cache, nxt, jnp.int32(pos + t))
         return np.concatenate(outs, axis=1)
 
     def request_fns(self, n_tokens: int | None = None):

@@ -279,6 +279,54 @@ def test_engine_truncates_run_at_missing_slab(rng):
         q.close()
 
 
+# The tiny hybrid of tests/test_hybrid_resume.py: granite-4.0-h-micro's
+# layer period at reduced widths, its SSD chunk shrunk to 32.
+PIPELINE_ARCHS = {
+    "dense": lambda: _arch("global"),
+    "hybrid": lambda: dataclasses.replace(
+        configs.get_arch("granite-4.0-h-micro").reduced(), ssm_chunk=32),
+}
+
+
+@pytest.fixture(scope="module", params=list(PIPELINE_ARCHS))
+def pipeline_engine(request):
+    """A jitted engine per architecture, shared by the cases below so
+    each compiles its prefill and decode step once."""
+    cfg = PIPELINE_ARCHS[request.param]()
+    params = transformer.init_params(jax.random.PRNGKey(1), cfg)
+    return PrefixResumeEngine(params, cfg, max_seq=80, index=_mk_index(),
+                              decode_tokens=4)
+
+
+@pytest.mark.parametrize("n", [1, 2, 5])
+def test_decode_runs_one_step_ahead_and_drops_none(rng, monkeypatch,
+                                                   pipeline_engine, n):
+    """The decode loop dispatches step t before it reads token t: the
+    tokens equal a loop that reads each token before the next step, and
+    n tokens take n-1 steps (none whose result is thrown away)."""
+    engine = pipeline_engine
+    toks = rng.integers(1, engine.cfg.vocab_size, (2, 64)).astype(np.int32)
+    res = engine.prefill(toks)
+    step = engine._decode
+    calls = []
+
+    def counted(*args):
+        calls.append(args[3])
+        return step(*args)
+
+    monkeypatch.setattr(engine, "_decode", counted)
+    got = engine.decode(res, n)
+    assert [int(p) for p in calls] == list(range(64, 64 + n - 1))
+
+    cache, want = res.state["cache"], []
+    nxt = jnp.argmax(res.state["logits"], -1).astype(jnp.int32)[:, None]
+    for t in range(n):
+        want.append(np.asarray(nxt))
+        nxt, _, cache = step(engine.params, cache, nxt, jnp.int32(64 + t))
+    assert got.shape == (2, n)
+    np.testing.assert_array_equal(got, np.concatenate(want, axis=1))
+
+
 # ---------------------------------------------------------------------------
 # Slab residency: device slabs, host slabs and a mix restore the same bits.
 # ---------------------------------------------------------------------------

@@ -204,13 +204,17 @@ def test_router_and_edge_args(served):
 
 
 def test_one_sync_per_decoded_token(served):
+    """One read a token, one dispatch a step: the last token is read
+    with no step after it, so n tokens take n-1 steps."""
     decodes = _named(served, "monarch.decode")
     assert len(decodes) == 2
     for d in decodes:
-        assert d.args == {"rows": ROWS, "pos": PROMPT, "steps": DECODE}
-        for child in ("monarch.decode.sync", "monarch.decode.dispatch"):
+        assert d.args == {"rows": ROWS, "pos": PROMPT, "steps": DECODE,
+                          "dispatched": DECODE - 1}
+        for child, count in (("monarch.decode.sync", DECODE),
+                             ("monarch.decode.dispatch", DECODE - 1)):
             assert sum(_inside(s, d) for s in _named(served, child)) \
-                == DECODE
+                == count
 
 
 def test_tokens_do_not_depend_on_the_profiler(served):
