@@ -23,27 +23,7 @@ from repro.kernels.hopscotch import ops as hop_ops
 from repro.kernels.string_match import ops as sm_ops
 from repro.kernels.xam_search import ops as xam_ops
 from repro.serve.admit_queue import AdmitQueue
-from repro.serve.kv_index import (KVIndexConfig, MonarchKVIndex,
-                                  _install_column)
-
-
-def _admit_hostloop(idx: MonarchKVIndex, fps: np.ndarray):
-    """The pre-batching admission flow (PR 2's `_admit_one` loop): one
-    jitted install dispatch + per-fingerprint host bookkeeping PER
-    fingerprint.  Kept here as the measured comparator for the O(1)-call
-    batched pipeline (`kv_index_admit` vs `kv_index_admit_hostloop`)."""
-    for fp in fps:
-        s = int(idx._set_of(np.asarray([fp], np.uint32))[0])
-        free = np.nonzero(~idx.valid_np[s])[0]
-        w = int(free[0]) if free.size else 0
-        bitcol = jnp.asarray(xam_ops.words_to_bits_np(
-            np.asarray([fp], np.uint32), idx.cfg.key_bits)[0])
-        idx.bits, idx.valid, idx.fp_of = _install_column(
-            idx.bits, idx.valid, idx.fp_of,
-            jnp.int32(s), jnp.int32(w), bitcol, jnp.uint32(fp))
-        idx.valid_np[s, w] = True
-        idx.slot_of[int(fp)] = (s, w)
-    jax.block_until_ready(idx.valid)
+from repro.serve.kv_index import KVIndexConfig, MonarchKVIndex
 
 
 def run(csv_rows: list[str], quick: bool = False):
@@ -136,11 +116,10 @@ def run(csv_rows: list[str], quick: bool = False):
           f"({t.median_us / (32 * 512 // 16):.1f} us/chunk)")
     csv_rows.append(f"kv_index_lookup_32x512,{t.median_us:.0f},")
 
-    # set-sharded lookup: same 32x512 batch at n_shards=4, now ONE device
+    # set-sharded lookup: same 32x512 batch at n_shards=4, ONE device
     # dispatch regardless of the shard count (the stacked shard_map path
-    # on a ("sets",) mesh; collapsed to the single fused launch on this
-    # 1-device rig — either way the per-shard host fan-out is gone, which
-    # is what the number tracks vs the PR-4 baseline).
+    # on a ("sets",) mesh; collapsed to the single fused launch on a
+    # 1-device host).
     idx_s = MonarchKVIndex(KVIndexConfig(n_sets=8, n_shards=4))
     idx_s.admit(toks_big)
     idx_s.admit(toks_big)
@@ -152,20 +131,6 @@ def run(csv_rows: list[str], quick: bool = False):
           f"{idx_s.stats.lookups} lookups)")
     csv_rows.append(f"kv_index_lookup_sharded,{t.median_us:.0f},4shards")
 
-    # the kept PR-4 host fan-out (differential reference): one pallas_call
-    # per occupied shard — the measured comparator for the single dispatch
-    idx_f = MonarchKVIndex(KVIndexConfig(n_sets=8, n_shards=4),
-                           dispatch="fanout")
-    idx_f.admit(toks_big)
-    idx_f.admit(toks_big)
-    t2 = time_callable(lambda: idx_f.lookup(toks_big), warmup=1, reps=reps)
-    timings["kv_index_lookup_fanout"] = t2
-    print(f"kv_index lookup 32x512 tokens, 4-shard host fan-out: "
-          f"{t2.median_us:.0f} us -> single-dispatch speedup "
-          f"{t2.median_us / t.median_us:.1f}x")
-    csv_rows.append(f"kv_index_lookup_fanout,{t2.median_us:.0f},"
-                    f"{t2.median_us / t.median_us:.1f}x")
-
     # device-resident rotation: the set+7 remap (donated roll + ppermute
     # boundary exchange across partitions; pure donated roll when
     # collapsed) — plane data never moves through the host.
@@ -174,8 +139,7 @@ def run(csv_rows: list[str], quick: bool = False):
     print(f"kv_index rotate (device remap, 4 shards): {t.median_us:.0f} us")
     csv_rows.append(f"kv_index_rotate,{t.median_us:.0f},4shards")
 
-    # batched admission: ONE jitted device call per 64-fingerprint batch,
-    # vs the pre-PR host loop (one install dispatch per fingerprint).
+    # batched admission: ONE jitted device call per 64-fingerprint batch.
     # Fresh unique fingerprints every rep so the install path (not the
     # resident fast path) is what's timed.
     n_fp, n_batches = 64, reps + 4
@@ -190,21 +154,9 @@ def run(csv_rows: list[str], quick: bool = False):
           f"({t.median_us / n_fp:.1f} us/install)")
     csv_rows.append(f"kv_index_admit,{t.median_us:.0f},64fp")
 
-    loop_batches = iter(np.split(all_fps + 1_000_000, n_batches))
-    idx_l = MonarchKVIndex(KVIndexConfig(n_sets=8, admit_after_reads=0))
-    t2 = time_callable(lambda: _admit_hostloop(idx_l, next(loop_batches)),
-                       warmup=2, reps=reps)
-    timings["kv_index_admit_hostloop"] = t2
-    print(f"kv_index admit 64 fps (pre-PR host loop): {t2.median_us:.0f} us "
-          f"-> batched speedup {t2.median_us / t.median_us:.1f}x")
-    csv_rows.append(f"kv_index_admit_hostloop,{t2.median_us:.0f},"
-                    f"{t2.median_us / t.median_us:.1f}x")
-
     # stacked admission at 4 set shards: STILL one device dispatch per
     # batch (round-grid shard_map over the ("sets",) mesh; collapsed to
-    # the single donated scan on this 1-device rig), vs the kept
-    # per-partition fanout oracle paying one dispatch per occupied
-    # partition.
+    # the single donated scan on a 1-device host).
     st_batches = iter(np.split(all_fps + 4_000_000, n_batches))
     idx_st = MonarchKVIndex(KVIndexConfig(
         n_sets=8, n_shards=4, admit_after_reads=0))
@@ -216,18 +168,6 @@ def run(csv_rows: list[str], quick: bool = False):
           f"{t.median_us:.0f} us ({idx_st.stats.admit_calls} dispatches/"
           f"{reps + 2} batches)")
     csv_rows.append(f"kv_index_admit_stacked,{t.median_us:.0f},4shards")
-
-    fan_batches = iter(np.split(all_fps + 5_000_000, n_batches))
-    idx_fa = MonarchKVIndex(KVIndexConfig(
-        n_sets=8, n_shards=4, admit_after_reads=0), dispatch="fanout")
-    t2 = time_callable(lambda: idx_fa.admit_fps(next(fan_batches)),
-                       warmup=2, reps=reps)
-    timings["kv_index_admit_fanout"] = t2
-    print(f"kv_index admit 64 fps, 4-shard fanout oracle: "
-          f"{t2.median_us:.0f} us -> stacked speedup "
-          f"{t2.median_us / t.median_us:.1f}x")
-    csv_rows.append(f"kv_index_admit_fanout,{t2.median_us:.0f},"
-                    f"{t2.median_us / t.median_us:.1f}x")
 
     # device-resident hopscotch insert (apps/hashtable.py device backend):
     # one donated device call per insert — windowed scatter + bounded

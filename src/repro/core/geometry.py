@@ -100,8 +100,8 @@ def port_select(k: int, mode_column_in: bool, grid: int = 8):
 # Set-axis sharding (serving): contiguous-block ownership of the set planes.
 # The serving index (serve/kv_index.py) splits its n_sets CAM sets across
 # n_shards mesh devices; these helpers are THE shard-address arithmetic, so
-# host grouping, admission fan-out and the rotation remap all agree on which
-# shard owns which physical set.
+# host grouping, the admission grid and the rotation remap all agree on
+# which shard owns which physical set.
 # ---------------------------------------------------------------------------
 
 

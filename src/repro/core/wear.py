@@ -25,8 +25,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 
@@ -299,59 +297,6 @@ def window_would_exceed(state: WearState, cfg, superset: jnp.ndarray,
     cycle = jnp.asarray(cycle, jnp.int32)
     _, _, writes_now = _window_now(state, cfg, superset, cycle)
     return (writes_now + 1) > cfg.window_write_budget
-
-
-def shard_states(cfg: WearConfig, n_shards: int) -> list[WearState]:
-    """Per-shard §8 wear states for a set-sharded serving index.
-
-    Each shard tracks its own contiguous block of
-    ``cfg.n_supersets // n_shards`` supersets; because every t_MWW
-    decision reads only per-superset rows (``window_writes`` /
-    ``window_start`` / ``locked_until``), splitting the state this way is
-    decision-equivalent to one global state — only the global SWT scalars
-    (write/superset/dirty counters) become shard-local, and the serving
-    index disables the rotate signals those feed.
-
-    Returns a list of ``n_shards`` fresh :func:`init_state` states, each
-    sized ``n_supersets // n_shards`` (which must divide evenly).
-    """
-    if n_shards < 1 or cfg.n_supersets % n_shards != 0:
-        raise ValueError(
-            f"n_shards={n_shards} must divide n_supersets={cfg.n_supersets}")
-    sub = dataclasses.replace(cfg, n_supersets=cfg.n_supersets // n_shards)
-    return [init_state(sub) for _ in range(n_shards)]
-
-
-def concat_states(states: list[WearState]) -> WearState:
-    """Global read-only view over per-shard wear states.
-
-    Per-superset fields are concatenated in shard order (shard k's rows
-    land at global supersets ``[k * s_local, (k + 1) * s_local)`` —
-    matching ``geometry.shard_of_set`` ownership); scalar counters are
-    summed; the rotary offsets are taken from shard 0 (the serving index
-    never consumes them).  Used for reporting only — never write through
-    the result.
-    """
-    if len(states) == 1:
-        return states[0]
-    # Shard states may live on different mesh devices: gather through the
-    # host (this is a reporting path, never a compute path).
-    cat = lambda f: jnp.asarray(
-        np.concatenate([np.asarray(getattr(s, f)) for s in states]))
-    tot = lambda f: jnp.asarray(
-        sum(np.asarray(getattr(s, f)) for s in states))
-    return WearState(
-        swt_w=cat("swt_w"), swt_d=cat("swt_d"),
-        write_counter=tot("write_counter"),
-        superset_counter=tot("superset_counter"),
-        dirty_counter=tot("dirty_counter"),
-        offsets=states[0].offsets,
-        window_writes=cat("window_writes"),
-        window_start=cat("window_start"),
-        locked_until=cat("locked_until"),
-        total_rotates=tot("total_rotates"),
-        total_flushed=tot("total_flushed"),
-    )
 
 
 def record_writes(state: WearState, cfg, supersets, makes_dirty, cycles,

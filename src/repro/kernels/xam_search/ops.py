@@ -26,16 +26,14 @@ from repro.kernels.xam_search.kernel import (
 from repro.kernels.xam_search.ref import xam_search_ref
 
 #: Host-side fused-search launches since import (every device dispatch of a
-#: multi-set search bumps it exactly once — the unsharded single call, each
-#: per-shard call of the host fan-out, and the ONE shard_map dispatch of the
-#: stacked path).  The dispatch-count tests read and reset it.
+#: multi-set search bumps it exactly once — the unsharded single call and
+#: the ONE shard_map dispatch of the stacked path).  The dispatch-count
+#: tests read and reset it.
 LAUNCH_COUNT = 0
 
 #: Host-side ADMISSION launches since import — the write-path twin of
-#: ``LAUNCH_COUNT``.  ``MonarchKVIndex`` bumps it once per device admission
-#: dispatch: exactly once per batch on the stacked single-dispatch path
-#: (``admit_dispatch="auto"``), once per partition holding candidates on
-#: the kept per-partition fan-out oracle (``admit_dispatch="fanout"``).
+#: ``LAUNCH_COUNT``.  ``MonarchKVIndex`` bumps it once per admission
+#: batch, which is one device dispatch at every shard count.
 ADMIT_LAUNCH_COUNT = 0
 
 #: Adaptive query-block policy, now MEASURED: ``kernels/autotune.py``
@@ -291,37 +289,6 @@ def group_admits_stacked(set_ids: np.ndarray, n_sets: int, n_parts: int,
     return part_of, row, col, n_rounds, round_width
 
 
-def _multiset_dispatch(key_bits: np.ndarray, set_ids: np.ndarray,
-                       planes: jnp.ndarray, valid: jnp.ndarray, *,
-                       block_q: int, scoring: str, interpret: bool):
-    """Group + pad + LAUNCH one fused multi-set search; defer the sync.
-
-    Returns ``(out, slot)``: ``out`` is the in-flight (padded_q,) device
-    result, ``slot`` the padded row of each input query.  Callers that fan
-    out over shards dispatch every shard's kernel before materializing any
-    result, so the launches overlap under jax async dispatch."""
-    global LAUNCH_COUNT
-    LAUNCH_COUNT += 1
-    key_bits = np.asarray(key_bits, np.int8)
-    _, r = key_bits.shape
-    n_sets = planes.shape[0]
-    slot, block_sets, padded_q, n_blocks = group_queries_by_set(
-        set_ids, n_sets, block_q)
-    keys_p = np.zeros((padded_q, r), np.int8)
-    masks_p = np.zeros((padded_q, r), np.int8)
-    keys_p[slot] = key_bits
-    masks_p[slot] = 1
-    # Query-side operands follow the planes' placement, so shard-local
-    # calls run on the shard's own mesh device.
-    put = lambda x: jax.device_put(jnp.asarray(x), planes.sharding)
-    live = (np.arange(len(block_sets)) < n_blocks).astype(np.int32)
-    out = xam_search_multiset_pallas(
-        put(keys_p), put(masks_p), planes, valid,
-        put(block_sets), put(live),
-        block_q=block_q, scoring=scoring, interpret=interpret)
-    return out, slot
-
-
 def xam_search_multiset(key_bits: np.ndarray, set_ids: np.ndarray,
                         planes: jnp.ndarray, valid: jnp.ndarray, *,
                         block_q: int | None = None,
@@ -356,11 +323,25 @@ def xam_search_multiset(key_bits: np.ndarray, set_ids: np.ndarray,
     np.ndarray, shape (Q,), int32
         First matching *valid* way per query; ``-1`` = miss.
     """
-    out, slot = _multiset_dispatch(
-        key_bits, set_ids, planes, valid,
-        block_q=_pick_block_q(len(set_ids), block_q,
-                              plane_format_of(planes)),
-        scoring=_resolve_scoring(scoring), interpret=interpret)
+    block_q = _pick_block_q(len(set_ids), block_q, plane_format_of(planes))
+    global LAUNCH_COUNT
+    LAUNCH_COUNT += 1
+    key_bits = np.asarray(key_bits, np.int8)
+    _, r = key_bits.shape
+    slot, block_sets, padded_q, n_blocks = group_queries_by_set(
+        set_ids, planes.shape[0], block_q)
+    keys_p = np.zeros((padded_q, r), np.int8)
+    masks_p = np.zeros((padded_q, r), np.int8)
+    keys_p[slot] = key_bits
+    masks_p[slot] = 1
+    # Query-side operands follow the planes' placement.
+    put = lambda x: jax.device_put(jnp.asarray(x), planes.sharding)
+    live = (np.arange(len(block_sets)) < n_blocks).astype(np.int32)
+    out = xam_search_multiset_pallas(
+        put(keys_p), put(masks_p), planes, valid,
+        put(block_sets), put(live),
+        block_q=block_q, scoring=_resolve_scoring(scoring),
+        interpret=interpret)
     return np.asarray(out)[slot]
 
 
@@ -398,11 +379,9 @@ def xam_search_multiset_stacked(key_bits: np.ndarray, set_ids: np.ndarray,
     :func:`group_queries_by_set_stacked` turns the whole query batch into
     a dense ``(n_parts, Qmax, R)`` operand; with a ``("sets",)`` ``mesh``
     the search is ONE jitted ``shard_map`` call — XLA places every
-    shard's fused kernel from a single program, replacing the
-    one-``pallas_call``-per-shard host fan-out of
-    :func:`xam_search_multiset_sharded`.  Without a mesh (co-located
-    shards) the same stacked layout flattens into ONE plain fused launch
-    over the global planes.
+    shard's fused kernel from a single program.  Without a mesh
+    (co-located shards) the same stacked layout flattens into ONE plain
+    fused launch over the global planes.
 
     Parameters
     ----------
@@ -414,8 +393,8 @@ def xam_search_multiset_stacked(key_bits: np.ndarray, set_ids: np.ndarray,
         see :func:`xam_search_multiset`)
         Stored bits for ALL sets.  With ``mesh`` this must be sharded
         ``P("sets")`` over it (contiguous blocks, shard k's sets on mesh
-        device k — the layout ``MonarchKVIndex`` assembles zero-copy from
-        its per-shard planes); without a mesh any single-device array.
+        device k — the layout ``MonarchKVIndex`` keeps its planes in);
+        without a mesh any single-device array.
     valid : jnp.ndarray, shape (n_sets, C), int8
         Validity planes, sharded like ``planes``.
     mesh : Mesh | None
@@ -485,82 +464,6 @@ def xam_search_multiset_stacked(key_bits: np.ndarray, set_ids: np.ndarray,
         out = np.asarray(fn(put(keys_p), put(masks_p), put(block_sets),
                             put(n_blocks), planes, valid))
     return out[part_of, slot].astype(np.int32)
-
-
-def xam_search_multiset_sharded(key_bits: np.ndarray, set_ids: np.ndarray,
-                                planes_by_shard, valid_by_shard, *,
-                                block_q: int | None = None,
-                                scoring: str | None = None,
-                                interpret: bool | None = None) -> np.ndarray:
-    """Fan a query batch out over set-sharded CAM planes.
-
-    Two-level extension of :func:`group_queries_by_set`'s pow2 bucketing:
-    queries are first split by owning shard (``set_id // sets_per_shard``,
-    contiguous-block ownership — ``geometry.shard_of_set``), then each
-    shard's sub-batch is grouped into per-set blocks and searched by ONE
-    shard-local :func:`xam_search_multiset` launch against that shard's
-    ``(sets_per_shard, R, C)`` planes.  All shard kernels are dispatched
-    before any result is materialized, so on a multi-device ``("sets",)``
-    mesh the searches run concurrently.
-
-    Parameters
-    ----------
-    key_bits : np.ndarray, shape (Q, R), {0, 1}
-        Host-side query bit rows.
-    set_ids : np.ndarray, shape (Q,), int
-        GLOBAL physical set ids in ``[0, n_shards * sets_per_shard)``.
-    planes_by_shard : sequence of jnp.ndarray, (sets_per_shard, R, C) int8
-        Shard-local stored-bit planes (shard k owns global sets
-        ``[k * sets_per_shard, (k + 1) * sets_per_shard)``).
-    valid_by_shard : sequence of jnp.ndarray, (sets_per_shard, C) int8
-        Shard-local validity planes.
-
-    Returns
-    -------
-    np.ndarray, shape (Q,), int32
-        First matching valid way per query (way index is set-local, as in
-        the unsharded path); ``-1`` = miss.
-
-    Notes
-    -----
-    With one shard this is EXACTLY :func:`xam_search_multiset` — same
-    grouping, same kernel, same inputs — which pins the single-shard
-    serving path bit-identical to the unsharded implementation.
-
-    This host fan-out is the DIFFERENTIAL REFERENCE for the
-    single-dispatch path: :func:`xam_search_multiset_stacked` answers the
-    same ``(key_bits, set_ids)`` batch from one ``shard_map`` dispatch
-    over the stacked ``(n_parts, Qmax, R)`` layout (contract in
-    :func:`group_queries_by_set_stacked` — per-shard blocks padded to a
-    common pow2 ``Qmax``, per-shard valid block counts scalar-prefetched)
-    and must return bit-identical ways; ``tests/test_kv_index_differential
-    .py`` replays randomized schedules through both after every op.
-    """
-    n_shards = len(planes_by_shard)
-    if n_shards == 1:
-        return xam_search_multiset(
-            key_bits, set_ids, planes_by_shard[0], valid_by_shard[0],
-            block_q=block_q, scoring=scoring, interpret=interpret)
-    scoring = _resolve_scoring(scoring)
-    key_bits = np.asarray(key_bits, np.int8)
-    set_ids = np.asarray(set_ids, np.int64)
-    s_local = planes_by_shard[0].shape[0]
-    shard_ids = set_ids // s_local
-    # Dispatch every shard's fused search before syncing any of them.
-    pending = []
-    for k in np.unique(shard_ids):
-        sel = np.nonzero(shard_ids == k)[0]
-        out, slot = _multiset_dispatch(
-            key_bits[sel], set_ids[sel] - int(k) * s_local,
-            planes_by_shard[int(k)], valid_by_shard[int(k)],
-            block_q=_pick_block_q(sel.size, block_q,
-                                  plane_format_of(planes_by_shard[0])),
-            scoring=scoring, interpret=interpret)
-        pending.append((sel, slot, out))
-    ways = np.empty(set_ids.shape[0], np.int32)
-    for sel, slot, out in pending:
-        ways[sel] = np.asarray(out)[slot]
-    return ways
 
 
 # ---------------------------------------------------------------------------

@@ -44,13 +44,13 @@ def make_host_mesh() -> Mesh:
 def set_partitions(n_shards: int) -> int:
     """Device-partition count for ``n_shards`` logical set shards.
 
-    The single-dispatch lookup/rotation paths shard the global plane
-    arrays contiguously over the ``("sets",)`` mesh, so the mesh size
-    must DIVIDE the logical shard count (partition boundaries coarsen
-    shard boundaries).  Returns the largest divisor of ``n_shards`` that
-    this host's device count can hold — 1 on a single-device host, where
-    every logical shard co-locates and the index collapses to the
-    unsharded single-launch path."""
+    The serving index shards its global plane arrays contiguously over
+    the ``("sets",)`` mesh, so the mesh size must DIVIDE the logical
+    shard count (partition boundaries coarsen shard boundaries).  Returns
+    the largest divisor of ``n_shards`` that this host's device count can
+    hold — 1 on a single-device host, where every logical shard
+    co-locates and the index collapses to the unsharded single-launch
+    path."""
     devices = len(jax.devices())
     if n_shards <= 1 or devices <= 1:
         return 1
@@ -91,25 +91,9 @@ def make_set_mesh(n_shards: int) -> Mesh | None:
     return Mesh(np.asarray(jax.devices()[:n]), ("sets",))
 
 
-def set_shard_devices(mesh: Mesh | None, n_shards: int) -> list | None:
-    """Per-shard device assignment over a ``make_set_mesh`` mesh.
-
-    Returns a length-``n_shards`` list mapping shard k to a mesh device
-    in CONTIGUOUS blocks (``k * n_devices // n_shards`` — contiguous so
-    the per-shard placement agrees with the ``NamedSharding(mesh,
-    P("sets"))`` partitions the single-dispatch paths assemble), or
-    ``None`` when ``mesh`` is None (single-device host: callers skip
-    explicit placement entirely, which keeps the 1-shard path
-    byte-identical to the unsharded code)."""
-    if mesh is None:
-        return None
-    devs = list(mesh.devices.flat)
-    return [devs[k * len(devs) // n_shards] for k in range(n_shards)]
-
-
 def set_axis_sharding(mesh: Mesh) -> NamedSharding:
     """Contiguous leading-axis sharding over the ``("sets",)`` mesh —
-    the layout of every assembled global plane array."""
+    the layout of the index's plane and per-set arrays."""
     return NamedSharding(mesh, P("sets"))
 
 

@@ -9,15 +9,13 @@ MonarchKVIndex prefix cache.
 the published widths and cuts depth to one chip's share.)
 
 The request loop is the same flow examples/serve_prefix_cache.py
-demonstrates; this launcher adds mesh placement (params TP/FSDP-sharded,
-cache sharded per ``cache_specs`` — ``--seq-shard-kv`` enables the §Perf
-split-KV layout) and batch scheduling over a request queue.  The loop
-itself lives in :func:`run_request_loop` — one implementation shared by
-this launcher (closed-loop: the next batch starts when the previous
-finished) and by ``benchmarks/serve_bench.py`` (open-loop: scheduled
-Poisson/replayed-trace arrivals, latency charged from the SCHEDULED
-arrival so backlog shows up as queueing delay instead of being
-coordinated-omission'd away).
+demonstrates; this launcher adds mesh placement (params TP/FSDP-sharded)
+and batch scheduling over a request queue.  The loop itself lives in
+:func:`run_request_loop` — one implementation shared by this launcher
+(closed-loop: the next batch starts when the previous finished) and by
+``benchmarks/serve_bench.py`` (open-loop: scheduled Poisson/replayed-trace
+arrivals, latency charged from the SCHEDULED arrival so backlog shows up
+as queueing delay instead of being coordinated-omission'd away).
 
 Index scaling knobs (see docs/SERVING.md for the full operator guide):
 ``--n-shards`` splits the Monarch index's CAM sets across the
@@ -284,8 +282,6 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--prompt-len", type=int, default=96)
     ap.add_argument("--decode-tokens", type=int, default=8)
-    ap.add_argument("--seq-shard-kv", action="store_true",
-                    help="§Perf: split-KV decode cache layout")
     ap.add_argument("--no-resume", action="store_true",
                     help="disable the prefix-cache DECODE resume path "
                          "(index still counts hits, but every request "

@@ -5,67 +5,56 @@ SHARDED along the set axis across a ``("sets",)`` device mesh
 (``launch/mesh.make_set_mesh``).  The paper's headline win is in-package
 parallelism — many XAM subarrays searched concurrently behind one wide
 interface (§III) — and the set axis is exactly that parallelism at serving
-scale: shard k owns the contiguous block of physical sets
-``[k * sets_per_shard, (k + 1) * sets_per_shard)`` (``geometry.
-shard_of_set``) and carries its own stored-bit/validity/fingerprint
-planes, D̄&R̄ metadata, per-set replacement counters and §8 ``WearState``
-on its own mesh device.
+scale.  Each CAM plane and counter is ONE array over all ``n_sets`` sets:
+a plain device array on one device, a ``P("sets")`` array on a set mesh,
+where mesh device k holds the contiguous block of physical sets
+``[k * sets_per_part, (k + 1) * sets_per_part)`` (``geometry.
+shard_of_set``): its stored-bit/validity/fingerprint planes, D̄&R̄
+metadata, per-set replacement counters and §8 wear rows.
 
 Data flow per batch:
 
 * LOOKUP: every 16-token chunk is fingerprinted (murmur3) and the whole
-  batch is answered by ONE device dispatch regardless of the shard
-  count: the two-level host grouping emits a stacked ``(n_shards, Qmax,
-  R)`` padded layout (per-shard per-set blocks, Qmax pow2-bucketed,
-  per-shard valid block counts scalar-prefetched) and
-  ``xam_ops.xam_search_multiset_stacked`` wraps the fused multiset
-  kernel in a ``shard_map`` over the ``("sets",)`` mesh, so XLA places
-  all per-shard searches from a single call — no per-shard host
-  round-trips.  With one shard (or all shards co-located on one device)
-  the path IS the unsharded fused kernel, bit for bit.  The PR-4 host
-  fan-out (one ``pallas_call`` per shard) survives as the differential
-  reference behind ``dispatch="fanout"``.
-* ADMISSION: like lookup, ONE device dispatch per batch at every shard
-  count.  The host packs candidates into the ROUND GRID of
-  ``xam_ops.group_admits_stacked`` — a ``(n_parts, n_rounds,
-  round_width)`` stacked layout where round r holds each set's rank-r
-  candidate (per-set prefix ranks; both axes pow2-bucketed) — and one
-  jitted, donated-state dispatch (``shard_map`` over the ``("sets",)``
-  mesh when partitions span devices, the plain jitted scan otherwise)
-  runs ``_admit_rounds_body``: a ``lax.scan`` over rounds whose step
-  admits a whole round VECTORIZED — residency probe, no-allocate gate,
+  batch is answered by ONE device dispatch: the fused multiset kernel
+  (``xam_ops.xam_search_multiset``) over the planes on one device, and on
+  a set mesh its ``shard_map`` wrapping
+  (``xam_ops.xam_search_multiset_stacked``: a stacked ``(n_parts, Qmax,
+  R)`` layout of per-partition per-set blocks, Qmax pow2-bucketed,
+  per-partition valid block counts scalar-prefetched), so XLA places all
+  per-partition searches from a single call.
+* ADMISSION: ONE device dispatch per batch as well.  The host packs
+  candidates into the ROUND GRID of ``xam_ops.group_admits_stacked`` — a
+  ``(n_parts, n_rounds, round_width)`` layout where round r holds each
+  set's rank-r candidate (per-set prefix ranks; both axes pow2-bucketed)
+  — and ``_admit_rounds_body``, jitted as is on one device and through
+  ``shard_map`` on a set mesh, runs a ``lax.scan`` over rounds whose step
+  admits a whole round VECTORIZED: residency probe, no-allocate gate,
   t_MWW throttle (``core/wear.py`` — the same machinery the Fig. 11
   simulator scans), cold-victim way selection, column install and
   vectorized wear recording (``wear.record_write_rows``).  Decisions
   couple only through per-set state (residency, window budget, the
   per-set replacement counter) and a round's sets are pairwise distinct
   by construction (same-set candidates differ in rank), so the
-  round-parallel schedule is bit-equivalent to one global sequential
-  scan — the shard-invariance tests replay randomized schedules at
-  ``n_shards in {1, 2, 4}`` and require identical hits, installs and
-  wear reports.  The PR-5 per-partition ``_admit_batch`` scan survives
-  as the differential oracle behind ``admit_dispatch="fanout"``
-  (``tests/test_kv_index_differential.py`` pins both paths bit-identical
-  after every op).
+  round-parallel schedule is bit-equivalent to one sequential scan of
+  the candidates in batch order.  ``tests/kv_index_reference.py`` keeps
+  that scan as the reference the differential tests replay randomized
+  schedules against, after every op, at ``n_shards in {1, 2, 4}``.
 * ROTATION: the rotary remap is the GLOBAL permutation ``set -> set + 7``
-  applied to every shard's planes in lockstep with the ``_set_of`` offset
-  bump, so resident entries stay searchable after the remap (pinned since
-  the batched-admission PR) and the fingerprint -> physical-set mapping —
-  hence wear accounting — is independent of the shard count.  Across
-  shards the roll is DEVICE-RESIDENT: per-shard plane rolls plus a
-  ``ppermute`` boundary exchange of the sets that cross shard edges
-  under the global permutation (``geometry.shard_roll_plan`` /
-  ``mesh.make_sharded_roll``) — bits/valid/fp_of/read_after never move
-  through the host, and set_writes/WearState track PHYSICAL sets so they
-  never move at all.
+  applied to the planes in lockstep with the ``_set_of`` offset bump, so
+  resident entries stay searchable after the remap and the fingerprint
+  -> physical-set mapping — hence wear accounting — is independent of
+  the shard count.  On one device it is one donated roll; on a set mesh,
+  per-partition rolls plus a ``ppermute`` boundary exchange of the sets
+  that cross partition edges (``geometry.shard_roll_plan`` /
+  ``mesh.make_sharded_roll``).  Plane data never moves through the host,
+  and set_writes/wear track PHYSICAL sets so they never move at all.
 
-Intentional change pinned by the shard-invariance tests: the replacement
-counter is PER SET (it was one free-running global scalar).  A global
-counter couples victim choice in one set to eviction traffic in every
-other set — the single cross-set dependency that would make admission
-results depend on how sets are sharded.  Per-set counters keep the
-§8 "random counter" replacement flavor while making the per-shard scans
-exactly equal to the global sequential order.
+The replacement counter is PER SET.  A global counter would couple victim
+choice in one set to eviction traffic in every other set — the single
+cross-set dependency that would make admission results depend on how
+sets are sharded.  Per-set counters keep the §8 "random counter"
+replacement flavor while making every partition's scan equal to the
+global sequential order.
 
 Asynchronous admission lives in ``serve/admit_queue.py``: ``AdmitQueue``
 moves ``admit_fps`` off the serving loop onto a worker thread (installs
@@ -80,11 +69,12 @@ endurance and an expected op rate — the serving twin of
 ``--lifetime-years`` (and the shard count as ``--n-shards``).
 
 The index is exercised by examples/serve_prefix_cache.py and
-benchmarks/kernels_bench.py (``kv_index_admit`` pins the batched path
-against the pre-batching host loop; ``kv_index_lookup_sharded`` and
-``kv_index_admit_async`` pin the sharded fan-out and the queue overlap).
-See docs/ARCHITECTURE.md for the paper-concept -> code map and
-docs/SERVING.md for the operator guide.
+benchmarks/kernels_bench.py (``kv_index_admit`` and
+``kv_index_admit_stacked`` time the batched admission,
+``kv_index_lookup_sharded`` the lookup at four shards and
+``kv_index_admit_async`` the queue overlap).  See docs/ARCHITECTURE.md
+for the paper-concept -> code map and docs/SERVING.md for the operator
+guide.
 """
 from __future__ import annotations
 
@@ -105,8 +95,7 @@ from repro.core import wear
 from repro.core.timing import SECONDS_PER_YEAR, t_mww_seconds
 from repro.data.pipeline import (fingerprint_blocks, murmur3_np,
                                  prefix_fingerprint_blocks)
-from repro.kernels.common import (
-    bucket_pow2, pack_bits_np, resolve_plane_format)
+from repro.kernels.common import pack_bits_np, resolve_plane_format
 from repro.kernels.xam_search import ops as xam_ops
 from repro.launch import mesh as mesh_mod
 from repro.serve.spans import span
@@ -243,118 +232,8 @@ class KVIndexStats:
     throttled: int = 0            # t_MWW window exhausted
     evictions: int = 0
     rotations: int = 0
-    searches: int = 0             # lookup dispatches (1 per batch on the
-                                  # single-dispatch paths; 1 per occupied
-                                  # shard on the "fanout" reference)
-    admit_calls: int = 0          # jitted admit launches (1 per batch on
-                                  # the stacked path; 1 per partition
-                                  # holding candidates on "fanout")
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-def _install_column(bits, valid, fp_of, s, w, bitcol, fp):
-    """Device-side install of ONE CAM column.  Kept as the pre-batching
-    primitive: benchmarks/kernels_bench.py uses it to measure the host-loop
-    admission flow the batched pipeline replaced."""
-    bits = bits.at[s, :, w].set(bitcol)
-    valid = valid.at[s, w].set(jnp.int8(1))
-    fp_of = fp_of.at[s, w].set(fp)
-    return bits, valid, fp_of
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4, 5, 6))
-def _admit_batch(bits, valid, fp_of, read_after, set_writes, counter,
-                 wstate, wdyn, admit_after, sets, fps, bitcols, cycles,
-                 touches, active):
-    """ONE device call admits a whole (shard-local) candidate batch.
-
-    A ``lax.scan`` over the (order-preserving) candidate list; each step is
-    the full per-fingerprint admission pipeline: residency probe ->
-    read_after bump | no-allocate gate | t_MWW throttle -> way select ->
-    column install fused with §8 wear recording.  Same-set collisions
-    resolve through the scan carry (segment conflicts never race — later
-    candidates see earlier installs AND earlier evictions: the residency
-    and no-allocate decisions are made against the in-batch state, exactly
-    as a sequential per-fingerprint loop would), which keeps the batched
-    path bit-equivalent to sequential admission.  ``counter`` is the
-    PER-SET replacement counter plane (S,) — every decision in the scan
-    couples only through per-set state, which is what makes per-shard
-    scans equal to one global scan.  ``touches`` carries the host
-    first_touch counts (unique fps, so they cannot change mid-batch).
-    All mutable planes are donated; outputs feed the host shadow map in
-    one transfer.
-    """
-    n_ways = valid.shape[1]
-    iota = jnp.arange(n_ways, dtype=jnp.int32)
-
-    def step(carry, x):
-        bits, valid, fp_of, read_after, set_writes, counter, ws = carry
-        s, fp, bitcol, cycle, touch, act = x
-
-        vrow = valid[s]
-        frow = fp_of[s]
-        hitv = (vrow == 1) & (frow == fp)
-        is_res = jnp.any(hitv) & act
-        res_w = jnp.argmax(hitv).astype(jnp.int32)
-        # resident re-offer: D/R metadata only (marks the way re-read).
-        read_after = read_after.at[s, res_w].add(
-            jnp.where(is_res, 1, 0).astype(jnp.int32))
-
-        # no-allocate gate (D̄&R̄ "never accessed" filter): evaluated against
-        # the CURRENT residency, so a fingerprint evicted by an earlier
-        # same-batch install re-enters the touch count like the sequential
-        # flow would.
-        skipped = act & ~is_res & (touch < admit_after)
-
-        # t_MWW lifetime throttle — shared wear machinery (§6.2/§8).
-        # window_would_exceed rejects BEFORE the write, so under this
-        # policy record_write's lock branch never fires; is_locked is kept
-        # as a guard for wear states also driven by other writers.
-        locked = wear.is_locked(ws, s, cycle)
-        over = wear.window_would_exceed(ws, wdyn, s, cycle)
-        throttled = act & ~is_res & ~skipped & (locked | over)
-        do_install = act & ~is_res & ~skipped & ~throttled
-
-        # Way selection: first free way, else counter-ordered cold victim
-        # (never-re-read ways first — D̄&R̄-style replacement).  The
-        # replacement counter free-runs PER SET.
-        free = vrow == 0
-        has_free = jnp.any(free)
-        free_w = jnp.argmax(free).astype(jnp.int32)
-        order = ((iota + counter[s]) % n_ways).astype(jnp.int32)
-        cold = read_after[s][order] == 0
-        victim = jnp.where(jnp.any(cold), order[jnp.argmax(cold)], order[0])
-        way = jnp.where(has_free, free_w, victim).astype(jnp.int32)
-        evict = do_install & ~has_free
-        old_fp = frow[way]
-        counter = counter.at[s].add(jnp.where(evict, 1, 0).astype(jnp.int32))
-
-        # Column install (one CAM column + metadata; bitcol arrives in
-        # the plane format — packed words scatter as-is).
-        bits = bits.at[s, :, way].set(
-            jnp.where(do_install, bitcol.astype(bits.dtype),
-                      bits[s, :, way]))
-        valid = valid.at[s, way].set(
-            jnp.where(do_install, 1, vrow[way]).astype(jnp.int8))
-        fp_of = fp_of.at[s, way].set(jnp.where(do_install, fp, old_fp))
-        read_after = read_after.at[s, way].set(
-            jnp.where(do_install, 0, read_after[s, way]).astype(jnp.int32))
-        set_writes = set_writes.at[s].add(
-            jnp.where(do_install, 1, 0).astype(jnp.int32))
-
-        # Wear recording fused with the install (one implementation: §8's
-        # record_write — the same function the Fig. 11 simulator scans).
-        ws2, rot, _fl = wear.record_write(ws, wdyn, s, jnp.asarray(True),
-                                          cycle)
-        ws = jax.tree.map(lambda o, n: jnp.where(do_install, n, o), ws, ws2)
-
-        out = (is_res, skipped, throttled, do_install, way, evict, old_fp)
-        return (bits, valid, fp_of, read_after, set_writes, counter, ws), out
-
-    carry = (bits, valid, fp_of, read_after, set_writes, counter, wstate)
-    carry, outs = jax.lax.scan(step, carry,
-                               (sets, fps, bitcols, cycles, touches, active))
-    return carry, outs
+    searches: int = 0             # lookup dispatches (1 per batch)
+    admit_calls: int = 0          # admission dispatches (1 per batch)
 
 
 def _admit_rounds_body(bits, valid, fp_of, read_after, set_writes, counter,
@@ -362,19 +241,24 @@ def _admit_rounds_body(bits, valid, fp_of, read_after, set_writes, counter,
                        touches, active):
     """Segmented-parallel admission over the round grid (ONE partition).
 
-    The candidate operands are ``(n_rounds, round_width)`` grids from
+    The state operands are one partition's blocks: the planes and per-set
+    counters over its sets, and its wear state, whose three scalar
+    counters arrive as ``(1,)`` arrays (one entry of the index's
+    per-partition counters).  The candidate operands are that partition's
+    ``(1, n_rounds, round_width)`` slice of the grid from
     ``xam_ops.group_admits_stacked``: round r holds each set's rank-r
     candidate, so within a round every active lane targets a DISTINCT
     set.  The ``lax.scan`` over rounds replays intra-set collisions in
     exact batch order (rank order IS batch order within a set) while each
-    round's step runs the full per-fingerprint pipeline of
-    ``_admit_batch`` vectorized over the lanes — gathers row-clipped,
-    installs scattered with an out-of-bounds sentinel so inactive /
-    non-installing lanes write nothing, wear recorded via
-    ``wear.record_write_rows`` (distinct rows per round is exactly its
-    contract).  Because every decision couples only through per-set state,
-    the result is bit-identical to the sequential scan — pinned against
-    the ``admit_dispatch="fanout"`` oracle after every op.
+    round's step runs the full per-fingerprint pipeline vectorized over
+    the lanes — gathers row-clipped, installs scattered with an
+    out-of-bounds sentinel so inactive / non-installing lanes write
+    nothing, wear recorded via ``wear.record_write_rows`` (distinct rows
+    per round is exactly its contract).  Because every decision couples
+    only through per-set state, the result is bit-identical to a
+    sequential scan of the candidates in batch order (the reference in
+    ``tests/kv_index_reference.py``).  The decision grids come back with
+    the same leading partition axis.
     """
     n_ways = valid.shape[1]
     s_all = valid.shape[0]
@@ -397,8 +281,8 @@ def _admit_rounds_body(bits, valid, fp_of, read_after, set_writes, counter,
         # no-allocate gate (D̄&R̄ "never accessed" filter).
         skipped = act & ~is_res & (touch < admit_after)
 
-        # t_MWW lifetime throttle — same shared wear machinery as the
-        # sequential scan (reject-before-write, per-set window).
+        # t_MWW lifetime throttle — shared wear machinery
+        # (reject-before-write, per-set window).
         locked = wear.is_locked(ws, sc, cycle)
         over = wear.window_would_exceed(ws, wdyn, sc, cycle)
         throttled = act & ~is_res & ~skipped & (locked | over)
@@ -441,59 +325,42 @@ def _admit_rounds_body(bits, valid, fp_of, read_after, set_writes, counter,
         return (bits, valid, fp_of, read_after, set_writes, counter, ws), out
 
     carry = (bits, valid, fp_of, read_after, set_writes, counter, wstate)
-    carry, outs = jax.lax.scan(round_step, carry,
-                               (sets, fps, bitcols, cycles, touches, active))
-    return carry, outs
+    carry, outs = jax.lax.scan(
+        round_step, carry,
+        (sets[0], fps[0], bitcols[0], cycles[0], touches[0], active[0]))
+    return carry, tuple(o[None] for o in outs)
 
 
-#: Single-partition entry point for the round-grid admission (donated
-#: planes/counters/wear, exactly like ``_admit_batch``).
-_admit_rounds = functools.partial(
-    jax.jit, donate_argnums=(0, 1, 2, 3, 4, 5, 6))(_admit_rounds_body)
+def _wear_layout(rows, whole) -> wear.WearState:
+    """Where each field of the index's wear state lives: the per-set rows
+    and the per-partition scalar counters along the set axis (``rows``),
+    the rotary offsets and rotate totals — which serving never changes,
+    since its config disables every rotate signal — whole (``whole``).
+    Filled with shardings to place the state, with partition specs to
+    hand it to ``shard_map``."""
+    return wear.WearState(
+        swt_w=rows, swt_d=rows, write_counter=rows, superset_counter=rows,
+        dirty_counter=rows, offsets=whole, window_writes=rows,
+        window_start=rows, locked_until=rows, total_rotates=whole,
+        total_flushed=whole)
 
 
 @functools.lru_cache(maxsize=None)
-def _admit_shardmap_fn(mesh):
-    """Jitted ``shard_map`` wrapper admitting EVERY partition's round grid
-    from ONE dispatch — the write-path twin of
-    ``xam_ops._stacked_shardmap_fn``.  Each mesh device receives its
-    ``P("sets")`` slices: plane/counter blocks, the per-set wear rows, its
-    ``(1,)`` block of the stacked wear scalars and its ``(1, n_rounds,
-    round_width)`` candidate slice; the traced wear knobs and the
-    no-allocate threshold arrive replicated.  The §8 wear state is passed
-    DECOMPOSED (per-set rows shard, scalar counters stack) because the
-    rotary offsets and rotate totals are invariants of the admission path
-    (the serving config disables every rotate signal) and stay outside the
-    dispatch entirely.  All state operands are donated."""
-    def per_shard(bits, valid, fp_of, read_after, set_writes, counter,
-                  swt_w, swt_d, window_writes, window_start, locked_until,
-                  wc, ssc, dc, wdyn, admit_after,
-                  sets, fps, bitcols, cycles, touches, active):
-        ws = wear.WearState(
-            swt_w=swt_w, swt_d=swt_d,
-            write_counter=wc[0], superset_counter=ssc[0],
-            dirty_counter=dc[0],
-            offsets=geometry.zero_offsets(),      # invariant; discarded
-            window_writes=window_writes, window_start=window_start,
-            locked_until=locked_until,
-            total_rotates=jnp.zeros((), jnp.int32),
-            total_flushed=jnp.zeros((), jnp.int32))
-        carry, outs = _admit_rounds_body(
-            bits, valid, fp_of, read_after, set_writes, counter, ws, wdyn,
-            admit_after, sets[0], fps[0], bitcols[0], cycles[0], touches[0],
-            active[0])
-        bits, valid, fp_of, read_after, set_writes, counter, ws = carry
-        return ((bits, valid, fp_of, read_after, set_writes, counter,
-                 ws.swt_w, ws.swt_d, ws.window_writes, ws.window_start,
-                 ws.locked_until, ws.write_counter[None],
-                 ws.superset_counter[None], ws.dirty_counter[None])
-                + tuple(o[None] for o in outs))
-
-    spec = (P("sets"),) * 14 + (P(), P()) + (P("sets"),) * 6
-    return jax.jit(
-        jax.shard_map(per_shard, mesh=mesh, in_specs=spec,
-                      out_specs=P("sets"), check_vma=False),
-        donate_argnums=tuple(range(14)))
+def _admit_program(mesh):
+    """The jitted admission dispatch: ``_admit_rounds_body`` as is when
+    ``mesh`` is None, else through ``shard_map`` over the ``("sets",)``
+    mesh, each device receiving its ``P("sets")`` blocks of the state and
+    of the candidate grid while the wear knobs and the no-allocate
+    threshold arrive replicated.  The planes, counters and wear state are
+    donated."""
+    body = _admit_rounds_body
+    if mesh is not None:
+        rows, whole = P("sets"), P()
+        state = (rows,) * 6 + (_wear_layout(rows, whole),)
+        body = jax.shard_map(
+            body, mesh=mesh, in_specs=state + (whole, whole) + (rows,) * 6,
+            out_specs=(state, rows), check_vma=False)
+    return jax.jit(body, donate_argnums=tuple(range(7)))
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3),
@@ -509,28 +376,6 @@ def _rotate_planes(bits, valid, fp_of, read_after, shift: int):
 def _set_bytes(plane) -> int:
     """Bytes one set of an ``(n_sets, ...)`` device plane holds."""
     return int(np.prod(plane.shape[1:])) * np.dtype(plane.dtype).itemsize
-
-
-def _shard_property(name: str, doc: str, settable: bool = True):
-    """Global view over a per-partition plane list: partition 0's array
-    unwrapped when there is only one (zero-copy — donation-safe for
-    external callers like the bench host loop), a host-side concatenation
-    in partition order otherwise."""
-    def get(self):
-        parts = getattr(self, name)
-        if len(parts) == 1:
-            return parts[0]
-        return np.concatenate([np.asarray(p) for p in parts], axis=0)
-
-    def set_(self, value):
-        if self.n_parts == 1:
-            getattr(self, name)[0] = value
-        else:
-            setattr(self, name, [
-                self._put(np.asarray(value)[self._slice(k)], k)
-                for k in range(self.n_parts)])
-
-    return property(get, set_ if settable else None, None, doc)
 
 
 class KVSlabStore:
@@ -721,24 +566,6 @@ class MonarchKVIndex:
     seed : int
         Reserved for future stochastic policies (placement is currently
         deterministic).
-    dispatch : {"auto", "fanout"}
-        ``"auto"`` (default): single-dispatch paths — state lives in
-        ``n_parts = mesh-partition`` blocks (1 when every shard
-        co-locates), lookup is one ``shard_map``/``pallas_call`` launch,
-        rotation is the on-device ``ppermute`` boundary exchange.
-        ``"fanout"``: the PR-4 reference — one storage block PER LOGICAL
-        SHARD, one ``pallas_call`` per shard from the host, rotation
-        gathered through the host.  Kept as the differential oracle
-        (``tests/test_kv_index_differential.py`` pins both paths
-        bit-identical after every op); results never depend on it.
-    admit_dispatch : {"auto", "fanout"} or None
-        Admission dispatch policy; ``None`` (default) follows
-        ``dispatch``.  ``"auto"``: the stacked round-grid path — ONE
-        donated device dispatch admits the whole batch at every shard
-        count.  ``"fanout"``: the PR-5 per-partition ``_admit_batch``
-        scan loop, kept as the admission differential oracle (requires
-        no mesh layout, so it is also forced whenever
-        ``dispatch="fanout"``).  Results never depend on the choice.
     now_fn : callable, optional
         Wall-clock source for ``clock="wall"`` configs: a zero-arg
         callable returning MONOTONIC seconds as a float (default
@@ -746,23 +573,30 @@ class MonarchKVIndex:
         t_MWW window deterministically.  Never consulted under
         ``clock="ops"`` (pinned — the op-clock path is bit-identical to
         the pre-wall-clock implementation).
+    slab_store : KVSlabStore, optional
+        KV slab store kept in lockstep with admission (commit on install,
+        discard on skip/throttle, drop on evict); None = tag-only index
+        (dedup, counting).
 
     Attributes
     ----------
-    bits, valid, fp_of, read_after : global views (property)
+    bits, valid, fp_of, read_after : jax.Array
         The CAM planes — ``(n_sets, key_bits, set_ways)`` int8 stored
         bits (``(n_sets, key_bits // 8, set_ways)`` uint8 packed words
         under ``plane_format="packed8"`` — unpack with
         ``kernels.common.unpack_bits_np(..., axis=1)``),
-        ``(n_sets, set_ways)`` validity/fingerprint/D̄&R̄ planes.
-        With one partition these are THE device arrays; with several they
-        are host-side concatenations of the partition-resident planes
-        (read-only use intended; assignment re-splits across partitions).
+        ``(n_sets, set_ways)`` validity/fingerprint/D̄&R̄ planes.  Sharded
+        ``P("sets")`` over ``set_mesh`` when there is one.  Admission and
+        rotation donate them and rebind the attributes, so read them
+        afresh after either.
+    set_writes, counter : jax.Array
+        ``(n_sets,)`` per-set install (wear) and replacement counters,
+        laid out like the planes.
+    set_mesh : Mesh or None
+        The ``("sets",)`` mesh the state is sharded over; None when the
+        host has one device (every shard co-locates).
     n_parts : int
-        Device partitions actually holding state: the ``("sets",)`` mesh
-        size under ``dispatch="auto"`` (1 on a single-device host —
-        co-located shards collapse to the unsharded path), ``n_shards``
-        under ``dispatch="fanout"``.
+        Device partitions holding state: the ``set_mesh`` size, or 1.
     stats : KVIndexStats
         Host-side operation counters.
     ops_total : int
@@ -781,18 +615,9 @@ class MonarchKVIndex:
     """
 
     def __init__(self, cfg: KVIndexConfig | None = None, seed: int = 0,
-                 dispatch: str = "auto", admit_dispatch: str | None = None,
                  now_fn=None, slab_store: KVSlabStore | None = None):
         # cfg default constructed per instance: a shared KVIndexConfig()
         # default would alias mutable config across indexes.
-        assert dispatch in ("auto", "fanout"), dispatch
-        if admit_dispatch is None:
-            admit_dispatch = dispatch
-        assert admit_dispatch in ("auto", "fanout"), admit_dispatch
-        # "fanout" storage keeps one block per LOGICAL shard (no mesh
-        # layout to stack over) — its admission is the per-partition loop.
-        assert not (dispatch == "fanout" and admit_dispatch == "auto"), (
-            "dispatch='fanout' storage only supports fanout admission")
         self.cfg = KVIndexConfig() if cfg is None else cfg
         c = self.cfg
         if c.clock not in wear.CLOCKS:
@@ -803,49 +628,31 @@ class MonarchKVIndex:
             raise ValueError(
                 f"KVIndexConfig.fingerprint={c.fingerprint!r}: expected "
                 "'block' or 'prefix'")
-        # Optional KV slab store, kept in lockstep by admit_fps's host
-        # fold (commit on install, discard on skip/throttle, drop on
-        # evict); None = tag-only index (dedup, counting).
         self.slab_store = slab_store
         # t_MWW clock domain.  "ops": the op counter is the cycle proxy
         # (pre-existing semantics, now_fn never consulted).  "wall": cycle
         # stamps are host wall microseconds relative to construction,
         # taken ONCE per admission batch so the device scans see only
-        # host-provided constants and stay deterministic (the fanout /
-        # stacked differential oracle pins bit-identity between dispatch
-        # paths for free — both stamp from the same host read).
+        # host-provided constants and stay deterministic.
         self.clock = c.clock
         self._now_fn = time.monotonic if now_fn is None else now_fn
         self._wall_t0 = self._now_fn() if self.clock == "wall" else 0.0
         self._wall_folded = 0       # cycles removed by clock rebases
-        self.dispatch = dispatch
-        self.admit_dispatch = admit_dispatch
-        self.n_shards = c.n_shards
         self.sets_per_shard = geometry.sets_per_shard(c.n_sets, c.n_shards)
-        # ("sets",) mesh placement: partition k's planes/wear live on mesh
-        # device k; None on a single-device host — every shard co-locates.
-        # Under "auto" state is stored in one block per MESH PARTITION
-        # (sharding is a pure relabeling, so coarsening co-located shards
-        # into one block changes no result — pinned by the invariance
-        # tests), which is what lets lookup run as ONE shard_map dispatch
-        # and collapses to the exact unsharded path on one device.  Under
-        # "fanout" state keeps one block per logical shard (the PR-4
-        # reference paths).
+        # State is stored in one block per MESH PARTITION (sharding is a
+        # pure relabeling, so coarsening co-located shards into one block
+        # changes no result — pinned by the invariance tests); on a
+        # one-device host that is the plain unsharded layout.
         self.set_mesh = mesh_mod.make_set_mesh(c.n_shards)
-        if dispatch == "fanout":
-            self.n_parts = c.n_shards
-            self._devices = mesh_mod.set_shard_devices(
-                self.set_mesh, c.n_shards)
-        elif self.set_mesh is None:
+        if self.set_mesh is None:
             self.n_parts = 1
-            self._devices = None
+            rows = whole = jax.devices()[0]
         else:
             self.n_parts = int(self.set_mesh.devices.size)
-            self._devices = list(self.set_mesh.devices.flat)
-        self._use_shard_map = (dispatch == "auto"
-                               and self.set_mesh is not None)
+            rows = mesh_mod.set_axis_sharding(self.set_mesh)
+            whole = mesh_mod.replicated_sharding(self.set_mesh)
+        self._rows = rows           # placement of per-set state and grids
         self.sets_per_part = c.n_sets // self.n_parts
-        s_loc = self.sets_per_part
         # Stored-bit plane layout: "int8" keeps one bit per byte;
         # "packed8" stores 8 bits per uint8 word along the key-bit axis
         # (the kernel unpacks per tile in VMEM — installs scatter packed
@@ -860,30 +667,19 @@ class MonarchKVIndex:
         self.plane_rows = (c.key_bits if self.plane_format == "int8"
                            else c.key_bits // 8)
         plane_dtype = (np.int8 if self.plane_format == "int8" else np.uint8)
-        # Device-resident CAM state, per partition: fingerprint bits
-        # column-wise per set, plus the validity / fingerprint / D-R
-        # metadata planes, the PER-SET replacement counters and the
-        # per-set install (wear) counters.
-        self._bits = [
-            self._put(
-                np.zeros((s_loc, self.plane_rows, c.set_ways), plane_dtype),
-                k)
-            for k in range(self.n_parts)]
-        self._valid = [
-            self._put(np.zeros((s_loc, c.set_ways), np.int8), k)
-            for k in range(self.n_parts)]
-        self._fp_of = [
-            self._put(np.zeros((s_loc, c.set_ways), np.uint32), k)
-            for k in range(self.n_parts)]
-        self._read_after = [
-            self._put(np.zeros((s_loc, c.set_ways), np.int32), k)
-            for k in range(self.n_parts)]
-        self._set_writes = [
-            self._put(np.zeros((s_loc,), np.int32), k)
-            for k in range(self.n_parts)]
-        self._counters = [
-            self._put(np.zeros((s_loc,), np.int32), k)
-            for k in range(self.n_parts)]
+        # Device-resident CAM state: fingerprint bits column-wise per set,
+        # plus the validity / fingerprint / D-R metadata planes, the
+        # PER-SET replacement counters and the per-set install (wear)
+        # counters.
+        ways = (c.n_sets, c.set_ways)
+        place = functools.partial(jax.device_put, device=rows)
+        self.bits = place(np.zeros((c.n_sets, self.plane_rows, c.set_ways),
+                                   plane_dtype))
+        self.valid = place(np.zeros(ways, np.int8))
+        self.fp_of = place(np.zeros(ways, np.uint32))
+        self.read_after = place(np.zeros(ways, np.int32))
+        self.set_writes = place(np.zeros(c.n_sets, np.int32))
+        self.counter = place(np.zeros(c.n_sets, np.int32))
         # §8 wear state over the physical sets — the simulator's own
         # machinery with serving knobs: window length = window_ops (op-count
         # cycle proxy), budget = set_ways * m_writes, WR/WC/DC rotation
@@ -892,30 +688,25 @@ class MonarchKVIndex:
         # reach 32, so ``rotate_signal`` provably never fires (the default
         # shift of 9 left WR armed despite the stated intent).  That
         # invariance is also what makes the vectorized wear recording of
-        # the stacked admission exact (``wear.record_write_rows``).
-        # One state per partition, over that partition's sets.
+        # the round-grid admission exact (``wear.record_write_rows``).
+        # Its three scalar counters are kept per partition, one entry
+        # each (``wear_state`` sums them).
         self.wear_cfg = wear.WearConfig(
             n_supersets=c.n_sets, m_writes=c.m_writes,
             dc_limit=1 << 30, wc_limit=1 << 30, wr_shift=32,
             t_mww_cycles=c.window_ops, blocks_per_superset=c.set_ways,
             clock=c.clock)
-        self.wear_dyn = wear.dyn_of(self.wear_cfg)
-        self._wear_states = [
-            self._put_tree(st, k)
-            for k, st in enumerate(wear.shard_states(self.wear_cfg,
-                                                     self.n_parts))]
-        self._wear_dyns = [self._put_tree(self.wear_dyn, k)
-                           for k in range(self.n_parts)]
-        self._admit_after = [
-            self._put(np.asarray(c.admit_after_reads, np.int32), k)
-            for k in range(self.n_parts)]
-        if self._use_shard_map and self.n_parts > 1:
-            # Replicated once at construction so the per-batch stacked
-            # admission dispatch performs no implicit host transfers.
-            repl = mesh_mod.replicated_sharding(self.set_mesh)
-            self._wdyn_repl = jax.device_put(self.wear_dyn, repl)
-            self._admit_after_repl = jax.device_put(
-                np.asarray(c.admit_after_reads, np.int32), repl)
+        per_part = np.zeros(self.n_parts, np.int32)
+        self._wear = jax.device_put(
+            dataclasses.replace(
+                wear.init_state(self.wear_cfg), write_counter=per_part,
+                superset_counter=per_part, dirty_counter=per_part),
+            _wear_layout(rows, whole))
+        # Placed once at construction, so the per-batch admission
+        # dispatch performs no implicit host transfers.
+        self.wear_dyn = jax.device_put(wear.dyn_of(self.wear_cfg), whole)
+        self._admit_after = jax.device_put(
+            np.asarray(c.admit_after_reads, np.int32), whole)
         # Host-side policy shadow (map + mirrors): keeps assertions and
         # eviction bookkeeping off the device sync path.
         self.valid_np = np.zeros((c.n_sets, c.set_ways), bool)
@@ -926,74 +717,16 @@ class MonarchKVIndex:
         self.ops_total = 0          # op counter == t_MWW cycle proxy
         self.stats = KVIndexStats()
 
-    # -- sharding plumbing ---------------------------------------------
-    def _put(self, x, k: int):
-        """Place ``x`` on shard k's mesh device (no-op placement when the
-        host has one device, preserving the unsharded dispatch path)."""
-        if self._devices is None:
-            return jnp.asarray(x)
-        return jax.device_put(x, self._devices[k])
-
-    def _put_tree(self, tree, k: int):
-        if self._devices is None:
-            return tree
-        return jax.device_put(tree, self._devices[k])
-
-    def _put_admit(self, x):
-        """EXPLICIT single-device placement for stacked-admission grids.
-
-        Unlike :meth:`_put`, which falls back to an implicit
-        ``jnp.asarray`` transfer on one-device hosts, this always issues
-        an explicit ``jax.device_put`` — so the stacked admission path
-        stays legal under ``jax.transfer_guard("disallow")``, which
-        blocks only IMPLICIT transfers (the no-host-transfer pin)."""
-        dev = self._devices[0] if self._devices is not None else jax.devices()[0]
-        return jax.device_put(x, dev)
-
-    def _slice(self, k: int) -> slice:
-        """Global-set slice owned by storage partition k."""
-        return geometry.shard_set_slice(k, self.cfg.n_sets, self.n_parts)
-
-    def _assemble(self, parts: list) -> jnp.ndarray:
-        """Zero-copy GLOBAL jax.Array over the per-partition planes:
-        each partition's block is already resident on its mesh device, so
-        the contiguous ``P("sets")`` sharded view costs no data movement.
-        The assembled array SHARES buffers with ``parts`` — donating it
-        (rotation) invalidates them, so callers rebind from the output."""
-        if self.n_parts == 1:
-            return parts[0]
-        shape = (self.cfg.n_sets,) + tuple(parts[0].shape[1:])
-        return jax.make_array_from_single_device_arrays(
-            shape, mesh_mod.set_axis_sharding(self.set_mesh), list(parts))
-
-    def _split_global(self, arr: jnp.ndarray) -> list:
-        """Inverse of :meth:`_assemble`: the per-device blocks of a
-        ``P("sets")``-sharded global array, in global set order (zero
-        copy — each block is a view of the resident shard buffer)."""
-        if self.n_parts == 1:
-            return [arr]
-        shards = sorted(arr.addressable_shards,
-                        key=lambda s: s.index[0].start or 0)
-        return [s.data for s in shards]
-
-    bits = _shard_property("_bits", "stored-bit planes, global view")
-    valid = _shard_property("_valid", "validity planes, global view")
-    fp_of = _shard_property("_fp_of", "fingerprint planes, global view")
-    read_after = _shard_property(
-        "_read_after", "D̄&R̄ re-read counters, global view")
-    set_writes = _shard_property(
-        "_set_writes", "per-set install counters, global view",
-        settable=False)
-    counter = _shard_property(
-        "_counters", "per-set replacement counters, global view",
-        settable=False)
-
     @property
     def wear_state(self) -> wear.WearState:
-        """Global §8 wear view: THE shard state when unsharded, else the
-        per-set fields concatenated in shard order (see
-        ``wear.concat_states``) — reporting only, never write through."""
-        return wear.concat_states(self._wear_states)
+        """Global §8 wear view: the per-set rows over every set and the
+        scalar counters summed over partitions — reporting only, never
+        write through."""
+        w = self._wear
+        return dataclasses.replace(
+            w, write_counter=jnp.sum(w.write_counter),
+            superset_counter=jnp.sum(w.superset_counter),
+            dirty_counter=jnp.sum(w.dirty_counter))
 
     # ------------------------------------------------------------------
     def _set_of(self, fps: np.ndarray) -> np.ndarray:
@@ -1034,9 +767,7 @@ class MonarchKVIndex:
         bound exact indefinitely."""
         if self._clock_cycles() < wear.CLOCK_REBASE_AT:
             return
-        for k in range(self.n_parts):
-            self._wear_states[k] = wear.rebase_clock(
-                self._wear_states[k], wear.CLOCK_REBASE_AT)
+        self._wear = wear.rebase_clock(self._wear, wear.CLOCK_REBASE_AT)
         if self.clock == "ops":
             self.ops_total -= wear.CLOCK_REBASE_AT
         else:
@@ -1065,10 +796,9 @@ class MonarchKVIndex:
         -------
         np.ndarray, shape (B, S // 16), bool
             True where the chunk's KV is already cached.  ONE device
-            dispatch for the whole batch: the fused multiset kernel
-            (one partition) or its ``shard_map`` wrapping over the
-            ``("sets",)`` mesh (the stacked layout).  The ``"fanout"``
-            reference dispatches one call per shard holding queries.
+            dispatch for the whole batch: the fused multiset kernel on
+            one device, its ``shard_map`` wrapping over the ``("sets",)``
+            mesh (the stacked layout) otherwise.
         """
         self._maybe_rebase_clock()
         fps = self.fingerprints(tokens)
@@ -1079,25 +809,18 @@ class MonarchKVIndex:
         sets = self._set_of(flat)
         key_bits = xam_ops.words_to_bits_np(
             flat.astype(np.uint32), self.cfg.key_bits)
-        if self._use_shard_map and self.n_parts > 1:
-            ways = xam_ops.xam_search_multiset_stacked(
-                key_bits, sets, self._assemble(self._bits),
-                self._assemble(self._valid), mesh=self.set_mesh)
-            self.stats.searches += 1
-        elif self.n_parts == 1:
-            planes, valid = self._bits[0], self._valid[0]
-            with span("lookup.search", queries=key_bits.shape[0],
-                      key_bits=key_bits.shape[1], ways=planes.shape[-1],
-                      sets=np.unique(sets).size,
-                      set_bytes=_set_bytes(planes) + _set_bytes(valid)):
+        with span("lookup.search", queries=key_bits.shape[0],
+                  key_bits=key_bits.shape[1], ways=self.bits.shape[-1],
+                  sets=np.unique(sets).size,
+                  set_bytes=_set_bytes(self.bits) + _set_bytes(self.valid)):
+            if self.set_mesh is None:
                 ways = xam_ops.xam_search_multiset(
-                    key_bits, sets, planes, valid)
-            self.stats.searches += 1
-        else:
-            ways = xam_ops.xam_search_multiset_sharded(
-                key_bits, sets, self._bits, self._valid)
-            self.stats.searches += len(
-                np.unique(sets // self.sets_per_part))
+                    key_bits, sets, self.bits, self.valid)
+            else:
+                ways = xam_ops.xam_search_multiset_stacked(
+                    key_bits, sets, self.bits, self.valid,
+                    mesh=self.set_mesh)
+        self.stats.searches += 1
         hit = ways >= 0
         self.stats.chunk_hits += int(hit.sum())
         self.stats.chunk_misses += int((~hit).sum())
@@ -1113,14 +836,10 @@ class MonarchKVIndex:
         """Offer a batch's chunks for admission (after KV was computed).
 
         Fingerprints are uniqued (order-preserved) and forwarded to
-        :meth:`admit_fps` — O(1) jitted device calls per shard regardless
-        of batch size."""
+        :meth:`admit_fps` — one device dispatch regardless of batch
+        size."""
         fps = np.unique(self.fingerprints(tokens).reshape(-1))
         self.admit_fps(fps)
-
-    def _admit_one(self, fp: np.uint32):
-        """Single-fingerprint compatibility shim over the batched path."""
-        self.admit_fps(np.asarray([fp], np.uint32))
 
     def admit_fps(self, fps: np.ndarray):
         """Batched admission of (unique, order-preserved) fingerprints.
@@ -1134,17 +853,12 @@ class MonarchKVIndex:
 
         Notes
         -----
-        With ``admit_dispatch="auto"`` (the default) the whole batch is
-        admitted by ONE donated device dispatch at every shard count: the
-        host packs candidates into the round grid of
-        ``xam_ops.group_admits_stacked`` (cycle stamps keep their global
-        batch position) and ``_admit_rounds_body`` admits round after
-        round, each round vectorized over its (pairwise-distinct-set)
-        lanes.  ``admit_dispatch="fanout"`` keeps the per-partition
-        ``_admit_batch`` scan loop as the oracle.  Because every decision
-        couples only through per-set state, both are bit-equivalent to
-        admitting the same fingerprints one at a time in batch order, at
-        any shard count (and any partitioning of shards onto devices).
+        The whole batch is admitted by ONE donated device dispatch at
+        every shard count (:meth:`_admit_device`).  Because every
+        decision couples only through per-set state, the result is
+        bit-equivalent to admitting the same fingerprints one at a time
+        in batch order, at any shard count (and any partitioning of
+        shards onto devices).
         """
         fps = np.asarray(fps, np.uint32)
         b = int(fps.size)
@@ -1155,32 +869,23 @@ class MonarchKVIndex:
         touches = np.asarray(
             [self.first_touch.get(int(fp), 0) for fp in fps], np.int32)
         bitcols = self._bitcols(fps)
-        # t_MWW cycle stamps, computed ONCE here so both dispatch paths
-        # stamp identically (the differential oracle pins this).  Op
-        # clock: each candidate's global batch position.  Wall clock: one
-        # host timestamp for the whole batch — the device scan sees only
-        # host constants either way, so it stays deterministic.
+        # t_MWW cycle stamps, computed ONCE here on the host.  Op clock:
+        # each candidate's global batch position.  Wall clock: one host
+        # timestamp for the whole batch — the device scan sees only host
+        # constants either way, so it stays deterministic.
         if self.clock == "ops":
             cycles = (self.ops_total + np.arange(b)).astype(np.int32)
         else:
             cycles = np.full(b, self._clock_cycles(), np.int32)
-        if self.admit_dispatch == "auto":
-            skip, thr, inst, way, evict, old_fp = self._admit_stacked(
-                fps, sets, touches, bitcols, cycles)
-        else:
-            skip, thr, inst, way, evict, old_fp = self._admit_fanout(
-                fps, sets, touches, bitcols, cycles)
+        skip, thr, inst, way, evict, old_fp = self._admit_device(
+            fps, sets, touches, bitcols, cycles)
         self.ops_total += b
 
-        # Host shadow-map fold, in GLOBAL batch order.  (Every shadow-map
-        # operation on a given fingerprint — install, touch bump, evict of
-        # its slot — happens inside its one owning partition, so batch
-        # order and the fanout path's partition-major order produce the
-        # same shadow state.)  The slab store folds in lockstep: a
-        # victim's slab dies with its way, a staged slab becomes resident
-        # exactly when its fingerprint installs (or refreshes a resident
-        # way), and is discarded on skip/throttle so rejected KV never
-        # serves a hit.
+        # Host shadow-map fold, in batch order.  The slab store folds in
+        # lockstep: a victim's slab dies with its way, a staged slab
+        # becomes resident exactly when its fingerprint installs (or
+        # refreshes a resident way), and is discarded on skip/throttle so
+        # rejected KV never serves a hit.
         store = self.slab_store
         for i in range(b):
             if evict[i]:
@@ -1218,18 +923,17 @@ class MonarchKVIndex:
                 > prev // self.cfg.rotate_every):
             self._rotate()
 
-    def _admit_stacked(self, fps, sets, touches, bitcols, cycles):
-        """ONE-dispatch admission over the stacked round grid.
+    def _admit_device(self, fps, sets, touches, bitcols, cycles):
+        """ONE-dispatch admission over the round grid.
 
         Packs the batch into the ``(n_parts, n_rounds, round_width)``
         grid of ``xam_ops.group_admits_stacked`` (pow2-bucketed on both
-        candidate axes so repeated batch sizes reuse compilations), then
-        launches a single donated device call: the jitted
-        ``_admit_rounds`` scan when one partition holds everything, else
-        the ``_admit_shardmap_fn`` shard_map over the set mesh.  Returns
-        the per-candidate decision arrays in GLOBAL batch order."""
+        candidate axes so repeated batch sizes reuse compilations), places
+        it with explicit ``device_put`` calls (so the dispatch stays legal
+        under ``jax.transfer_guard("disallow")``), and launches the
+        single donated ``_admit_program`` call.  Returns the
+        per-candidate decision arrays in batch order."""
         c = self.cfg
-        b = int(fps.size)
         part_of, row, col, n_rounds, round_width = (
             xam_ops.group_admits_stacked(
                 sets, c.n_sets, self.n_parts, lo=ADMIT_BUCKET_LO))
@@ -1250,157 +954,18 @@ class MonarchKVIndex:
 
         xam_ops.ADMIT_LAUNCH_COUNT += 1
         self.stats.admit_calls += 1
-        if self._use_shard_map and self.n_parts > 1:
-            outs = self._dispatch_stacked_shardmap(
-                sets_g, fps_g, bit_g, cyc_g, tch_g, act_g)
-        else:
-            put = self._put_admit
-            carry, outs = _admit_rounds(
-                self._bits[0], self._valid[0], self._fp_of[0],
-                self._read_after[0], self._set_writes[0], self._counters[0],
-                self._wear_states[0], self._wear_dyns[0],
-                self._admit_after[0],
-                put(sets_g[0]), put(fps_g[0]), put(bit_g[0]), put(cyc_g[0]),
-                put(tch_g[0]), put(act_g[0]))
-            (self._bits[0], self._valid[0], self._fp_of[0],
-             self._read_after[0], self._set_writes[0], self._counters[0],
-             self._wear_states[0]) = carry
+        put = functools.partial(jax.device_put, device=self._rows)
+        state, outs = _admit_program(self.set_mesh)(
+            self.bits, self.valid, self.fp_of, self.read_after,
+            self.set_writes, self.counter, self._wear, self.wear_dyn,
+            self._admit_after, put(sets_g), put(fps_g), put(bit_g),
+            put(cyc_g), put(tch_g), put(act_g))
+        (self.bits, self.valid, self.fp_of, self.read_after,
+         self.set_writes, self.counter, self._wear) = state
 
         # One sync for the whole batch; un-grid back to batch order.
-        outs_np = [np.asarray(o) for o in jax.device_get(outs)]
-        sel = idx if outs_np[0].ndim == 3 else (row, col)
         _res, skip, thr, inst, way, evict, old_fp = (
-            o[sel] for o in outs_np)
-        return skip, thr, inst, way, evict, old_fp
-
-    def _dispatch_stacked_shardmap(self, sets_g, fps_g, bit_g, cyc_g,
-                                   tch_g, act_g):
-        """Run the stacked admission grid as ONE ``shard_map`` dispatch.
-
-        Assembles the per-partition planes/counters into zero-copy
-        ``P("sets")`` global views, decomposes the §8 wear states (per-set
-        rows assemble like planes; scalar counters stack to an
-        ``(n_parts,)`` array from fresh per-device ``(1,)`` reshapes, so
-        donation never invalidates live state), places the candidate
-        grids sharded on their leading partition axis, and calls the
-        cached ``_admit_shardmap_fn``.  Every transfer here is an
-        EXPLICIT ``device_put`` (the wear knobs and no-allocate threshold
-        were replicated once at construction), keeping the per-batch path
-        legal under ``jax.transfer_guard("disallow")``.  Rebinds all
-        donated state from the outputs and returns the stacked decision
-        grids."""
-        mesh = self.set_mesh
-        shd = mesh_mod.set_axis_sharding(mesh)
-        ws = self._wear_states
-
-        def stack_scalar(field):
-            # jnp.reshape emits a FRESH (1,) buffer on each scalar's
-            # resident device — the assembled stack can be donated
-            # without invalidating the live wear states.
-            return jax.make_array_from_single_device_arrays(
-                (self.n_parts,), shd,
-                [jnp.reshape(getattr(w, field), (1,)) for w in ws])
-
-        fn = _admit_shardmap_fn(mesh)
-        out = fn(
-            self._assemble(self._bits), self._assemble(self._valid),
-            self._assemble(self._fp_of), self._assemble(self._read_after),
-            self._assemble(self._set_writes), self._assemble(self._counters),
-            self._assemble([w.swt_w for w in ws]),
-            self._assemble([w.swt_d for w in ws]),
-            self._assemble([w.window_writes for w in ws]),
-            self._assemble([w.window_start for w in ws]),
-            self._assemble([w.locked_until for w in ws]),
-            stack_scalar("write_counter"), stack_scalar("superset_counter"),
-            stack_scalar("dirty_counter"),
-            self._wdyn_repl, self._admit_after_repl,
-            jax.device_put(sets_g, shd), jax.device_put(fps_g, shd),
-            jax.device_put(bit_g, shd), jax.device_put(cyc_g, shd),
-            jax.device_put(tch_g, shd), jax.device_put(act_g, shd))
-
-        parts = [self._split_global(o) for o in out[:14]]
-        (self._bits, self._valid, self._fp_of, self._read_after,
-         self._set_writes, self._counters) = parts[:6]
-        sww_p, swd_p, wwr_p, wst_p, lck_p, wc_p, ssc_p, dc_p = parts[6:]
-        # Rotary offsets / rotate totals never entered the dispatch (the
-        # serving config disables every rotate signal), so the old
-        # buffers are still live — reattach them.
-        self._wear_states = [
-            wear.WearState(
-                swt_w=sww_p[k], swt_d=swd_p[k],
-                write_counter=jnp.reshape(wc_p[k], ()),
-                superset_counter=jnp.reshape(ssc_p[k], ()),
-                dirty_counter=jnp.reshape(dc_p[k], ()),
-                offsets=old.offsets,
-                window_writes=wwr_p[k], window_start=wst_p[k],
-                locked_until=lck_p[k],
-                total_rotates=old.total_rotates,
-                total_flushed=old.total_flushed)
-            for k, old in enumerate(self._wear_states)]
-        return out[14:]
-
-    def _admit_fanout(self, fps, sets, touches, bitcols, cycles):
-        """PR-5 per-partition admission oracle (``admit_dispatch="fanout"``).
-
-        Groups candidates by owning storage partition (original order
-        preserved within each group; cycle stamps keep their global batch
-        position) and runs ONE donated ``_admit_batch`` scan per
-        partition holding candidates — dispatched back-to-back, synced
-        together.  Returns the decision arrays scattered back to GLOBAL
-        batch order, so the shared shadow-map fold in ``admit_fps`` is
-        identical for both dispatch modes."""
-        b = int(fps.size)
-        shard_ids = sets // self.sets_per_part
-        skip = np.zeros(b, bool)
-        thr = np.zeros(b, bool)
-        inst = np.zeros(b, bool)
-        evict = np.zeros(b, bool)
-        way = np.zeros(b, np.int32)
-        old_fp = np.zeros(b, np.uint32)
-        launches = []
-        for k in np.unique(shard_ids):
-            k = int(k)
-            sel = np.nonzero(shard_ids == k)[0]
-            bk = sel.size
-            bb = bucket_pow2(bk, lo=ADMIT_BUCKET_LO)
-            fps_p = np.zeros(bb, np.uint32)
-            fps_p[:bk] = fps[sel]
-            sets_p = np.zeros(bb, np.int32)
-            sets_p[:bk] = sets[sel] - k * self.sets_per_part  # local rows
-            bit_p = np.zeros((bb, self.plane_rows), bitcols.dtype)
-            bit_p[:bk] = bitcols[sel]
-            cycles_p = np.full(bb, cycles[0], np.int32)  # pad: inactive
-            cycles_p[:bk] = cycles[sel]              # host-stamped, per batch
-            touch_p = np.zeros(bb, np.int32)
-            touch_p[:bk] = touches[sel]
-            active = np.zeros(bb, bool)
-            active[:bk] = True
-
-            carry, outs = _admit_batch(
-                self._bits[k], self._valid[k], self._fp_of[k],
-                self._read_after[k], self._set_writes[k], self._counters[k],
-                self._wear_states[k], self._wear_dyns[k],
-                self._admit_after[k],
-                self._put(sets_p, k), self._put(fps_p, k),
-                self._put(bit_p, k), self._put(cycles_p, k),
-                self._put(touch_p, k), self._put(active, k))
-            (self._bits[k], self._valid[k], self._fp_of[k],
-             self._read_after[k], self._set_writes[k], self._counters[k],
-             self._wear_states[k]) = carry
-            xam_ops.ADMIT_LAUNCH_COUNT += 1
-            self.stats.admit_calls += 1
-            launches.append((sel, outs))
-
-        for sel, outs in launches:
-            bk = sel.size
-            _res, sk, th, in_, wy, ev, of = (
-                np.asarray(o)[:bk] for o in outs)
-            skip[sel] = sk
-            thr[sel] = th
-            inst[sel] = in_
-            way[sel] = wy
-            evict[sel] = ev
-            old_fp[sel] = of
+            np.asarray(o)[idx] for o in jax.device_get(outs))
         return skip, thr, inst, way, evict, old_fp
 
     def _rotate(self):
@@ -1408,55 +973,27 @@ class MonarchKVIndex:
         GLOBAL permutation ``set -> set + 7 (mod n_sets)`` while the
         ``_set_of`` offset moves in lockstep, so resident entries stay
         searchable under the rotated placement and the physical mapping is
-        identical at every shard count.  One partition: ONE donated device
-        roll.  Across partitions: DEVICE-RESIDENT — each shard donates a
-        local roll of its block-aligned slab and ``ppermute``s the
-        boundary sets that cross shard edges under the global permutation
-        (``mesh.make_sharded_roll``); no plane data touches the host.
-        The ``"fanout"`` reference keeps the PR-4 host gather.
-        Wear/replacement counters track PHYSICAL sets and are untouched.
-        When admissions flow through an ``AdmitQueue``, the queue drains
-        before calling this (drain barrier)."""
+        identical at every shard count.  One device: ONE donated device
+        roll.  On a set mesh: each partition donates a local roll of its
+        block and ``ppermute``s the boundary sets that cross partition
+        edges (``mesh.make_sharded_roll``); no plane data touches the
+        host.  Wear/replacement counters track PHYSICAL sets and are
+        untouched.  When admissions flow through an ``AdmitQueue``, the
+        queue drains before calling this (drain barrier)."""
         n = self.cfg.n_sets
         shift = ROTATE_STRIDE % n
         self.offset = (self.offset + ROTATE_STRIDE) % n
         self.stats.rotations += 1
         if shift:
-            if self.n_parts == 1:
-                (self._bits[0], self._valid[0], self._fp_of[0],
-                 self._read_after[0]) = _rotate_planes(
-                    self._bits[0], self._valid[0], self._fp_of[0],
-                    self._read_after[0], shift=shift)
-            elif self._use_shard_map:
-                self._rotate_device(shift)
-            else:
-                # "fanout" reference: cross-shard gather/scatter via the
-                # global-view properties (getter concatenates, setter
-                # re-splits and re-places per shard).
-                self.bits = np.roll(self.bits, shift, axis=0)
-                self.valid = np.roll(self.valid, shift, axis=0)
-                self.fp_of = np.roll(self.fp_of, shift, axis=0)
-                self.read_after = np.roll(self.read_after, shift, axis=0)
+            roll = (functools.partial(_rotate_planes, shift=shift)
+                    if self.set_mesh is None
+                    else mesh_mod.make_sharded_roll(self.set_mesh, n, shift))
+            self.bits, self.valid, self.fp_of, self.read_after = roll(
+                self.bits, self.valid, self.fp_of, self.read_after)
             self.valid_np = np.roll(self.valid_np, shift, axis=0)
             self.fp_of_np = np.roll(self.fp_of_np, shift, axis=0)
             self.slot_of = {fp: ((s + shift) % n, w)
                             for fp, (s, w) in self.slot_of.items()}
-
-    def _rotate_device(self, shift: int):
-        """On-device cross-shard remap: donated per-shard rolls + the
-        ``ppermute`` boundary exchange, applied to all four planes in one
-        jitted collective.  The assembled global views share buffers with
-        the per-partition lists, so after the donation the lists are
-        rebound from the outputs (zero-copy device views)."""
-        roll = mesh_mod.make_sharded_roll(
-            self.set_mesh, self.cfg.n_sets, shift)
-        bits, valid, fp_of, read_after = roll(
-            self._assemble(self._bits), self._assemble(self._valid),
-            self._assemble(self._fp_of), self._assemble(self._read_after))
-        self._bits = self._split_global(bits)
-        self._valid = self._split_global(valid)
-        self._fp_of = self._split_global(fp_of)
-        self._read_after = self._split_global(read_after)
 
     # ------------------------------------------------------------------
     @property
@@ -1485,38 +1022,34 @@ class MonarchKVIndex:
     def write_distribution(self) -> np.ndarray:
         """Installs per PHYSICAL set — the wear-evenness metric (device
         counter; unlike residency it never decays on eviction).  Shape
-        (n_sets,), concatenated in shard order."""
+        (n_sets,)."""
         return np.asarray(self.set_writes)
 
     def wear_report(self) -> dict:
-        """Serving-side §8 wear stats from the shared WearState(s).
+        """Serving-side §8 wear stats from the shared WearState.
 
         Returns
         -------
         dict
             ``installs_per_set_max/mean``, ``skew_max_over_mean`` (wear
-            evenness), ``window_writes`` (per-set, shard-concatenated),
-            ``throttled_sets_now`` (sets an admission would be rejected
-            from right now — the admit path rejects via
-            ``window_would_exceed`` BEFORE the write, so
-            ``record_write``'s post-overflow lock never engages here),
-            plus the throttle/rotation stats.  Identical at every shard
-            count for the same schedule.
+            evenness), ``window_writes`` (per-set), ``throttled_sets_now``
+            (sets an admission would be rejected from right now — the
+            admit path rejects via ``window_would_exceed`` BEFORE the
+            write, so ``record_write``'s post-overflow lock never engages
+            here), plus the throttle/rotation stats.  Identical at every
+            shard count for the same schedule.
         """
         w = self.write_distribution().astype(np.float64)
         mean = float(w.mean()) if w.size else 0.0
         cyc = jnp.asarray(min(self._clock_cycles(), 2 ** 31 - 1), jnp.int32)
-        throttled_now = sum(
-            int(np.asarray(wear.window_would_exceed(
-                self._wear_states[k], self._wear_dyns[k],
-                jnp.arange(self.sets_per_part), cyc)).sum())
-            for k in range(self.n_parts))
+        throttled_now = int(np.asarray(wear.window_would_exceed(
+            self._wear, self.wear_dyn, jnp.arange(self.cfg.n_sets),
+            cyc)).sum())
         return {
             "installs_per_set_max": float(w.max()) if w.size else 0.0,
             "installs_per_set_mean": mean,
             "skew_max_over_mean": float(w.max() / mean) if mean > 0 else 1.0,
-            "window_writes": np.asarray(
-                self.wear_state.window_writes).tolist(),
+            "window_writes": np.asarray(self._wear.window_writes).tolist(),
             "throttled_sets_now": throttled_now,
             "throttled": self.stats.throttled,
             "rotations": self.stats.rotations,

@@ -18,10 +18,10 @@ levels:
   tokens match the no-cache reference.
 * The full serving loop (``run_request_loop`` + ``AdmitQueue``): a
   randomized zipf schedule replayed at ``n_shards in {1, 2, 4}`` and
-  against the kept ``dispatch="fanout"`` oracle produces identical
-  per-request hits/resumed counts, identical policy state (installs,
-  planes, wear), and identical decoded tokens.  Rides the CI
-  forced-4-device leg, where the shard counts get real placement.
+  through the sequential reference index (``tests/kv_index_reference.py``)
+  produces identical per-request hits/resumed counts, identical policy
+  state (installs, planes, wear), and identical decoded tokens.  Rides
+  the CI forced-4-device leg, where the shard counts get real placement.
 """
 from __future__ import annotations
 
@@ -40,6 +40,7 @@ from repro.serve.admit_queue import AdmitQueue
 from repro.serve.kv_index import (CHUNK_TOKENS, KVIndexConfig, KVSlabStore,
                                   MonarchKVIndex)
 from repro.serve.resume import PrefixResumeEngine
+from kv_index_reference import ReferenceKVIndex
 
 SHARD_COUNTS = (1, 2, 4)
 
@@ -424,7 +425,7 @@ def test_slab_restore_bit_identity(rng, kind, residency):
 
 
 # ---------------------------------------------------------------------------
-# Schedule replay: shard counts + the fan-out oracle stay in lockstep.
+# Schedule replay: shard counts + the reference index stay in lockstep.
 # ---------------------------------------------------------------------------
 
 def _policy_state(idx):
@@ -476,23 +477,19 @@ def _replay(idx, requests, cfg, params):
 def test_schedule_replay_shard_lockstep(rng):
     """The ISSUE's replay pin: one randomized zipf schedule through the
     REAL loop (read-your-writes lookups, submit-after-prefill, slab
-    commits off-thread) at every shard count and against the fan-out
-    oracle — identical hits, resumed counts, installs/planes/wear,
+    commits off-thread) at every shard count and through the reference
+    index — identical hits, resumed counts, installs/planes/wear,
     resident slabs, and decoded tokens."""
     cfg = _arch("global")
     params = transformer.init_params(jax.random.PRNGKey(1), cfg)
     requests = _zipf_requests(8, rng)
-    runs = {}
-    for n in SHARD_COUNTS:
-        runs[n] = _replay(_mk_index(n_shards=n, admit_after_reads=1),
-                          requests, cfg, params)
-    oracle_idx = MonarchKVIndex(
-        KVIndexConfig(n_shards=4, n_sets=8, set_ways=8, admit_after_reads=1,
-                      rotate_every=1 << 30, fingerprint="prefix"),
-        dispatch="fanout", slab_store=KVSlabStore())
-    runs["fanout"] = _replay(oracle_idx, requests, cfg, params)
-
-    ref_recs, ref_dec, _ = runs[SHARD_COUNTS[0]]
+    runs = {n: _replay(_mk_index(n_shards=n, admit_after_reads=1),
+                       requests, cfg, params)
+            for n in SHARD_COUNTS}
+    ref_recs, ref_dec, ref_idx = _replay(
+        ReferenceKVIndex(_mk_index(admit_after_reads=1).cfg,
+                         slab_store=KVSlabStore()),
+        requests, cfg, params)
     assert sum(r.hit_chunks for r in ref_recs) > 0      # schedule hits
     assert sum(r.resumed_chunks for r in ref_recs) > 0  # and resumes
     for key, (recs, dec, _idx) in runs.items():
@@ -502,13 +499,12 @@ def test_schedule_replay_shard_lockstep(rng):
         for da, db in zip(ref_dec, dec):
             np.testing.assert_array_equal(da, db, err_msg=str(key))
 
-    # Shard-count runs share set geometry -> full policy state (installs,
-    # planes, wear, resident slabs) must be identical.  The fan-out
-    # oracle shares everything policy-visible too (same geometry, same
-    # admission semantics) and is compared on the same state dict.
-    ref_state = _policy_state(runs[SHARD_COUNTS[0]][2])
-    for key in list(SHARD_COUNTS[1:]) + ["fanout"]:
-        st = _policy_state(runs[key][2])
+    # Every shard count shares the reference's set geometry and admission
+    # semantics -> full policy state (installs, planes, wear, resident
+    # slabs) must be identical.
+    ref_state = _policy_state(ref_idx)
+    for key, (_recs, _dec, idx) in runs.items():
+        st = _policy_state(idx)
         for k in ref_state:
             if isinstance(ref_state[k], np.ndarray):
                 np.testing.assert_array_equal(ref_state[k], st[k],

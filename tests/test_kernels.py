@@ -402,9 +402,8 @@ def test_xam_stacked_boundary_sets_post_rotation(n_parts, rng):
 def test_stacked_compile_cache_capped_at_pow2_buckets():
     """Jit-cache growth pin for the stacked layout: ~40 distinct ragged
     batch sizes collapse onto the pow2 Qmax buckets, so the fused
-    kernel's compiled-shape count stays logarithmic (the host fan-out
-    per-shard path obeys the same bucket policy via
-    ``group_queries_by_set``)."""
+    kernel's compiled-shape count stays logarithmic (the one-partition
+    search obeys the same bucket policy via ``group_queries_by_set``)."""
     import jax
     from repro.kernels.xam_search.kernel import xam_search_multiset_pallas
     rng = np.random.default_rng(0)

@@ -145,12 +145,12 @@ def test_eviction_prefers_never_reread_ways():
         window_ops=1 << 30))
     fps = [np.uint32(f) for f in range(1, 9)]
     for fp in fps:
-        idx._admit_one(fp)
+        idx.admit_fps(np.asarray([fp], np.uint32))
     assert len(idx.slot_of) == 8       # set full
     hot = fps[:5]
     for fp in hot:
-        idx._admit_one(fp)             # re-touch: marks read_after
-    idx._admit_one(np.uint32(1000))    # forces one eviction
+        idx.admit_fps(np.asarray([fp], np.uint32))  # marks read_after
+    idx.admit_fps(np.asarray([1000], np.uint32))    # forces one eviction
     assert idx.stats.evictions == 1
     for fp in hot:                     # re-read ways were not the victim
         assert int(fp) in idx.slot_of
@@ -162,7 +162,7 @@ def test_eviction_updates_device_state():
         n_sets=1, set_ways=4, admit_after_reads=0, m_writes=1 << 20,
         window_ops=1 << 30))
     for f in range(1, 10):             # overflows the 4-way set
-        idx._admit_one(np.uint32(f))
+        idx.admit_fps(np.asarray([f], np.uint32))
     assert idx.stats.evictions > 0
     # device planes and host shadow stay consistent through evictions
     assert int(np.asarray(idx.valid).sum()) == len(idx.slot_of)

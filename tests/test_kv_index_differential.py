@@ -1,25 +1,25 @@
-"""Differential step-trace pins: single-dispatch paths vs the PR-4 paths.
+"""Differential step-trace pins: the index against its reference.
 
-The single-dispatch PR replaced the host fan-out lookup (one
-``pallas_call`` per shard) with ONE ``shard_map``-wrapped stacked launch,
-and the host-gather rotation with an on-device ``ppermute`` boundary
-exchange.  Both old paths are KEPT behind
-``MonarchKVIndex(..., dispatch="fanout")`` as the oracle, and this module
-replays the same randomized schedule through both indexes side by side,
-pinning planes / hits / shadow maps / replacement counters / wear
-IDENTICAL after EVERY op — not just at end of schedule, so a transient
-divergence (e.g. a boundary set landing on the wrong shard mid-remap)
-cannot cancel out before the final check.
+``tests/kv_index_reference.py`` holds the reference: the index at
+``n_shards=1`` admitting by a sequential scan of the candidates in batch
+order, looking up through the one-partition kernel over the global
+planes.  This module replays the same randomized schedule through the
+index under test, at ``n_shards in {1, 2, 4}``, and through the
+reference side by side, pinning planes / hits / shadow maps /
+replacement counters / wear IDENTICAL after EVERY op — not just at end
+of schedule, so a transient divergence (e.g. a boundary set landing on
+the wrong partition mid-remap) cannot cancel out before the final check.
 
-On a one-device host the "auto" index collapses every shard count to the
-unsharded layout, so the differential still pins collapsed-vs-fanout
-bit-equality; under ``XLA_FLAGS=--xla_force_host_platform_device_count=4``
-(the CI multi-device leg) the same tests drive the real shard_map
-dispatch, multi-device placement and the ppermute rotation.
+On a one-device host every shard count collapses to the unsharded
+layout, so the differential pins the round-grid admission against the
+sequential scan; under
+``XLA_FLAGS=--xla_force_host_platform_device_count=4`` (the CI
+multi-device leg) the same tests drive the real ``shard_map`` lookup and
+admission, multi-device placement and the ppermute rotation.
 
-Also here: the no-host-transfer rotation pin (the remap must move no
-plane data through the host — ``jax.transfer_guard("disallow")``) and
-the jit-cache growth cap of the stacked layout (pow2 Qmax bucketing).
+Also here: the no-host-transfer admission and rotation pins
+(``jax.transfer_guard("disallow")``) and the jit-cache growth cap of the
+stacked layout (pow2 Qmax bucketing).
 """
 from __future__ import annotations
 
@@ -32,6 +32,7 @@ from repro.data.pipeline import fingerprint_blocks
 from repro.kernels.xam_search import ops as xam_ops
 from repro.serve.kv_index import (CHUNK_TOKENS, KVIndexConfig,
                                   MonarchKVIndex)
+from kv_index_reference import ReferenceKVIndex
 
 SHARD_COUNTS = (1, 2, 4)
 
@@ -40,9 +41,17 @@ def _pair(n_shards: int, **kw):
     base = dict(n_sets=8, set_ways=8, admit_after_reads=1, m_writes=2,
                 window_ops=256, rotate_every=1 << 30)
     base.update(kw)
-    cfg = dict(n_shards=n_shards, **base)
-    return (MonarchKVIndex(KVIndexConfig(**cfg)),
-            MonarchKVIndex(KVIndexConfig(**cfg), dispatch="fanout"))
+    cfg = KVIndexConfig(n_shards=n_shards, **base)
+    return MonarchKVIndex(cfg), ReferenceKVIndex(cfg)
+
+
+def _device_ways(idx: MonarchKVIndex, fps: np.ndarray) -> np.ndarray:
+    """The index's own device search for ``fps``: the stacked shard_map
+    search on a set mesh, the one-partition kernel otherwise."""
+    key_bits = xam_ops.words_to_bits_np(fps, idx.cfg.key_bits)
+    return xam_ops.xam_search_multiset_stacked(
+        key_bits, idx._set_of(fps), idx.bits, idx.valid,
+        mesh=idx.set_mesh)
 
 
 def _state(idx: MonarchKVIndex) -> dict:
@@ -77,8 +86,9 @@ def _assert_same(sa: dict, sb: dict, msg: str):
 @pytest.mark.parametrize("n_shards", SHARD_COUNTS)
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_differential_step_trace(seed, n_shards):
-    """Randomized admit/lookup/rotate schedule; auto and fanout indexes
-    must agree on EVERY intermediate state and every lookup result."""
+    """Randomized admit/lookup/rotate schedule; the index and the
+    reference must agree on EVERY intermediate state and every lookup
+    result."""
     rng = np.random.default_rng(seed)
     auto, ref = _pair(n_shards)
     rotated = False
@@ -114,8 +124,8 @@ def test_differential_step_trace(seed, n_shards):
 def test_differential_boundary_straddle_after_rotation(n_shards):
     """Fingerprints whose sets sit at shard edges, pushed ACROSS the
     boundary by repeated set+7 rotations: residency must survive the
-    remap on both paths and the paths must agree bit-for-bit — the exact
-    traffic the ppermute boundary exchange carries."""
+    remap, and the index and the reference must agree bit-for-bit — the
+    exact traffic the ppermute boundary exchange carries."""
     auto, ref = _pair(n_shards, admit_after_reads=0, set_ways=16)
     n_sets = auto.cfg.n_sets
     s_part = n_sets // n_shards
@@ -135,28 +145,19 @@ def test_differential_boundary_straddle_after_rotation(n_shards):
                      f"n_shards={n_shards} rot={rot}")
         hits = auto._shadow_hits(fps)
         # every installed fp must still be found by the DEVICE search
-        key_bits = xam_ops.words_to_bits_np(fps, auto.cfg.key_bits)
-        sets = auto._set_of(fps)
-        if auto._use_shard_map and auto.n_parts > 1:
-            ways = xam_ops.xam_search_multiset_stacked(
-                key_bits, sets, auto._assemble(auto._bits),
-                auto._assemble(auto._valid), mesh=auto.set_mesh)
-        else:
-            ways = xam_ops.xam_search_multiset(
-                key_bits, sets, auto._bits[0], auto._valid[0])
+        ways = _device_ways(auto, fps)
         np.testing.assert_array_equal(ways >= 0, hits)
-        ways_ref = xam_ops.xam_search_multiset_sharded(
-            key_bits, sets, ref._bits, ref._valid)
+        ways_ref = xam_ops.xam_search_multiset(
+            xam_ops.words_to_bits_np(fps, ref.cfg.key_bits),
+            ref._set_of(fps), ref.bits, ref.valid)
         np.testing.assert_array_equal(ways, ways_ref)
     assert auto.stats.rotations == 3
 
 
 @pytest.mark.parametrize("n_shards", SHARD_COUNTS)
 def test_admission_is_single_dispatch_at_every_shard_count(n_shards):
-    """Acceptance pin: with ``admit_dispatch="auto"`` one admit_fps batch
-    is ONE device dispatch no matter how many shards (and partitions)
-    the index spans, while the fanout oracle pays one dispatch per
-    partition holding candidates."""
+    """Acceptance pin: one admit_fps batch is ONE device dispatch no
+    matter how many shards (and partitions) the index spans."""
     auto, ref = _pair(n_shards, admit_after_reads=0)
     rng = np.random.default_rng(0)
     for _ in range(4):
@@ -164,10 +165,7 @@ def test_admission_is_single_dispatch_at_every_shard_count(n_shards):
         before = xam_ops.ADMIT_LAUNCH_COUNT
         auto.admit_fps(fps)
         assert xam_ops.ADMIT_LAUNCH_COUNT == before + 1
-        before = xam_ops.ADMIT_LAUNCH_COUNT
         ref.admit_fps(fps)
-        want = len(np.unique(ref._set_of(fps) // ref.sets_per_part))
-        assert xam_ops.ADMIT_LAUNCH_COUNT == before + want
     assert auto.stats.admit_calls == 4
     _assert_same(_state(auto), _state(ref), f"n_shards={n_shards}")
 
@@ -207,44 +205,38 @@ def test_rotation_moves_no_plane_data_through_host():
     # ...and the remap preserved residency (device search vs the host
     # shadow oracle, outside the guard)
     probe = np.arange(1, 65, dtype=np.uint32)
-    key_bits = xam_ops.words_to_bits_np(probe, idx.cfg.key_bits)
-    sets = idx._set_of(probe)
-    if idx._use_shard_map and idx.n_parts > 1:
-        ways = xam_ops.xam_search_multiset_stacked(
-            key_bits, sets, idx._assemble(idx._bits),
-            idx._assemble(idx._valid), mesh=idx.set_mesh)
-    else:
-        ways = xam_ops.xam_search_multiset(
-            key_bits, sets, idx._bits[0], idx._valid[0])
+    ways = _device_ways(idx, probe)
     want = idx._shadow_hits(probe)
     assert want.any()
     np.testing.assert_array_equal(ways >= 0, want)
 
 
-def test_device_rotation_never_replaces_planes_from_host():
+def test_device_rotation_never_replaces_planes_from_host(monkeypatch):
     """Behavioral twin of the transfer-guard pin (the CPU backend's guard
     cannot see host<->device copies — everything is host memory): the
-    device rotate path must never route plane data through ``_put`` (the
-    host->device placement every fanout re-split uses), while the fanout
-    reference with >1 partition must."""
+    rotate path must never place plane data from the host
+    (``jax.device_put``) nor fetch it (``jax.device_get``), and the planes
+    keep their placement across the remap."""
     idx, ref = _pair(4, admit_after_reads=0)
     fps = np.arange(1, 65, dtype=np.uint32)
     idx.admit_fps(fps)
     ref.admit_fps(fps)
+    placed = [x.sharding for x in (idx.bits, idx.valid, idx.fp_of,
+                                   idx.read_after)]
 
-    def instrument(index):
-        calls = []
-        orig = index._put
-        index._put = lambda x, k: (calls.append(k), orig(x, k))[-1]
-        return calls
-
-    auto_puts = instrument(idx)
+    calls = []
+    for name in ("device_put", "device_get"):
+        orig = getattr(jax, name)
+        monkeypatch.setattr(
+            jax, name,
+            lambda *a, _o=orig, _n=name, **k: (calls.append(_n),
+                                               _o(*a, **k))[-1])
     idx._rotate()
-    assert auto_puts == []
-    ref_puts = instrument(ref)
+    monkeypatch.undo()
+    assert calls == []
+    assert [x.sharding for x in (idx.bits, idx.valid, idx.fp_of,
+                                 idx.read_after)] == placed
     ref._rotate()
-    if ref.n_parts > 1:
-        assert len(ref_puts) >= 4 * ref.n_parts   # 4 planes re-placed/shard
     _assert_same(_state(idx), _state(ref), "post-instrumented rotate")
 
 
@@ -348,15 +340,7 @@ def test_differential_packed_rotation_boundary_exchange(n_shards):
         _assert_same(_state_unpacked(packed), _state_unpacked(plain),
                      f"n_shards={n_shards} rot={rot}")
         # every resident must still be found by the packed device search
-        key_bits = xam_ops.words_to_bits_np(fps, packed.cfg.key_bits)
-        sets = packed._set_of(fps)
-        if packed._use_shard_map and packed.n_parts > 1:
-            ways = xam_ops.xam_search_multiset_stacked(
-                key_bits, sets, packed._assemble(packed._bits),
-                packed._assemble(packed._valid), mesh=packed.set_mesh)
-        else:
-            ways = xam_ops.xam_search_multiset(
-                key_bits, sets, packed._bits[0], packed._valid[0])
+        ways = _device_ways(packed, fps)
         np.testing.assert_array_equal(
             np.asarray(ways) >= 0, packed._shadow_hits(fps))
     assert packed.stats.rotations == 3

@@ -4,13 +4,14 @@ The load-bearing pin: a randomized lookup/admit/rotate schedule replayed
 at ``n_shards in {1, 2, 4}`` must produce IDENTICAL hits, installs
 (shadow map + device planes), per-set replacement counters and wear
 reports — sharding is a relabeling of who stores a set, never a policy
-change.  Since the single-dispatch PR the index stores state in MESH
+change.  The index stores each plane as one array sharded over MESH
 PARTITIONS (``idx.n_parts``): on a one-device host every shard count
 collapses to the exact unsharded path, and under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` (the CI
 multi-device leg) the same matrix exercises the real ``shard_map``
-lookup and ``ppermute`` rotation.  The step-for-step pins against the
-kept PR-4 fan-out paths live in ``tests/test_kv_index_differential.py``.
+lookup and admission and the ``ppermute`` rotation.  The step-for-step
+pins against the sequential reference (``tests/kv_index_reference.py``)
+live in ``tests/test_kv_index_differential.py``.
 
 The AdmitQueue tests pin the async relaxation: flush == the same
 ``admit_fps`` calls inline, rotation is a drain barrier, and
@@ -85,7 +86,9 @@ def test_shard_count_invariance(seed):
     idxs = [_mk(n) for n in SHARD_COUNTS]
     for step in range(8):
         toks = rng.integers(1, 600, (2, 6 * CHUNK_TOKENS)).astype(np.int32)
-        op = rng.random()
+        # The first step always offers twice, so every schedule skips
+        # (first touch) and installs (second touch) at least once.
+        op = 0.0 if step == 0 else rng.random()
         if op < 0.6:
             fps = np.unique(
                 fingerprint_blocks(toks, CHUNK_TOKENS).reshape(-1))
@@ -133,28 +136,34 @@ def test_shard_invariance_under_eviction_and_throttle_pressure():
 
 
 def test_sharded_state_shapes_and_ownership():
-    """Storage is one block per MESH PARTITION: n_parts == the ("sets",)
-    mesh size under "auto" (1 on a one-device host — co-located shards
-    collapse to the unsharded layout), n_shards under the "fanout"
-    reference."""
+    """Each plane and counter is ONE array over every set, stored in one
+    block per MESH PARTITION: n_parts == the ("sets",) mesh size (1 on a
+    one-device host — co-located shards collapse to the unsharded
+    layout), and partition k's block holds global sets
+    ``[k * s_loc, (k + 1) * s_loc)`` on mesh device k."""
     idx = _mk(4, n_sets=8)
     assert idx.sets_per_shard == 2           # logical shard geometry
     want_parts = mesh_mod.set_partitions(4)  # largest divisor host holds
     assert idx.n_parts == want_parts
     s_loc = 8 // want_parts
-    assert len(idx._bits) == want_parts
-    for k in range(want_parts):
-        assert idx._bits[k].shape == (
-            s_loc, idx.cfg.key_bits, idx.cfg.set_ways)
-        assert idx._wear_states[k].window_writes.shape == (s_loc,)
-        assert idx._counters[k].shape == (s_loc,)
-    # the fan-out reference keeps one block per logical shard
-    ref = MonarchKVIndex(KVIndexConfig(
-        n_shards=4, n_sets=8, set_ways=8), dispatch="fanout")
-    assert ref.n_parts == 4 and len(ref._bits) == 4
-    assert ref._bits[0].shape == (2, ref.cfg.key_bits, ref.cfg.set_ways)
-    # global views concatenate in partition order == global set order
-    assert np.asarray(idx.valid).shape == (8, idx.cfg.set_ways)
+    devices = (list(idx.set_mesh.devices.flat) if idx.set_mesh is not None
+               else [None])
+    arrays = {"bits": (idx.bits, (idx.cfg.key_bits, idx.cfg.set_ways)),
+              "valid": (idx.valid, (idx.cfg.set_ways,)),
+              "counter": (idx.counter, ()),
+              "window_writes": (idx.wear_state.window_writes, ())}
+    for name, (arr, tail) in arrays.items():
+        assert arr.shape == (8,) + tail, name
+        blocks = sorted(arr.addressable_shards,
+                        key=lambda sh: sh.index[0].start or 0)
+        assert len(blocks) == want_parts, name
+        for k, sh in enumerate(blocks):
+            assert sh.data.shape == (s_loc,) + tail, name
+            assert (sh.index[0].start or 0) == k * s_loc, name
+            if devices[k] is not None:
+                assert sh.device == devices[k], name
+    # the global wear view sums the per-partition scalar counters
+    assert idx.wear_state.write_counter.shape == ()
     shard, local = geometry.shard_of_set(np.arange(8), 8, 4)
     np.testing.assert_array_equal(shard, np.arange(8) // 2)
     np.testing.assert_array_equal(local, np.arange(8) % 2)
@@ -170,8 +179,7 @@ def test_lookup_is_single_dispatch_at_every_shard_count(rng):
     lookup batch REGARDLESS of n_shards (the stacked shard_map path on a
     multi-device mesh, the collapsed unsharded launch otherwise), counted
     at the ops layer — where every host-side launch site increments
-    ``xam_ops.LAUNCH_COUNT`` exactly once.  The kept fan-out reference
-    still pays one dispatch per occupied shard."""
+    ``xam_ops.LAUNCH_COUNT`` exactly once."""
     from repro.kernels.xam_search import ops as xam_ops
     toks = rng.integers(1, 50_000, (4, 256)).astype(np.int32)
     for n_shards in SHARD_COUNTS:
@@ -181,27 +189,20 @@ def test_lookup_is_single_dispatch_at_every_shard_count(rng):
         idx.lookup(toks)       # 64 chunks spread over all sets
         assert xam_ops.LAUNCH_COUNT == before + 1, n_shards
         assert idx.stats.searches == s_before + 1
-    ref = MonarchKVIndex(KVIndexConfig(
-        n_shards=4, n_sets=8, set_ways=8, admit_after_reads=0),
-        dispatch="fanout")
-    before = xam_ops.LAUNCH_COUNT
-    ref.lookup(toks)           # all 4 shards occupied -> 4 dispatches
-    assert xam_ops.LAUNCH_COUNT == before + 4
 
 
 def test_set_mesh_single_device_is_none():
     """On a 1-device host the ("sets",) mesh degenerates: shards
-    co-locate and placement is skipped (the dry-run env is the multi-
-    device path; tests must see the real device count)."""
+    co-locate and the index keeps plain single-device arrays (the dry-run
+    env is the multi-device path; tests must see the real device
+    count)."""
     import jax
     if len(jax.devices()) == 1:
         assert mesh_mod.make_set_mesh(4) is None
-        assert mesh_mod.set_shard_devices(None, 4) is None
     else:
         mesh = mesh_mod.make_set_mesh(4)
         assert mesh is not None and mesh.axis_names == ("sets",)
-        devs = mesh_mod.set_shard_devices(mesh, 4)
-        assert len(devs) == 4
+        assert mesh.devices.size == mesh_mod.set_partitions(4)
 
 
 # ---------------------------------------------------------------------------

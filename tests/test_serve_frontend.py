@@ -11,11 +11,11 @@ Wall-clock coverage pins the tentpole contract from three sides:
   existing differential/sharded suites are the behavioral pin; here we
   additionally prove the clock source is untouched).
 * WALL SEMANTICS — with a controllable ``now_fn``: window expiry
-  unlocks sets as wall time passes, the auto-vs-fanout differential
-  oracle still agrees at n_shards {1, 2, 4} (per-batch host-side
-  stamps keep device scans deterministic), and the int32 clock rebase
-  is exact (an index driven near the rebase boundary matches one
-  driven from zero).
+  unlocks sets as wall time passes, the index still agrees with its
+  sequential reference (``tests/kv_index_reference.py``) at n_shards
+  {1, 2, 4} (per-batch host-side stamps keep device scans
+  deterministic), and the int32 clock rebase is exact (an index driven
+  near the rebase boundary matches one driven from zero).
 """
 from __future__ import annotations
 
@@ -30,6 +30,7 @@ from repro.core import wear
 from repro.launch.serve import RequestRecord, run_request_loop
 from repro.serve.admit_queue import AdmitQueue
 from repro.serve.kv_index import CHUNK_TOKENS, KVIndexConfig, MonarchKVIndex
+from kv_index_reference import ReferenceKVIndex
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:                      # for `import benchmarks.*`
@@ -109,13 +110,12 @@ def test_ops_clock_never_consults_the_wall_clock():
 
 
 def _wall_index(clk, *, n_shards: int = 1, window_s: float = 1.0,
-                admit_dispatch=None, **kw):
+                cls=MonarchKVIndex, **kw):
     cfg = dict(n_sets=8, set_ways=4, admit_after_reads=0, m_writes=1,
                window_ops=int(window_s * wear.WALL_HZ), rotate_every=1 << 30,
                clock="wall", n_shards=n_shards)
     cfg.update(kw)
-    return MonarchKVIndex(KVIndexConfig(**cfg),
-                          admit_dispatch=admit_dispatch, now_fn=clk)
+    return cls(KVIndexConfig(**cfg), now_fn=clk)
 
 
 def test_wall_window_locks_then_expires_with_wall_time():
@@ -162,15 +162,15 @@ def _assert_same(sa: dict, sb: dict, msg: str):
 
 
 @pytest.mark.parametrize("n_shards", [1, 2, 4])
-def test_wall_clock_differential_auto_vs_fanout(n_shards):
-    """The per-partition fanout oracle must stay bit-identical to the
-    stacked dispatch under the wall clock: stamps are taken ONCE per
-    admission batch on the host, so both dispatch paths see the same
-    cycle values no matter how the batch is partitioned."""
+def test_wall_clock_differential_vs_reference(n_shards):
+    """The round-grid admission must stay bit-identical to the sequential
+    reference under the wall clock: stamps are taken ONCE per admission
+    batch on the host, so both see the same cycle values no matter how
+    the batch is partitioned."""
     clk = FakeClock()
     auto = _wall_index(clk, n_shards=n_shards, set_ways=8, m_writes=2)
     ref = _wall_index(clk, n_shards=n_shards, set_ways=8, m_writes=2,
-                      admit_dispatch="fanout")
+                      cls=ReferenceKVIndex)
     rng = np.random.default_rng(11)
     for step in range(8):
         fps = np.unique(rng.integers(1, 1 << 20, 48).astype(np.uint32))

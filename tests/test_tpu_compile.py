@@ -152,31 +152,44 @@ def test_stacked_lookup_compiles_on_four_chips(set_mesh):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_admission_scan_compiles_on_four_chips(set_mesh):
-    shd = NamedSharding(set_mesh, P("sets"))
-    repl = NamedSharding(set_mesh, P())
-    n_rounds, width = 2, 8
-    grid = (4, n_rounds, width)
+def _admit_operands(rows, whole, n_parts):
+    """Shapes of one admission dispatch at the launchers' geometry: the
+    state laid out along the set axis (``rows``), the knobs ``whole``,
+    and an (n_parts, 2, 8) candidate grid."""
+    grid = (n_parts, 2, 8)
     wcfg = wear.WearConfig(n_supersets=N_SETS, blocks_per_superset=SET_WAYS)
-    wdyn = jax.tree.map(lambda a: _sds(a.shape, a.dtype, repl),
+    wdyn = jax.tree.map(lambda a: _sds(a.shape, a.dtype, whole),
                         jax.eval_shape(lambda: wear.dyn_of(wcfg)))
-    per_set = lambda dt: _sds((N_SETS,), dt, shd)
-    fn = kv_index._admit_shardmap_fn(set_mesh)
-    fn.lower(
-        _sds((N_SETS, KEY_BITS, SET_WAYS), jnp.int8, shd),
-        _sds((N_SETS, SET_WAYS), jnp.int8, shd),
-        _sds((N_SETS, SET_WAYS), jnp.uint32, shd),
-        _sds((N_SETS, SET_WAYS), jnp.int32, shd),
-        per_set(jnp.int32), per_set(jnp.int32),
-        per_set(jnp.int8), per_set(jnp.int8),
-        per_set(jnp.int32), per_set(jnp.int32), per_set(jnp.int32),
-        _sds((4,), jnp.int32, shd), _sds((4,), jnp.int32, shd),
-        _sds((4,), jnp.int32, shd),
-        wdyn, _sds((), jnp.int32, repl),
-        _sds(grid, jnp.int32, shd), _sds(grid, jnp.uint32, shd),
-        _sds(grid + (KEY_BITS,), jnp.int8, shd),
-        _sds(grid, jnp.int32, shd), _sds(grid, jnp.int32, shd),
-        _sds(grid, jnp.bool_, shd)).compile()
+    per_part = jnp.zeros(n_parts, jnp.int32)
+    wstate = jax.eval_shape(lambda: dataclasses.replace(
+        wear.init_state(wcfg), write_counter=per_part,
+        superset_counter=per_part, dirty_counter=per_part))
+    wstate = jax.tree.map(
+        lambda where, sub: jax.tree.map(
+            lambda a: _sds(a.shape, a.dtype, where), sub),
+        kv_index._wear_layout(rows, whole), wstate)
+    return (
+        _sds((N_SETS, KEY_BITS, SET_WAYS), jnp.int8, rows),
+        _sds((N_SETS, SET_WAYS), jnp.int8, rows),
+        _sds((N_SETS, SET_WAYS), jnp.uint32, rows),
+        _sds((N_SETS, SET_WAYS), jnp.int32, rows),
+        _sds((N_SETS,), jnp.int32, rows), _sds((N_SETS,), jnp.int32, rows),
+        wstate, wdyn, _sds((), jnp.int32, whole),
+        _sds(grid, jnp.int32, rows), _sds(grid, jnp.uint32, rows),
+        _sds(grid + (KEY_BITS,), jnp.int8, rows),
+        _sds(grid, jnp.int32, rows), _sds(grid, jnp.int32, rows),
+        _sds(grid, jnp.bool_, rows))
+
+
+def test_admission_scan_compiles_on_one_chip(one_chip):
+    kv_index._admit_program(None).lower(
+        *_admit_operands(one_chip, one_chip, 1)).compile()
+
+
+def test_admission_scan_compiles_on_four_chips(set_mesh):
+    kv_index._admit_program(set_mesh).lower(*_admit_operands(
+        NamedSharding(set_mesh, P("sets")), NamedSharding(set_mesh, P()),
+        4)).compile()
 
 
 # ---------------------------------------------------------------------------
